@@ -199,7 +199,7 @@ def test_jax_preemption_node_sharded_mesh_matches_single_device():
     from tpusim.jaxe.preempt import run_with_preemption
 
     mesh = _node_mesh_or_skip()
-    snap, pods = bench.build_workload(400, 10, priorities=True, seed=41)
+    snap, pods = bench.zipf_workload(400, 10, priorities=True, seed=41)
     base = run_with_preemption([p.copy() for p in pods], snap)
     assert base.preempted_pods, "workload drifted: nothing preempted"
     sharded = run_with_preemption([p.copy() for p in pods], snap, mesh=mesh)
@@ -215,7 +215,7 @@ def test_jax_preemption_mesh_host_arm_parity(monkeypatch):
     from tpusim.jaxe.preempt import run_with_preemption
 
     mesh = _node_mesh_or_skip()
-    snap, pods = bench.build_workload(400, 10, priorities=True, seed=43)
+    snap, pods = bench.zipf_workload(400, 10, priorities=True, seed=43)
     monkeypatch.delenv("TPUSIM_PREEMPT_DEVICE", raising=False)
     base = run_with_preemption([p.copy() for p in pods], snap)
     assert base.preempted_pods
@@ -233,7 +233,7 @@ def test_preempt_fast_path_engages_and_matches(monkeypatch):
     from tpusim.jaxe import fastscan
     from tpusim.jaxe.preempt import run_with_preemption
 
-    snap, pods = bench.build_workload(600, 40, priorities=True, seed=17)
+    snap, pods = bench.zipf_workload(600, 40, priorities=True, seed=17)
 
     monkeypatch.delenv("TPUSIM_FAST", raising=False)
     base = run_with_preemption(pods, snap)
@@ -270,7 +270,7 @@ def test_preempt_fast_verify_once_small_chunk0(monkeypatch):
     from tpusim.jaxe import backend, fastscan
     from tpusim.jaxe.preempt import run_with_preemption
 
-    snap, pods = bench.build_workload(400, 30, priorities=True, seed=23)
+    snap, pods = bench.zipf_workload(400, 30, priorities=True, seed=23)
     monkeypatch.delenv("TPUSIM_FAST", raising=False)
     base = run_with_preemption(pods, snap)
 

@@ -220,8 +220,7 @@ class TestAutoTransitions:
 
         saved = {k: (set(v) if isinstance(v, set) else v)
                  for k, v in jb._FAST_AUTO.items()}
-        jb._FAST_AUTO.update(disabled=False, verified_sigs=set(),
-                             transient=0)
+        jb._FAST_AUTO.update(disabled=False, verified_sigs=set())
         register().reset()
         yield
         jb._FAST_AUTO.update(saved)
@@ -258,25 +257,3 @@ class TestAutoTransitions:
     def test_trust_bridge_counts(self):
         flight.note_auto_transition("trust", "('v', 2)")
         assert register().backend_auto_transitions.get("trust") == 1
-
-    def test_forced_discard_transient_then_permanent(self):
-        from tpusim.jaxe import backend as jb
-
-        for _ in range(3):
-            jb._note_fast_failure(RuntimeError("RESOURCE_EXHAUSTED: hbm"))
-        m = register()
-        assert m.backend_auto_transitions.get("discard_transient") == 3
-        assert m.backend_auto_transitions.get("discard_permanent") == 1
-        assert jb._FAST_AUTO["disabled"]
-
-    def test_compile_failure_discards_permanently(self):
-        from tpusim.jaxe import backend as jb
-
-        jb._note_fast_failure(ValueError("Mosaic lowering failed"))
-        m = register()
-        assert m.backend_auto_transitions.get("discard_permanent") == 1
-        assert m.backend_auto_transitions.get("discard_transient") == 0
-        assert jb._FAST_AUTO["disabled"]
-        # the discard is visible on the exposition surface (--metrics-out)
-        assert ('tpusim_backend_auto_transitions_total'
-                '{transition="discard_permanent"} 1') in m.expose()

@@ -258,3 +258,55 @@ def synthetic_cluster(
     nodes = [make_node(f"{name_prefix}-{i}", milli_cpu=milli_cpu, memory=memory,
                        pods=pods_per_node) for i in range(num_nodes)]
     return ClusterSnapshot(nodes=nodes)
+
+
+def zipf_workload(num_pods: int, num_nodes: int, affinity: bool = False,
+                  seed: int = 12345, priorities: bool = False):
+    """(snapshot, pods) at a heterogeneous cluster shape: three node sizes
+    (4/8/16 cores, 8/16/32 GiB, 110 pods each), a zone label over 4 zones,
+    a NoSchedule taint on every tenth node, and Zipf-sized pod requests of
+    which 10% tolerate that taint. affinity=True pins half the pods to a
+    zone via required node affinity; priorities=True adds priority bands
+    (60% band 0, 30% band 500, 10% band 1000)."""
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    nodes = []
+    for i in range(num_nodes):
+        shape = i % 3
+        taints = None
+        if i % 10 == 0:
+            taints = [{"key": "dedicated", "value": "batch",
+                       "effect": "NoSchedule"}]
+        nodes.append(make_node(f"node-{i}", milli_cpu=[4000, 8000, 16000][shape],
+                               memory=[8, 16, 32][shape] * 1024**3, pods=110,
+                               labels={"zone": f"z{i % 4}"}, taints=taints))
+
+    cpu_buckets = np.array([50, 100, 250, 500, 1000, 2000, 4000])
+    mem_buckets = np.array([64, 128, 256, 512, 1024, 2048, 4096]) * 2**20
+    weights = 1.0 / np.arange(1, len(cpu_buckets) + 1) ** 1.1
+    weights /= weights.sum()
+    cpu_idx = rng.choice(len(cpu_buckets), size=num_pods, p=weights)
+    mem_idx = rng.choice(len(mem_buckets), size=num_pods, p=weights)
+    tolerate = rng.rand(num_pods) < 0.1
+    want_zone = rng.randint(0, 8, size=num_pods) if affinity else None
+
+    pods = []
+    for i in range(num_pods):
+        kwargs = {}
+        if tolerate[i]:
+            kwargs["tolerations"] = [{"key": "dedicated", "operator": "Equal",
+                                      "value": "batch", "effect": "NoSchedule"}]
+        if affinity and want_zone[i] < 4:
+            kwargs["affinity"] = {"nodeAffinity": {
+                "requiredDuringSchedulingIgnoredDuringExecution": {
+                    "nodeSelectorTerms": [{"matchExpressions": [
+                        {"key": "zone", "operator": "In",
+                         "values": [f"z{want_zone[i]}"]}]}]}}}
+        pod = make_pod(f"p-{i}", milli_cpu=int(cpu_buckets[cpu_idx[i]]),
+                       memory=int(mem_buckets[mem_idx[i]]), **kwargs)
+        if priorities:
+            pod.spec.priority = int(rng.choice([0, 500, 1000],
+                                               p=[0.6, 0.3, 0.1]))
+        pods.append(pod)
+    return ClusterSnapshot(nodes=nodes), pods

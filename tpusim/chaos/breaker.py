@@ -1,11 +1,10 @@
 """Circuit breaker: closed → open on repeated faults → half-open re-probe
 → closed.
 
-The jax backend wraps device dispatch in one of these so a flaky device
-(injected chaos faults or a real wedged tunnel) degrades to the host
+The jax backend wraps device dispatch in one of these when a chaos plan
+arms it, so a device made flaky by injected faults degrades to the host
 pipeline and RECOVERS, instead of either crashing the run or staying
-disabled for the rest of the process (what the pre-breaker `_FAST_AUTO`
-three-strikes logic did for the Pallas fast path).
+disabled for the rest of the process.
 
 Deterministic by construction: state advances on *attempt counts*, never
 wall-clock — ``cooldown`` is the number of denied dispatches before a

@@ -60,8 +60,8 @@ class DeviceFault(RuntimeError):
 
 
 class InjectedDeviceError(DeviceFault):
-    """A scripted device-dispatch failure (the chaos analog of a dead
-    accelerator tunnel mid-batch)."""
+    """A scripted device-dispatch failure (the chaos analog of a device
+    lost mid-batch)."""
 
 
 class DeviceOutputError(DeviceFault):

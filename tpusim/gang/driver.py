@@ -157,28 +157,25 @@ def _joint_solve(backend, inc, members: List[Pod], compiled, cols
 
     n_shards = _shard_count()
     if n_shards > 1 and not _SHARD_AUTO["disabled"]:
-        import jax
+        # cross-shard gang lanes (ISSUE 16 sub-problem b): per-member
+        # filter/score runs per node shard with collective reductions,
+        # the stitched output re-gathers the full (member, node) matrix
+        # — padded columns come back all-infeasible, so slicing to the
+        # real node count feeds gang_choices byte-identical inputs
+        from dataclasses import replace as _dc_replace
 
-        if len(jax.devices()) >= n_shards:
-            # cross-shard gang lanes (ISSUE 16 sub-problem b): per-member
-            # filter/score runs per node shard with collective reductions,
-            # the stitched output re-gathers the full (member, node) matrix
-            # — padded columns come back all-infeasible, so slicing to the
-            # real node count feeds gang_choices byte-identical inputs
-            from dataclasses import replace as _dc_replace
+        from tpusim.jaxe.kernels import gang_lanes_sharded
+        from tpusim.jaxe.sharding import make_mesh, shard_for_mesh
 
-            from tpusim.jaxe.kernels import gang_lanes_sharded
-            from tpusim.jaxe.sharding import make_mesh, shard_for_mesh
-
-            mesh = make_mesh(n_shards, snap=1)
-            st, ca, xs_r = shard_for_mesh(mesh, statics, carry, xs)
-            with flight.span("shard:gang_lanes", "device") as sp:
-                lanes = gang_lanes_sharded(
-                    _dc_replace(config, shard_axis="node"), mesh, ca, st,
-                    xs_r)
-                if sp:
-                    sp.set("shards", n_shards)
-                    sp.set("members", len(members))
+        mesh = make_mesh(n_shards, snap=1)
+        st, ca, xs_r = shard_for_mesh(mesh, statics, carry, xs)
+        with flight.span("shard:gang_lanes", "device") as sp:
+            lanes = gang_lanes_sharded(
+                _dc_replace(config, shard_axis="node"), mesh, ca, st,
+                xs_r)
+            if sp:
+                sp.set("shards", n_shards)
+                sp.set("members", len(members))
     if lanes is None:
         lanes = gang_lanes(config, carry, statics, xs)
     feasible = np.asarray(lanes[0])[:, :n_nodes]

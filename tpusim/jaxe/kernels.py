@@ -632,18 +632,41 @@ def explain_part_names(config: EngineConfig) -> list:
 # is untouched); with a mesh axis name they append the matching collective.
 # All reduced quantities are integers (or integer-valued f64 counts below
 # 2^53), so psum/pmax/pmin across shards are exact and order-independent —
-# the basis for the bit-identical cross-shard claim.
+# the basis for the bit-identical cross-shard claim. int64 max/min reduce
+# as int32 word pairs (_pmax_i64).
 
 def _ax_sum(v, axis):
     return v if axis is None else jax.lax.psum(v, axis)
 
 
 def _ax_max(v, axis):
-    return v if axis is None else jax.lax.pmax(v, axis)
+    if axis is None:
+        return v
+    if v.dtype == jnp.int64:
+        return _pmax_i64(v, axis)
+    return jax.lax.pmax(v, axis)
 
 
 def _ax_min(v, axis):
-    return v if axis is None else jax.lax.pmin(v, axis)
+    if axis is None:
+        return v
+    if v.dtype == jnp.int64:
+        return ~_pmax_i64(~v, axis)  # ~ reverses int64 order exactly
+    return jax.lax.pmin(v, axis)
+
+
+def _pmax_i64(v, axis):
+    """Exact int64 pmax from two int32 pmaxes: a TPU lowers no 64-bit
+    all-reduce but Sum. The signed high words go first; the low words,
+    shifted into signed order, then compete only among the shards that
+    hold the maximal high word."""
+    hi = (v >> 32).astype(jnp.int32)
+    lo = ((v & 0xFFFFFFFF) - (1 << 31)).astype(jnp.int32)
+    hi_max = jax.lax.pmax(hi, axis)
+    lo_max = jax.lax.pmax(
+        jnp.where(hi == hi_max, lo, jnp.iinfo(jnp.int32).min), axis)
+    return ((hi_max.astype(jnp.int64) << 32)
+            + (lo_max.astype(jnp.int64) + (1 << 31)))
 
 
 def _ax_any(v, axis):
@@ -1399,17 +1422,16 @@ def sharded_scan_fn(config: EngineConfig, mesh, donate: bool = False):
     key = (config, mesh, donate)
     fn = _SHARDED_SCAN_PROGRAMS.get(key)
     if fn is None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         st_specs, ca_specs, xs_specs = node_partition_specs(config.shard_axis)
-        sm = shard_map(
+        sm = jax.shard_map(
             partial(_schedule_scan_impl, config), mesh=mesh,
             in_specs=(ca_specs, st_specs, xs_specs),
             # final carry keeps its node-sharded layout; choices/counts/
             # advanced are replicated by construction (pmin/psum merges)
             out_specs=(ca_specs, P(), P(), P()),
-            check_rep=False)
+            check_vma=False)
         fn = jax.jit(sm, donate_argnums=(0,) if donate else ())
         _SHARDED_SCAN_PROGRAMS[key] = fn
     return fn
@@ -1891,15 +1913,14 @@ def analytics_reduce_sharded(mesh, inp: AnalyticsIn, n_valid, *, k: int,
     key = (mesh, k, axis)
     fn = _ANALYTICS_SHARDED_PROGRAMS.get(key)
     if fn is None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        sm = shard_map(
+        sm = jax.shard_map(
             partial(_analytics_reduce_impl, k=k, axis=axis), mesh=mesh,
             in_specs=(AnalyticsIn(*(P(axis) for _ in AnalyticsIn._fields)),
                       P()),
             out_specs=AnalyticsStats(*(P() for _ in AnalyticsStats._fields)),
-            check_rep=False)
+            check_vma=False)
         fn = jax.jit(sm)
         _ANALYTICS_SHARDED_PROGRAMS[key] = fn
     return fn(inp, n_valid)
@@ -1994,16 +2015,15 @@ def gang_lanes_sharded(config: EngineConfig, mesh, carry: Carry,
     key = (config, mesh)
     fn = _GANG_LANES_SHARDED_PROGRAMS.get(key)
     if fn is None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         st_specs, ca_specs, xs_specs = node_partition_specs(config.shard_axis)
-        sm = shard_map(
+        sm = jax.shard_map(
             partial(_gang_lanes_impl, config), mesh=mesh,
             in_specs=(ca_specs, st_specs, xs_specs),
             out_specs=(P(None, config.shard_axis),
                        P(None, config.shard_axis)),
-            check_rep=False)
+            check_vma=False)
         fn = jax.jit(sm)
         _GANG_LANES_SHARDED_PROGRAMS[key] = fn
     return fn(carry, statics, xs)

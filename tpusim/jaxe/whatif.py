@@ -106,11 +106,10 @@ def _scenario_program(config, mesh):
     """The manual shard_map route: scenarios partitioned over the mesh's
     "scenario" axis, node columns whole per shard (make_scenario_mesh).
     Cross-scenario communication is impossible by construction — each shard
-    runs the vmap-of-scan on its own scenario slice. check_rep=False because
+    runs the vmap-of-scan on its own scenario slice. check_vma=False because
     out_specs carry no replicated axes to prove."""
     fn = _SCENARIO_PROGRAMS.get((config, mesh))
     if fn is None:
-        from jax.experimental.shard_map import shard_map
 
         ca_spec, st_spec, xs_spec = scenario_specs()
 
@@ -126,9 +125,9 @@ def _scenario_program(config, mesh):
 
         from jax.sharding import PartitionSpec as P
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             local, mesh=mesh, in_specs=(ca_spec, st_spec, xs_spec),
-            out_specs=(P("scenario"), P("scenario")), check_rep=False))
+            out_specs=(P("scenario"), P("scenario")), check_vma=False))
         _SCENARIO_PROGRAMS[(config, mesh)] = fn
     return fn
 
@@ -401,14 +400,13 @@ def _decode_batch(scenarios, compiled_list, choices_b,
 def _try_fast_loop(scenarios, config, compiled_list, ptabs_list, host_trees):
     """Run every scenario through the Pallas fast path sequentially;
     returns the decoded results, or None to fall back to the batched vmap
-    program (ineligible scenario, fast path off/disabled, kernel failure,
-    or a failed AUTO self-verification)."""
+    program (ineligible scenario, fast path off/disabled, or a failed AUTO
+    self-verification). A kernel that fails to lower or run raises."""
     from tpusim.framework.metrics import register
     from tpusim.jaxe.backend import (
         _FAST_AUTO,
         _auto_verify_and_pin,
         _fast_path_enabled,
-        _note_fast_failure,
         _note_fast_fallback,
         plan_signature,
     )
@@ -430,15 +428,7 @@ def _try_fast_loop(scenarios, config, compiled_list, ptabs_list, host_trees):
     choices_list = []
     counts_list = []
     for b, plan in enumerate(plans):
-        try:
-            choices, counts, _adv = fast_scan(plan)
-        except Exception as exc:
-            log.warning("what-if fast loop failed (%s: %s); falling back "
-                        "to the batched vmap program",
-                        type(exc).__name__, exc)
-            _note_fast_failure(exc)
-            return None
-        _FAST_AUTO["transient"] = 0
+        choices, counts, _adv = fast_scan(plan)
         sig = plan_signature(plan)
         if auto_mode and sig not in _FAST_AUTO["verified_sigs"]:
             # every scenario verifies until its kernel variant is trusted —

@@ -372,7 +372,7 @@ def flow_end(name: str, flow_id: str, cat: str = "host",
 
 def note_auto_transition(kind: str, sig: Optional[str] = None) -> None:
     """AUTO verify-then-trust transition: verify_pass/verify_fail/pin/
-    trust/defer/discard_transient/discard_permanent."""
+    trust/defer (shard_* for the node-sharded route)."""
     _metrics.register().backend_auto_transitions.inc(kind)
     rec = _active
     if rec is not None:

@@ -82,7 +82,7 @@ from tpusim.framework.store import MODIFIED
 from tpusim.gang.driver import schedule_with_gangs
 from tpusim.gang.group import has_gangs
 from tpusim.jaxe import backend as _backend
-from tpusim.jaxe import ensure_responsive_platform, ensure_x64
+from tpusim.jaxe import ensure_x64
 from tpusim.jaxe.delta import _SIG_KINDS, IncrementalCluster
 from tpusim.jaxe.kernels import (
     DeltaRows,
@@ -327,7 +327,6 @@ class StreamSession:
         if policy is not None and compiled_policy is None:
             compiled_policy = compile_policy(policy)
         ensure_x64()
-        ensure_responsive_platform()
         self.inc = (incremental if incremental is not None
                     else IncrementalCluster(snapshot))
         self.provider = provider
@@ -810,7 +809,7 @@ class StreamSession:
 
     def _decide_shard_layout(self, config) -> None:
         """Re-decide the residency layout at restage time: TPUSIM_SHARDS>1
-        (eligible, enough devices, not process-disabled) shards the twin's
+        (eligible, not process-disabled) shards the twin's
         node axis; anything else is a classified fallback to the
         single-device layout. Restage is the only place the layout can
         change — stream cycles inherit whatever the twin was staged as."""
@@ -819,13 +818,9 @@ class StreamSession:
         n_shards = _backend._shard_count()
         if n_shards <= 1 or _backend._SHARD_AUTO["disabled"]:
             return
-        import jax
-
         from tpusim.jaxe.kernels import shard_route_eligible
 
         ok, why = shard_route_eligible(config)
-        if ok and len(jax.devices()) < n_shards:
-            ok, why = False, "device_count"
         if not ok:
             register().shard_fallback.inc(why)
             flight.note_fast_fallback(

@@ -68,7 +68,8 @@ def test_more_shards_than_devices_raises(monkeypatch):
 @pytest.mark.parametrize("env_dir", [None, "placed"])
 def test_compile_cache_dir_rule(tmp_path, env_dir):
     """Without JAX_COMPILATION_CACHE_DIR the package keeps the cache at
-    <checkout>/.jax_cache; with it, JAX's own setting stands untouched."""
+    <checkout>/.jax_cache; with it, JAX's own settings stand untouched,
+    the minimum compile time to cache included."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     want = os.path.join(REPO, ".jax_cache")
@@ -78,9 +79,11 @@ def test_compile_cache_dir_rule(tmp_path, env_dir):
     out = subprocess.run(
         [sys.executable, "-c",
          "import json, jax, tpusim.jaxe as j; print(json.dumps("
-         "[jax.config.jax_compilation_cache_dir, j.compile_cache_dir()]))"],
+         "[jax.config.jax_compilation_cache_dir, j.compile_cache_dir(),"
+         " jax.config.jax_persistent_cache_min_compile_time_secs]))"],
         cwd=str(tmp_path), env=dict(env, PYTHONPATH=REPO),
         capture_output=True, text=True, timeout=120, check=True)
-    placed, ours = json.loads(out.stdout.strip().splitlines()[-1])
+    placed, ours, min_s = json.loads(out.stdout.strip().splitlines()[-1])
     assert placed == want
     assert ours == (None if env_dir is not None else want)
+    assert min_s == (1.0 if env_dir is not None else 0.5)

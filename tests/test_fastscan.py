@@ -276,7 +276,7 @@ def _run_auto(monkeypatch, corrupt=None, boom=False, num_pods=120):
     def wrapped(plan, **kw):
         calls.append(1)
         if boom:
-            raise RuntimeError("mosaic said no")
+            raise RuntimeError(boom)
         choices, counts, adv = real(plan, **kw)
         if corrupt is not None:
             choices = np.array(choices, copy=True)
@@ -321,7 +321,18 @@ def test_auto_fast_path_exception_propagates(monkeypatch):
     """A Mosaic rejection inside fast_scan reaches the caller: the batch
     is never quietly re-run on the XLA scan."""
     with pytest.raises(RuntimeError, match="mosaic said no"):
-        _run_auto(monkeypatch, boom=True)
+        _run_auto(monkeypatch, boom="mosaic said no")
+
+
+def test_auto_fast_path_transient_reruns_on_xla(monkeypatch):
+    """A one-off device OOM reruns that batch on the XLA scan, on the same
+    device, and leaves the fast path on for the next batch (ADVICE r4)."""
+    backend, baseline, auto, calls = _run_auto(
+        monkeypatch, boom="RESOURCE_EXHAUSTED: out of memory allocating")
+    assert calls
+    assert _outcomes(auto) == _outcomes(baseline)
+    assert backend._FAST_AUTO["transient"] == 1
+    assert backend._FAST_AUTO["disabled"] is False
 
 
 def test_too_many_scalar_kinds_fall_back():
@@ -586,6 +597,32 @@ def test_group_budget_falls_back(monkeypatch):
     plan, reason = plan_fast(config, compiled, cols)
     assert plan is None
     assert "unrolled-loop budget" in reason
+
+
+def test_failure_classification(monkeypatch):
+    """ADVICE r4: transient runtime errors (device OOM etc) must not
+    permanently disable the fast path — but three in a row do. A lowering
+    or compile error is not classified at all: the caller re-raises it,
+    even when it names an exhausted resource (a VMEM overflow)."""
+    from tpusim.jaxe import backend
+
+    monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
+    monkeypatch.setitem(backend._FAST_AUTO, "transient", 0)
+    oom = RuntimeError("RESOURCE_EXHAUSTED: out of memory while allocating")
+    assert backend._note_fast_failure(oom)
+    assert backend._FAST_AUTO["disabled"] is False
+    assert backend._note_fast_failure(oom)
+    assert backend._FAST_AUTO["disabled"] is False
+    assert backend._note_fast_failure(oom)
+    assert backend._FAST_AUTO["disabled"] is True
+
+    monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
+    monkeypatch.setitem(backend._FAST_AUTO, "transient", 0)
+    for msg in ("Mosaic failed to compile TPU kernel: unsupported block",
+                "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem"):
+        assert not backend._note_fast_failure(RuntimeError(msg))
+    assert backend._FAST_AUTO["transient"] == 0
+    assert backend._FAST_AUTO["disabled"] is False
 
 
 def test_forced_mode_honors_disabled(monkeypatch):

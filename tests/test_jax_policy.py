@@ -981,10 +981,12 @@ def test_reset_fast_auto_restores_boot_state():
 
     backend._FAST_AUTO["disabled"] = True
     backend._FAST_AUTO["verified_sigs"].add(("sig",))
+    backend._FAST_AUTO["transient"] = 2
     backend._VICTIM_AUTO["disabled"] = True
     backend._VICTIM_AUTO["verified_sigs"].add(("v",))
     backend.reset_fast_auto()
-    assert backend._FAST_AUTO == {"disabled": False, "verified_sigs": set()}
+    assert backend._FAST_AUTO == {"disabled": False, "verified_sigs": set(),
+                                  "transient": 0}
     assert backend._VICTIM_AUTO == {"disabled": False,
                                     "verified_sigs": set()}
     assert backend._CHAOS == {"injector": None, "breaker": None,

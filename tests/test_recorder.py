@@ -220,7 +220,8 @@ class TestAutoTransitions:
 
         saved = {k: (set(v) if isinstance(v, set) else v)
                  for k, v in jb._FAST_AUTO.items()}
-        jb._FAST_AUTO.update(disabled=False, verified_sigs=set())
+        jb._FAST_AUTO.update(disabled=False, verified_sigs=set(),
+                             transient=0)
         register().reset()
         yield
         jb._FAST_AUTO.update(saved)
@@ -257,3 +258,28 @@ class TestAutoTransitions:
     def test_trust_bridge_counts(self):
         flight.note_auto_transition("trust", "('v', 2)")
         assert register().backend_auto_transitions.get("trust") == 1
+
+    def test_forced_discard_transient_then_permanent(self):
+        from tpusim.jaxe import backend as jb
+
+        for _ in range(3):
+            assert jb._note_fast_failure(
+                RuntimeError("RESOURCE_EXHAUSTED: hbm"))
+        m = register()
+        assert m.backend_auto_transitions.get("discard_transient") == 3
+        assert m.backend_auto_transitions.get("discard_permanent") == 1
+        assert jb._FAST_AUTO["disabled"]
+        # the discard is visible on the exposition surface (--metrics-out)
+        assert ('tpusim_backend_auto_transitions_total'
+                '{transition="discard_permanent"} 1') in m.expose()
+
+    def test_compile_failure_is_not_discarded(self):
+        """A lowering error is the caller's to raise: no transition is
+        recorded and the path is not quietly switched off."""
+        from tpusim.jaxe import backend as jb
+
+        assert not jb._note_fast_failure(ValueError("Mosaic lowering failed"))
+        m = register()
+        assert m.backend_auto_transitions.get("discard_permanent") == 0
+        assert m.backend_auto_transitions.get("discard_transient") == 0
+        assert not jb._FAST_AUTO["disabled"]

@@ -101,7 +101,7 @@ class _Compiled(Exception):
     pass
 
 
-def _compile_fast_scan(monkeypatch, one_chip, plan):
+def _compile_fast_scan(monkeypatch, one_chip, plan, **kwargs):
     """Drive fast_scan(interpret=False) to its first kernel call, compile
     that call for the described chip, and return the executable."""
     from tpusim.jaxe import fastscan
@@ -113,15 +113,16 @@ def _compile_fast_scan(monkeypatch, one_chip, plan):
         call = build(*args, **kwargs)
 
         def compile_first(*operands):
-            out["exe"] = call.lower(*_shapes(list(operands),
-                                             one_chip)).compile()
+            with jax.enable_x64(False):  # as fast_scan calls it
+                lowered = call.lower(*_shapes(list(operands), one_chip))
+            out["exe"] = lowered.compile()
             raise _Compiled
 
         return compile_first
 
     monkeypatch.setattr(fastscan, "_build_call", for_chip)
     with pytest.raises(_Compiled):
-        fastscan.fast_scan(plan, interpret=False)
+        fastscan.fast_scan(plan, interpret=False, **kwargs)
     return out["exe"]
 
 
@@ -136,15 +137,164 @@ def test_pallas_group_free_compiles(monkeypatch, one_chip):
     assert "tpu_custom_call" in exe.as_text()
 
 
-def test_pallas_policy_residue_compiles(monkeypatch, one_chip):
+# count mode (alwaysCheckAllPredicates), the NoExecute-only taint stage
+# and MostRequested scoring: the policy variants POLICY_RESIDUE lacks
+COUNT_MODE = {
+    "kind": "Policy", "apiVersion": "v1", "alwaysCheckAllPredicates": True,
+    "predicates": [
+        {"name": "PodFitsResources"},
+        {"name": "PodToleratesNodeNoExecuteTaints"},
+        {"name": "TestLabelsPresence",
+         "argument": {"labelsPresence": {"labels": ["foo"],
+                                         "presence": True}}}],
+    "priorities": [{"name": "MostRequestedPriority", "weight": 1}],
+}
+
+
+@pytest.mark.parametrize("policy", ["POLICY_RESIDUE", "COUNT_MODE"])
+def test_pallas_policy_residue_compiles(monkeypatch, one_chip, policy):
     import bench
     from tpusim.jaxe.fastscan import plan_fast, pol_const_of
 
-    config, compiled, cols, ptabs = _prepared(policy=bench.POLICY_RESIDUE)
+    config, compiled, cols, ptabs = _prepared(policy={
+        "POLICY_RESIDUE": bench.POLICY_RESIDUE,
+        "COUNT_MODE": COUNT_MODE}[policy])
     plan, why = plan_fast(config, compiled, cols, ptabs=ptabs)
     assert plan is not None, why
     assert pol_const_of(plan) is not None
     exe = _compile_fast_scan(monkeypatch, one_chip, plan)
+    assert "tpu_custom_call" in exe.as_text()
+
+
+def _group_workload(features, num_pods=64):
+    """A 5,000-node cluster with zone and rack labels, and pods that use
+    the pod-group `features` (ports, disk, spread, vol_zone, interpod,
+    maxpd): each kernel variant the Pallas route takes for them."""
+    from tpusim.api.snapshot import (
+        ClusterSnapshot,
+        make_node,
+        make_pod,
+        make_pod_volume,
+        make_pv,
+        make_pvc,
+    )
+    from tpusim.api.types import (
+        LABEL_ZONE_FAILURE_DOMAIN,
+        Affinity,
+        ContainerPort,
+        Service,
+    )
+
+    nodes = [make_node(f"n{i}", milli_cpu=16000, memory=64 * 1024**3,
+                       pods=110,
+                       labels={LABEL_ZONE_FAILURE_DOMAIN: f"z{i % 3}",
+                               "rack": f"r{i % 8}"})
+             for i in range(NODES)]
+    def term(app, key):
+        return {"labelSelector": {"matchLabels": {"app": app}},
+                "topologyKey": key}
+
+    existing = [make_pod(f"e{i}", node_name=f"n{i}", phase="Running",
+                         milli_cpu=100, labels={"app": f"a{i % 2}"})
+                for i in range(6)]
+    if "interpod" in features:
+        existing[0].spec.affinity = Affinity.from_obj({"podAntiAffinity": {
+            "requiredDuringSchedulingIgnoredDuringExecution": [
+                term("a1", LABEL_ZONE_FAILURE_DOMAIN)]}})
+    extra = {}
+    if "spread" in features:
+        extra["services"] = [Service.from_obj(
+            {"metadata": {"name": "web", "namespace": "default"},
+             "spec": {"selector": {"app": "a0"}}})]
+    if "vol_zone" in features:
+        extra["pvs"] = [make_pv(
+            "pv-z", labels={LABEL_ZONE_FAILURE_DOMAIN: "z1"})]
+        extra["pvcs"] = [make_pvc("claim-z", volume_name="pv-z")]
+    pods = []
+    for i in range(num_pods):
+        kw = {"labels": {"app": f"a{i % 2}"}}
+        vols = []
+        if "interpod" in features and i % 4 == 0:
+            kw["affinity"] = {
+                "podAntiAffinity": {
+                    "requiredDuringSchedulingIgnoredDuringExecution": [
+                        term("a1", "rack")]},
+                "podAffinity": {
+                    "preferredDuringSchedulingIgnoredDuringExecution": [
+                        {"weight": 10, "podAffinityTerm": term(
+                            "a0", LABEL_ZONE_FAILURE_DOMAIN)}]}}
+        if "interpod" in features and i % 4 == 2:
+            kw["affinity"] = {
+                "podAffinity": {
+                    "requiredDuringSchedulingIgnoredDuringExecution": [
+                        term("a0", "rack")]},
+                "podAntiAffinity": {
+                    "preferredDuringSchedulingIgnoredDuringExecution": [
+                        {"weight": 5, "podAffinityTerm": term(
+                            "a1", "rack")}]}}
+        if "vol_zone" in features and i % 5 == 0:
+            vols.append(make_pod_volume("z", pvc="claim-z"))
+        if "disk" in features and i % 3 == 0:
+            vols.append(make_pod_volume("d", {"rbd": {
+                "monitors": ["m"], "pool": "p", "image": f"x{i % 2}"}}))
+        if "maxpd" in features and i % 2 == 0:
+            vols.append(make_pod_volume("b", {"awsElasticBlockStore": {
+                "volumeID": f"ebs{i % 3}"}}))
+        p = make_pod(f"p{i}", milli_cpu=(1 + i % 7) * 100,
+                     memory=(1 + i % 5) * 2**26, volumes=vols or None, **kw)
+        if "ports" in features and i % 4 == 1:
+            p.spec.containers[0].ports = [ContainerPort.from_obj(
+                {"containerPort": 80, "hostPort": 8080 + i % 2})]
+        pods.append(p)
+    return ClusterSnapshot(nodes=nodes, pods=existing, **extra), pods
+
+
+GROUP_FEATURES = ("ports", "disk", "spread", "vol_zone", "interpod", "maxpd")
+
+
+@pytest.mark.parametrize("features", [
+    *((f,) for f in GROUP_FEATURES), GROUP_FEATURES],
+    ids=[*GROUP_FEATURES, "every_group_feature"])
+def test_pallas_group_variant_compiles(monkeypatch, one_chip, features):
+    """Each pod-group feature's kernel variant (presence carry, inter-pod
+    domain carry, volume-count carry), and all of them in one kernel."""
+    import bench
+    from tpusim.jaxe import ensure_x64
+    from tpusim.jaxe.fastscan import ip_const_of, mp_const_of, plan_fast
+
+    ensure_x64()
+    snap, pods = _group_workload(set(features))
+    compiled, config, _, _, _, cols = bench._prepare(snap, pods,
+                                                    to_device=False)
+    assert not compiled.unsupported, compiled.unsupported
+    plan, why = plan_fast(config, compiled, cols)
+    assert plan is not None, why
+    assert plan.num_groups > 0 or plan.has_vol_zone or \
+        mp_const_of(plan) is not None
+    assert (ip_const_of(plan) is not None) == ("interpod" in features)
+    assert (mp_const_of(plan) is not None) == ("maxpd" in features)
+    exe = _compile_fast_scan(monkeypatch, one_chip, plan)
+    assert "tpu_custom_call" in exe.as_text()
+
+
+def test_pallas_preempt_carry_compiles(monkeypatch, one_chip):
+    """The preemption hybrid's call: a plan over placed pods, resumed from
+    an explicit carry, one power-of-two bucket, carry returned."""
+    import bench
+    from tpusim.api.snapshot import zipf_workload
+    from tpusim.jaxe import ensure_x64
+    from tpusim.jaxe.fastscan import init_carry, plan_fast
+
+    ensure_x64()
+    snap, pods = zipf_workload(64, NODES, priorities=True)
+    compiled, config, _, _, _, cols = bench._prepare(snap, pods,
+                                                    to_device=False)
+    plan, why = plan_fast(config, compiled, cols, placed_pods=snap.pods)
+    assert plan is not None, why
+    exe = _compile_fast_scan(
+        monkeypatch, one_chip, plan, chunk=16, start=8, stop=20,
+        carry_in=init_carry(plan, rr=3), return_carry=True,
+        fixed_chunk=True)
     assert "tpu_custom_call" in exe.as_text()
 
 

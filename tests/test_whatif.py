@@ -237,6 +237,28 @@ class TestFastLoop:
         with pytest.raises(RuntimeError, match="mosaic said no"):
             run_what_if(scenarios)
 
+    def test_transient_failure_falls_back_to_vmap(self, monkeypatch):
+        """A one-off device OOM reruns the batch on the vmap program, on
+        the same device, and leaves the fast path on (ADVICE r4)."""
+        scenarios = self._scenarios()
+        vmap_results = run_what_if(scenarios)
+        from tpusim.jaxe import backend, fastscan
+
+        monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
+        monkeypatch.setitem(backend._FAST_AUTO, "verified_sigs", set())
+        monkeypatch.setattr(backend, "_fast_path_enabled",
+                            lambda: (True, True))
+        monkeypatch.setattr(
+            fastscan, "fast_scan",
+            lambda plan, **kw: (_ for _ in ()).throw(
+                RuntimeError("RESOURCE_EXHAUSTED: out of memory")))
+        results = run_what_if(scenarios)
+        assert backend._FAST_AUTO["transient"] == 1
+        assert backend._FAST_AUTO["disabled"] is False
+        for fr, vr in zip(results, vmap_results):
+            assert placements_key(fr.placements) == \
+                placements_key(vr.placements)
+
 
 def test_fuzz_what_if_fast_loop_parity(monkeypatch):
     """Randomized eligible batches: the fast loop must match the vmap

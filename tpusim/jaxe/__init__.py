@@ -30,9 +30,11 @@ import jax
 
 def compile_cache_dir():
     """Where this package points JAX's persistent compilation cache: None
-    when JAX_COMPILATION_CACHE_DIR is set (JAX reads that itself), else
-    the fixed path <checkout>/.jax_cache. The path is part of the cache's
-    key, so it must not move between processes."""
+    when JAX_COMPILATION_CACHE_DIR is set (JAX reads that, and the rest of
+    the cache settings, itself), else the fixed path <checkout>/.jax_cache,
+    the directory above this package — a checkout when run from source,
+    which is how this repo is run. The path must not move between
+    processes, or no process finds another's entries."""
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return None
     return os.path.join(
@@ -42,7 +44,7 @@ def compile_cache_dir():
 
 if compile_cache_dir() is not None:
     jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 _x64_enabled = False

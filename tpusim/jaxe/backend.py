@@ -69,9 +69,19 @@ log = logging.getLogger(__name__)
 # shape pads + feature flags) whose first large-enough batch verified —
 # each distinct Pallas/Mosaic kernel variant earns trust separately
 # (ADVICE r4: a process whose first verified batch was group-free must not
-# run the group-featured kernel unverified). A kernel that fails to lower,
-# compile or run raises: there is no quiet route around the device.
-_FAST_AUTO = {"disabled": False, "verified_sigs": set()}
+# run the group-featured kernel unverified); `transient` counts consecutive
+# runtime failures such as a one-off device OOM — that batch reruns on the
+# XLA scan on the same device, and three in a row disable the path. Any
+# other kernel failure (a lowering or compile error) raises: there is no
+# quiet route around the device.
+_FAST_AUTO = {"disabled": False, "verified_sigs": set(), "transient": 0}
+
+_TRANSIENT_MARKERS = ("RESOURCE_EXHAUSTED", "out of memory", "OOM",
+                      "UNAVAILABLE", "DEADLINE_EXCEEDED", "CANCELLED")
+# a compile-time refusal can carry a runtime marker too (a VMEM or HBM
+# overflow is RESOURCE_EXHAUSTED) and is never transient
+_COMPILE_MARKERS = ("Mosaic", "compil", "lower", "vmem", "VMEM")
+_MAX_TRANSIENT_FAILURES = 3
 
 
 def reset_fast_auto() -> None:
@@ -83,6 +93,7 @@ def reset_fast_auto() -> None:
     tests/conftest.py; production code never calls it."""
     _FAST_AUTO["disabled"] = False
     _FAST_AUTO["verified_sigs"] = set()
+    _FAST_AUTO["transient"] = 0
     _VICTIM_AUTO["disabled"] = False
     _VICTIM_AUTO["verified_sigs"] = set()
     _SHARD_AUTO["disabled"] = False
@@ -168,6 +179,29 @@ def plan_signature(plan) -> tuple:
     return sig
 
 
+def _note_fast_failure(exc: Exception) -> bool:
+    """Classify a fast-path failure. A transient runtime error (ADVICE r4)
+    returns True: the caller reruns that batch on the XLA scan, and
+    _MAX_TRANSIENT_FAILURES in a row disable the path for the process.
+    Anything else returns False and the caller re-raises it."""
+    msg = f"{type(exc).__name__}: {exc}"
+    if (not any(m in msg for m in _TRANSIENT_MARKERS)
+            or any(m in msg for m in _COMPILE_MARKERS)):
+        return False
+    _FAST_AUTO["transient"] += 1
+    flight.note_auto_transition("discard_transient")
+    if _FAST_AUTO["transient"] >= _MAX_TRANSIENT_FAILURES:
+        _FAST_AUTO["disabled"] = True
+        flight.note_auto_transition("discard_permanent")
+        log.warning("pallas fast path: %d consecutive transient failures; "
+                    "disabling it for this process", _FAST_AUTO["transient"])
+    else:
+        log.warning("pallas fast path: transient failure %d/%d (%s); this "
+                    "batch reruns on the XLA scan", _FAST_AUTO["transient"],
+                    _MAX_TRANSIENT_FAILURES, msg)
+    return True
+
+
 def _auto_verify_and_pin(config, compiled, cols, choices, counts,
                          sig: tuple, limit: int = None,
                          statics=None, carry=None) -> bool:
@@ -220,8 +254,8 @@ def _fast_path_enabled() -> tuple[bool, bool]:
     far slower than the XLA scan — so non-TPU backends require the explicit
     opt-in with TPUSIM_FAST_INTERPRET=1 (correctness runs).
 
-    A process-wide `disabled` flag (set by a verify disagreement) is
-    honored in BOTH modes: a kernel that miscomputed once is not
+    A process-wide `disabled` flag (a verify disagreement, or repeated
+    transient failures) is honored in BOTH modes: a failing kernel is not
     re-attempted, and re-uploaded, on every batch (ADVICE r4)."""
     env = os.environ.get("TPUSIM_FAST")
     if env == "0":
@@ -720,7 +754,8 @@ class JaxBackend:
         def _discard_fast_path():
             # pay the uploads the fast path deferred and rebuild the
             # XLA-scan inputs (set via nonlocal) with a fresh dispatch
-            # clock; _auto_verify_and_pin has disabled the path already
+            # clock; whether the path stays disabled for the process is
+            # the caller's call (_note_fast_failure / _auto_verify_and_pin)
             nonlocal fplan, statics, carry, use_chunks, xs, dispatch_start
             fplan = None
             statics = _xla_statics()
@@ -734,17 +769,27 @@ class JaxBackend:
         if fplan is not None:
             from tpusim.jaxe.fastscan import fast_scan
 
-            with flight.profiled("tpusim:fast_scan"):
-                choices, counts, _adv = fast_scan(fplan)
-            if fast_verify and not _auto_verify_and_pin(
-                    config, compiled, cols, choices, counts, fast_sig,
-                    statics=_xla_statics(), carry=_xla_carry()):
-                # the kernel lowered but miscomputed: the guardrail
-                # already disabled it process-wide; rerun on XLA
+            try:
+                with flight.profiled("tpusim:fast_scan"):
+                    choices, counts, _adv = fast_scan(fplan)
+            except Exception as exc:
+                # a transient runtime error reruns this batch on the XLA
+                # scan, on the same device; a lowering or compile error
+                # propagates
+                if not _note_fast_failure(exc):
+                    raise
                 _discard_fast_path()
-            elif auto_mode and not fast_verify:
-                # already-pinned variant ran without re-verification
-                flight.note_auto_transition("trust", str(fast_sig))
+            else:
+                _FAST_AUTO["transient"] = 0
+                if fast_verify and not _auto_verify_and_pin(
+                        config, compiled, cols, choices, counts, fast_sig,
+                        statics=_xla_statics(), carry=_xla_carry()):
+                    # the kernel lowered but miscomputed: the guardrail
+                    # already disabled it process-wide; rerun on XLA
+                    _discard_fast_path()
+                elif auto_mode and not fast_verify:
+                    # already-pinned variant ran without re-verification
+                    flight.note_auto_transition("trust", str(fast_sig))
         # node-sharded route decision (ISSUE 16): TPUSIM_SHARDS=k > 1 runs
         # the same fused scan as a shard_map over a k-device node mesh —
         # bit-identical placements via cross-shard collectives, so every

@@ -1420,8 +1420,8 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                 # present on the node
                 port_bad = fail_cond & False
                 for g2 in range(gpad):
-                    port_bad = port_bad | jnp.where(
-                        prow_r[j, g2] != 0, pres_rows[g2] > 0, False)
+                    port_bad = port_bad | (
+                        (prow_r[j, g2] != 0) & (pres_rows[g2] > 0))
                 if general_on:
                     fail_general = fail_general | port_bad
                     bits_general = bits_general | (
@@ -1529,8 +1529,8 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                 # conflicts with the volume set of any group present
                 fail_disk = fail_cond & False
                 for g2 in range(gpad):
-                    fail_disk = fail_disk | jnp.where(
-                        drow_r[j, g2] != 0, pres_rows[g2] > 0, False)
+                    fail_disk = fail_disk | (
+                        (drow_r[j, g2] != 0) & (pres_rows[g2] > 0))
                 stages.append(
                     (fail_disk, jnp.int32(1) << BIT_DISK_CONFLICT))
             emit_label(NO_DISK_CONFLICT_PRED)
@@ -1605,15 +1605,16 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                     self_t = iprow_r[j, L.aff_self + t] != 0
                     unpl_t = iprow_r[j, L.aff_unpl + t] != 0
                     valid_dom = domsel > 0
-                    on_node = mcount > 0
-                    term_matches = jnp.where(host_t, valid_dom & on_node,
-                                             valid_dom & (dc_at > 0))
+                    # selects stay on int32: Mosaic selects no i1 vectors
+                    term_matches = valid_dom & (
+                        jnp.where(host_t, mcount, dc_at) > 0)
                     # hostname terms scan only this node's pods; otherwise
                     # "a matching pod exists" is global (incl. unplaced
                     # snapshot pods)
                     exists_any = (jnp.sum(mcount, dtype=jnp.int32) > 0) \
                         | unpl_t
-                    exists = jnp.where(host_t, on_node, exists_any)
+                    exists = jnp.where(host_t, mcount,
+                                       exists_any.astype(jnp.int32)) > 0
                     term_ok = term_matches | ((~exists) & self_t)
                     aff_fail = aff_fail | (valid_t & ~term_ok)
                 aff_fail = aff_fail | (iprow_r[j, L.aff_err] != 0)
@@ -1625,8 +1626,8 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                     valid_t = iprow_r[j, L.anti_valid + t] != 0
                     host_t = iprow_r[j, L.anti_host + t] != 0
                     bvalid_dom = bdomsel > 0
-                    b_matches = jnp.where(host_t, bvalid_dom & (bmcount > 0),
-                                          bvalid_dom & (bdc_at > 0))
+                    b_matches = bvalid_dom & (
+                        jnp.where(host_t, bmcount, bdc_at) > 0)
                     anti_fail = anti_fail | (valid_t & b_matches)
                 anti_fail = anti_fail | (iprow_r[j, L.anti_err] != 0)
                 # existing pods' anti-affinity vs me (symmetric; runs first
@@ -2077,24 +2078,7 @@ def _build_call(npad: int, k: int, most_requested: bool, num_bits: int,
     )
     call = pl.pallas_call(kernel, grid_spec=grid_spec,
                           out_shape=out_shape, interpret=interpret)
-    return _traced_x32(jax.jit(lambda *args: call(*args)))
-
-
-def _traced_x32(jitted):
-    """`jitted`, traced and lowered with x64 off. Mosaic has no 64-bit
-    types, and under the backend's process-wide x64 every Python scalar in
-    the kernel body would enter as a weak int64; the operands and results
-    are int32 either way, so only the trace changes."""
-    def run(*args):
-        with jax.enable_x64(False):
-            return jitted(*args)
-
-    def lower(*args):
-        with jax.enable_x64(False):
-            return jitted.lower(*args)
-
-    run.lower = lower
-    return run
+    return jax.jit(call)
 
 
 def verify_against_xla(config, compiled, cols, choices, counts,
@@ -2334,7 +2318,11 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
                 args.append(jnp.asarray(grow(plan.sa_match_row[sl],
                                              polc.fd8)))
                 args.append(saval_dev)
-        out = call(*args)
+        # Mosaic has no 64-bit types, and under the backend's process-wide
+        # x64 every Python scalar in the kernel body would trace as a weak
+        # int64; the operands and results are int32 either way
+        with jax.enable_x64(False):
+            out = call(*args)
         carry = list(out[:7])
         misc = out[7]
         oat = 11

@@ -586,6 +586,7 @@ def run_with_preemption(pods: List[Pod], snapshot: ClusterSnapshot,
         _FAST_AUTO,
         _auto_verify_and_pin,
         _fast_path_enabled,
+        _note_fast_failure,
         plan_signature,
     )
     from tpusim.jaxe.fastscan import fast_scan, init_carry, plan_fast, rearm_carry
@@ -739,11 +740,23 @@ def run_with_preemption(pods: List[Pod], snapshot: ClusterSnapshot,
                 # never bind or advance rr)
                 bucket = _next_pow2(take)
                 if fplan is not None:
-                    with flight.profiled("tpusim:fast_scan"):
-                        choices, counts, advanced, fc_out = fast_scan(
-                            fplan, chunk=bucket, start=off,
-                            stop=off + take, carry_in=fcarry,
-                            return_carry=True, fixed_chunk=True)
+                    try:
+                        with flight.profiled("tpusim:fast_scan"):
+                            choices, counts, advanced, fc_out = fast_scan(
+                                fplan, chunk=bucket, start=off,
+                                stop=off + take, carry_in=fcarry,
+                                return_carry=True, fixed_chunk=True)
+                    except Exception as exc:
+                        # a transient runtime error: the outer loop
+                        # recompiles feed[pos:] and re-decides the engine;
+                        # a lowering or compile error propagates
+                        if not _note_fast_failure(exc):
+                            raise
+                        if dsp:
+                            dsp.set("error", type(exc).__name__)
+                            dsp.end()
+                        break
+                    _FAST_AUTO["transient"] = 0
                     if fverify:
                         # ONCE, on the run's first chunk only (the plan
                         # gate guarantees pos==0, rr_start==0 there):

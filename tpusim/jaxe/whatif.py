@@ -400,13 +400,15 @@ def _decode_batch(scenarios, compiled_list, choices_b,
 def _try_fast_loop(scenarios, config, compiled_list, ptabs_list, host_trees):
     """Run every scenario through the Pallas fast path sequentially;
     returns the decoded results, or None to fall back to the batched vmap
-    program (ineligible scenario, fast path off/disabled, or a failed AUTO
-    self-verification). A kernel that fails to lower or run raises."""
+    program (ineligible scenario, fast path off/disabled, a transient
+    runtime failure, or a failed AUTO self-verification). A kernel that
+    fails to lower or compile raises."""
     from tpusim.framework.metrics import register
     from tpusim.jaxe.backend import (
         _FAST_AUTO,
         _auto_verify_and_pin,
         _fast_path_enabled,
+        _note_fast_failure,
         _note_fast_fallback,
         plan_signature,
     )
@@ -428,7 +430,13 @@ def _try_fast_loop(scenarios, config, compiled_list, ptabs_list, host_trees):
     choices_list = []
     counts_list = []
     for b, plan in enumerate(plans):
-        choices, counts, _adv = fast_scan(plan)
+        try:
+            choices, counts, _adv = fast_scan(plan)
+        except Exception as exc:
+            if not _note_fast_failure(exc):
+                raise
+            return None
+        _FAST_AUTO["transient"] = 0
         sig = plan_signature(plan)
         if auto_mode and sig not in _FAST_AUTO["verified_sigs"]:
             # every scenario verifies until its kernel variant is trusted —

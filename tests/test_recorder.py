@@ -14,6 +14,7 @@ from tpusim import cli
 from tpusim.api.snapshot import ClusterSnapshot, make_node, make_pod
 from tpusim.framework.metrics import register
 from tpusim.obs import recorder as flight
+from tpusim.obs import tracectx
 from tpusim.obs.recorder import NOOP_SPAN, FlightRecorder
 from tpusim.simulator import run_simulation
 
@@ -124,14 +125,30 @@ class TestDisabledPath:
 
 def _run_traced(backend):
     nodes, pods = _quickstart()
+    ids = itertools.count(1)
+    tracectx.set_id_source(lambda: f"{next(ids):016x}")
     rec = flight.install(FlightRecorder(clock=_clock()))
     try:
         status = run_simulation(pods, ClusterSnapshot(nodes=nodes),
                                 backend=backend)
     finally:
         flight.uninstall()
+        tracectx.set_id_source(None)
     assert len(status.successful_pods) == 4
     return rec
+
+
+# a root span's measured args: process CPU time and the collector's, the
+# only values an injected clock does not pin
+MEASURED = ("cpu_ms", "gc_ms", "gc_collections")
+
+
+def _clocked_json(rec):
+    doc = rec.to_chrome()
+    for ev in doc["traceEvents"]:
+        for key in MEASURED:
+            (ev.get("args") or {}).pop(key, None)
+    return json.dumps(doc, sort_keys=True)
 
 
 class TestGoldens:
@@ -164,9 +181,10 @@ class TestGoldens:
         assert any(n.startswith("route:") for n in by_cat["device"])
 
     def test_jax_backend_byte_stable(self):
-        a = _run_traced("jax").to_chrome_json()
-        b = _run_traced("jax").to_chrome_json()
-        assert a == b
+        rec = _run_traced("jax")
+        [root] = [e for e in rec.events if e["name"] == "simulate"]
+        assert all(root["args"][k] >= 0 for k in MEASURED)
+        assert _clocked_json(rec) == _clocked_json(_run_traced("jax"))
 
 
 class TestChromeValidity:

@@ -307,6 +307,17 @@ def test_traced_stream_run_lints_clean_with_exemplars(traced, tmp_path):
     doc = json.loads(traced.to_chrome_json())
     lint = _load_tool("trace_lint")
     assert lint.lint_trace(doc) == []
+    # the session records each loop cycle's ingest once, as its own
+    # root, on the trace of the cycle it feeds
+    ingests = [e for e in doc["traceEvents"]
+               if e.get("name") == "stream_ingest"]
+    cycles = [e for e in doc["traceEvents"]
+              if e.get("name") == "stream_cycle"]
+    assert len(ingests) == len(cycles) == 4
+    assert all(e["args"]["events"] >= 0 and "gc_ms" in e["args"]
+               for e in ingests)
+    assert [e["args"]["trace_id"] for e in ingests] == \
+        [e["args"]["trace_id"] for e in cycles]
     # the latency exemplars the run stamped resolve back into the trace
     exposition = register().expose()
     assert 'trace_id="' in exposition, "no exemplars on the exposition"

@@ -607,6 +607,9 @@ class JaxBackend:
             flight.note_route("reference_fallback", len(pods))
             return self._reference(pods, snapshot)
 
+        # the engine configuration and the route: everything between the
+        # staging and the Pallas plan
+        esp = flight.span("engine_config")
         hard_weight = self.hard_pod_affinity_symmetric_weight
         if cp is not None and cp.hard_weight is not None:
             hard_weight = cp.hard_weight
@@ -679,10 +682,17 @@ class JaxBackend:
             log.info("pallas fast path deferred: %d pods is below "
                      "the self-verification threshold; using the "
                      "XLA scan", len(pods))
+        if esp:
+            esp.set("fast", bool(fast_on))
+            esp.end()
         if fast_on:
             from tpusim.jaxe.fastscan import plan_fast
 
-            fplan, why = plan_fast(config, compiled, cols, ptabs=ptabs)
+            with flight.span("plan_fast") as psp:
+                fplan, why = plan_fast(config, compiled, cols, ptabs=ptabs)
+                if psp:
+                    psp.set("pods", len(pods))
+                    psp.set("nodes", len(snapshot.nodes))
             if fplan is None:
                 _note_fast_fallback(metrics, why)
                 log.info("pallas fast path ineligible (%s); using the "
@@ -725,6 +735,8 @@ class JaxBackend:
                 carry = carry._replace(sa_lock=ptabs.sa_lock_init)
             return carry
 
+        usp = (flight.span("batch_upload") if fplan is None
+               else flight.NOOP_SPAN)
         statics = None if fplan is not None else _xla_statics()
         # Batches beyond TPUSIM_SCAN_CHUNK pods run through the
         # double-buffered chunked scan: pod columns stay host-side and stream
@@ -737,6 +749,9 @@ class JaxBackend:
             carry = _xla_carry()
             xs = (pod_columns_to_host(cols) if use_chunks
                   else pod_columns_to_device(cols))
+        if usp:
+            usp.set("pods", len(pods))
+            usp.end()
         # On TPU the per-pod filter→score→select→bind pipeline is one fused
         # device program, so the whole batch dispatch lands in the algorithm
         # histogram (the per-phase split of metrics.go has no device analog);
@@ -816,7 +831,8 @@ class JaxBackend:
         explain_lanes = None
         final_carry = None  # bound-and-dropped unless analytics reads it
         if fplan is None:  # fast path off, ineligible, or discarded above
-            with flight.profiled("tpusim:schedule_scan"):
+            with flight.span("scan_launch", "device"), \
+                    flight.profiled("tpusim:schedule_scan"):
                 if shard_mesh is not None:
                     (final_carry, choices, counts,
                      shard_statics) = _dispatch_sharded(
@@ -832,8 +848,9 @@ class JaxBackend:
                 else:
                     (final_carry, choices, counts,
                      _) = schedule_scan(config, carry, statics, xs)
-        choices = np.asarray(choices)
-        counts = np.asarray(counts)
+        with flight.span("device_wait", "device"):
+            choices = np.asarray(choices)
+            counts = np.asarray(counts)
         if shard_mesh is not None:
             # verify-then-trust, the same seam as the fast path: the first
             # batch per (shard count, config) replays its leading pods

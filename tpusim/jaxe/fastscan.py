@@ -136,6 +136,7 @@ from tpusim.jaxe.state import (
     BIT_TAINTS_NOT_TOLERATED,
     BIT_VOLUME_ZONE_CONFLICT,
 )
+from tpusim.obs import recorder as flight
 
 INT_LIMIT = 1 << 29          # per-value bound after gcd reduction
 GHOST_REQ = 1 << 30          # > any reduced allocatable: never feasible
@@ -2140,6 +2141,8 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
     compiled kernel per bucket size instead of tracing per tail length
     (ghost padding rows are infeasible everywhere: no carry/rr effect).
     """
+    # the plan's uploads, the kernel calls, then the wait for their outputs
+    usp = flight.span("plan_upload", "device")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if not chunk:
@@ -2209,6 +2212,13 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
             saadom_dev = jnp.asarray(plan.saa_dom_tbl)
         if polc.has_sa:
             saval_dev = jnp.asarray(plan.sa_val_tbl)
+
+    if usp:
+        usp.set("nodes", npad)
+        usp.set("bytes", sum(int(a.nbytes) for a in statics + tables
+                             + carry + [misc]))
+        usp.end()
+    lsp = flight.span("kernel_launch", "device")
 
     def col(a, fill):
         out = np.full(k, fill, dtype=np.int32)
@@ -2344,15 +2354,20 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
             # dispatch-front progress; completion trails by <= sync_every
             progress(ci + 1, num_chunks, sl.stop)
 
-    while pending:
-        drain_one()
-    if not results:
-        out3 = (np.zeros(0, np.int32), np.zeros((0, num_bits), np.int32),
-                np.zeros(0, bool))
-    else:
-        out3 = (np.concatenate([r[0] for r in results]),
-                np.concatenate([r[1] for r in results]),
-                np.concatenate([r[2] for r in results]))
+    if lsp:
+        lsp.set("pods", span)
+        lsp.set("chunks", num_chunks)
+        lsp.end()
+    with flight.span("device_wait", "device"):
+        while pending:
+            drain_one()
+        if not results:
+            out3 = (np.zeros(0, np.int32),
+                    np.zeros((0, num_bits), np.int32), np.zeros(0, bool))
+        else:
+            out3 = (np.concatenate([r[0] for r in results]),
+                    np.concatenate([r[1] for r in results]),
+                    np.concatenate([r[2] for r in results]))
     if not return_carry:
         return out3
     carry_out = FastCarry(

@@ -17,6 +17,12 @@ Design constraints (ISSUE 2):
   `time.perf_counter` directly; the clock is a constructor argument so
   goldens can pin span structure byte-for-byte.
 
+One operation (a capacity query, a stream cycle, a what-if, an ingest)
+opens a *root* span (`root()`): it carries the operation's trace id and
+the process CPU time and garbage-collector time spent inside it, the two
+readings that tell a host stall from host work. Those args are measured,
+not clocked: they are the only values an injected clock does not pin.
+
 Counters/histograms do NOT live here — they land in the
 `framework/metrics.py` registry (`tpusim_backend_*` families) so the
 reference exposition surface stays unified; the `note_*` helpers below
@@ -25,6 +31,8 @@ bridge both sinks.
 
 from __future__ import annotations
 
+import contextvars
+import gc
 import json
 import threading
 import time
@@ -89,6 +97,61 @@ class Span:
 
     def end(self) -> None:
         self.rec._finish(self)
+
+
+class RootSpan(Span):
+    """The span of one whole operation. While it is open it is the
+    thread's root (a nested `root()` is a no-op) and, when no trace context
+    was active, its own fresh context is; on end it records `cpu_ms`
+    (`time.process_time` across it, all threads), `gc_ms` and
+    `gc_collections` (the collections that ran inside it, `_GC`)."""
+
+    __slots__ = ("_cpu0", "_gc0", "_ctx_token", "_root_token")
+
+    def __init__(self, rec: "FlightRecorder", name: str, cat: str):
+        super().__init__(rec, name, cat, 0.0)
+        self._root_token = _open_root.set(self)
+        self._ctx_token = None
+        if _current_trace() is None:
+            self._ctx_token = _tracectx.attach(_tracectx.start())
+        self._gc0 = (_GC.seconds, _GC.count)
+        self._cpu0 = time.process_time()
+        self.t0 = rec.clock()
+
+    def end(self) -> None:
+        cpu = time.process_time() - self._cpu0
+        self.set("cpu_ms", round(cpu * 1e3, 3))
+        self.set("gc_ms", round((_GC.seconds - self._gc0[0]) * 1e3, 3))
+        self.set("gc_collections", _GC.count - self._gc0[1])
+        self.rec._finish(self)
+        _tracectx.detach(self._ctx_token)
+        _open_root.reset(self._root_token)
+
+
+class _GcTotals:
+    """The garbage collector's running totals (a gc.callbacks hook that
+    `install` adds and `uninstall` removes): seconds and collections so
+    far. Root spans read them at start and end."""
+
+    __slots__ = ("seconds", "count", "_t0")
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.count = 0
+        self._t0: Optional[float] = None
+
+    def __call__(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.seconds += time.perf_counter() - self._t0
+            self.count += 1
+            self._t0 = None
+
+
+_GC = _GcTotals()
+_open_root: contextvars.ContextVar[Optional[RootSpan]] = \
+    contextvars.ContextVar("tpusim_open_root", default=None)
 
 
 class FlightRecorder:
@@ -319,12 +382,16 @@ _active: Optional[FlightRecorder] = None
 def install(rec: FlightRecorder) -> FlightRecorder:
     global _active
     _active = rec
+    if _GC not in gc.callbacks:
+        gc.callbacks.append(_GC)
     return rec
 
 
 def uninstall() -> None:
     global _active
     _active = None
+    if _GC in gc.callbacks:
+        gc.callbacks.remove(_GC)
 
 
 def get_recorder() -> Optional[FlightRecorder]:
@@ -341,6 +408,16 @@ def span(name: str, cat: str = "host") -> Any:
     if rec is None:
         return NOOP_SPAN
     return rec.span(name, cat)
+
+
+def root(name: str, cat: str = "host") -> Any:
+    """The root span of one operation (`RootSpan`) when a recorder is
+    installed and no root is open in this thread, else the shared no-op:
+    roots do not nest, so an operation that calls another is one root."""
+    rec = _active
+    if rec is None or _open_root.get() is not None:
+        return NOOP_SPAN
+    return RootSpan(rec, name, cat)
 
 
 def instant(name: str, cat: str = "host",
@@ -543,7 +620,10 @@ _annotation_cls: Any = None
 
 def profiled(name: str) -> Any:
     """`jax.profiler.TraceAnnotation` context so XLA profiles line up
-    with recorder spans; degrades to a null context without jax."""
+    with recorder spans; degrades to a null context without jax. With a
+    recorder installed the scope carries `rec_us`, the recorder's clock
+    (`now_us`) at its start: a profile's event time minus `rec_us * 1000`
+    is the offset that shifts the recorder's timeline onto the profile."""
     global _annotation_cls
     if _annotation_cls is None:
         try:
@@ -551,5 +631,10 @@ def profiled(name: str) -> Any:
             _annotation_cls = TraceAnnotation
         except Exception:  # pragma: no cover - jax is a hard dep in practice
             import contextlib
-            _annotation_cls = contextlib.nullcontext
-    return _annotation_cls(name)
+
+            def _annotation_cls(name, **_):
+                return contextlib.nullcontext()
+    rec = _active
+    if rec is None:
+        return _annotation_cls(name)
+    return _annotation_cls(name, rec_us=rec.now_us())

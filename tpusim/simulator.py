@@ -884,82 +884,104 @@ def run_simulation(pods: List[Pod], snapshot: ClusterSnapshot,
             cc.status.chaos_summary = chaos_engine.summary()
         return cc.status
     if backend == "jax":
-        from tpusim.backends import get_backend
+        # the root span of one query (a no-op without a recorder): its
+        # trace id stamps every span below, and its args say how much of
+        # it was the process on the CPU and how much the collector
+        with flight.root("simulate") as ssp:
+            if ssp:
+                ssp.set("pods", len(pods))
+                ssp.set("nodes", len(snapshot.nodes))
+            from tpusim.backends import get_backend
 
-        if enable_volume_scheduling:
-            raise ValueError("--enable-volume-scheduling requires --backend "
-                             "reference (delayed PV binding is stateful "
-                             "host-side matching)")
-        from tpusim.gang.group import has_gangs
+            if enable_volume_scheduling:
+                raise ValueError("--enable-volume-scheduling requires "
+                                 "--backend reference (delayed PV binding "
+                                 "is stateful host-side matching)")
+            from tpusim.gang.group import has_gangs
 
-        if enable_pod_priority:
-            if has_gangs(pods):
-                # preemption interplay (gang release, nomination cleanup)
-                # lives in the host orchestrator's queue/store machinery;
-                # the device hybrid has no group-aware retry loop
-                import logging
+            if enable_pod_priority:
+                if has_gangs(pods):
+                    # preemption interplay (gang release, nomination
+                    # cleanup) lives in the host orchestrator's queue/store
+                    # machinery; the device hybrid has no group-aware retry
+                    # loop
+                    import logging
 
-                logging.getLogger(__name__).warning(
-                    "pod groups with PodPriority are host-bound: running "
-                    "the reference orchestrator instead of the jax backend")
-                return run_simulation(
-                    pods, snapshot, provider=provider, backend="reference",
-                    scheduler_name=scheduler_name, enable_pod_priority=True,
-                    policy=policy, events=events,
-                    feature_gates=feature_gates, chaos_plan=chaos_plan)
-            # host-device hybrid: device scan schedules, the exact host
-            # Preempt pipeline fires on failures (jaxe/preempt.py)
-            from tpusim.jaxe.preempt import run_with_preemption
+                    logging.getLogger(__name__).warning(
+                        "pod groups with PodPriority are host-bound: running "
+                        "the reference orchestrator instead of the jax "
+                        "backend")
+                    return run_simulation(
+                        pods, snapshot, provider=provider,
+                        backend="reference", scheduler_name=scheduler_name,
+                        enable_pod_priority=True,
+                        policy=policy, events=events,
+                        feature_gates=feature_gates, chaos_plan=chaos_plan)
+                # host-device hybrid: device scan schedules, the exact host
+                # Preempt pipeline fires on failures (jaxe/preempt.py)
+                from tpusim.jaxe.preempt import run_with_preemption
 
-            return run_with_preemption(pods, snapshot, provider=provider,
-                                       incremental=incremental)
-        jax_backend = get_backend("jax", provider=provider, policy=policy,
-                                  compiled_policy=compiled_policy)
-        feed = list(reversed(pods))  # the LIFO queue pops the last element first
-        gangs = has_gangs(feed)
-        precompiled = (incremental.compile(feed) if incremental is not None
-                       and feed and snapshot.nodes and not gangs else None)
-        breaker = None
-        if chaos_plan is not None and not chaos_plan.device.empty():
-            from tpusim.jaxe.backend import install_chaos
+                return run_with_preemption(pods, snapshot, provider=provider,
+                                           incremental=incremental)
+            with flight.span("feed") as fsp:
+                jax_backend = get_backend("jax", provider=provider,
+                                          policy=policy,
+                                          compiled_policy=compiled_policy)
+                # the LIFO queue pops the last element first
+                feed = list(reversed(pods))
+                gangs = has_gangs(feed)
+                if fsp:
+                    fsp.set("pods", len(feed))
+            precompiled = (incremental.compile(feed)
+                           if incremental is not None and feed
+                           and snapshot.nodes and not gangs else None)
+            breaker = None
+            if chaos_plan is not None and not chaos_plan.device.empty():
+                from tpusim.jaxe.backend import install_chaos
 
-            breaker = install_chaos(chaos_plan.device)
-        try:
-            with flight.span("backend_schedule") as bsp:
-                if bsp:
-                    bsp.set("backend", "jax")
-                    bsp.set("pods", len(feed))
-                if gangs:
-                    # gang feeds route through the group driver: ungrouped
-                    # runs use the unchanged per-pod path against the live
-                    # incremental cluster, gangs are admitted all-or-nothing
-                    from tpusim.gang.driver import schedule_with_gangs
-                    from tpusim.jaxe.delta import IncrementalCluster
+                breaker = install_chaos(chaos_plan.device)
+            try:
+                with flight.span("backend_schedule") as bsp:
+                    if bsp:
+                        bsp.set("backend", "jax")
+                        bsp.set("pods", len(feed))
+                    if gangs:
+                        # gang feeds route through schedule_with_gangs:
+                        # ungrouped runs use the unchanged per-pod path
+                        # against the live incremental cluster, gangs are
+                        # admitted all-or-nothing
+                        from tpusim.gang.driver import schedule_with_gangs
+                        from tpusim.jaxe.delta import IncrementalCluster
 
-                    inc = incremental or IncrementalCluster(snapshot)
-                    placements = schedule_with_gangs(jax_backend, inc, feed)
-                else:
-                    placements = jax_backend.schedule(
-                        feed, snapshot, precompiled=precompiled)
-        finally:
+                        inc = incremental or IncrementalCluster(snapshot)
+                        placements = schedule_with_gangs(jax_backend, inc,
+                                                         feed)
+                    else:
+                        placements = jax_backend.schedule(
+                            feed, snapshot, precompiled=precompiled)
+            finally:
+                if breaker is not None:
+                    from tpusim.jaxe.backend import uninstall_chaos
+
+                    uninstall_chaos()
+            with flight.span("status") as stsp:
+                status = Status(scheduled_pods=list(snapshot.pods))
+                for placement in placements:
+                    if placement.scheduled:
+                        status.successful_pods.append(placement.pod)
+                    else:
+                        status.failed_pods.append(placement.pod)
+                last_failed = placements and not placements[-1].scheduled
+                status.stop_reason = (
+                    "Fail to get next pod: No pods left\n" if last_failed
+                    else "fail to get next pod: No pods left\n")
+                if stsp:
+                    stsp.set("pods", len(placements))
             if breaker is not None:
-                from tpusim.jaxe.backend import uninstall_chaos
-
-                uninstall_chaos()
-        status = Status(scheduled_pods=list(snapshot.pods))
-        for placement in placements:
-            if placement.scheduled:
-                status.successful_pods.append(placement.pod)
-            else:
-                status.failed_pods.append(placement.pod)
-        last_failed = placements and not placements[-1].scheduled
-        status.stop_reason = ("Fail to get next pod: No pods left\n" if last_failed
-                              else "fail to get next pod: No pods left\n")
-        if breaker is not None:
-            status.chaos_summary = {
-                "breaker_transitions": list(breaker.transitions)}
-            status.chaos_violations = []
-        return status
+                status.chaos_summary = {
+                    "breaker_transitions": list(breaker.transitions)}
+                status.chaos_violations = []
+            return status
     raise ValueError(f"unknown backend {backend!r}")
 
 
@@ -1208,17 +1230,14 @@ def run_stream_simulation(snapshot: Optional[ClusterSnapshot] = None, *,
             if skip_events:
                 evs = evs[skip_events:]
                 skip_events = 0
-            # one trace context per driver cycle (ISSUE 20): the ingest
-            # span AND the scheduler's own cycle context (a child — same
-            # trace id) share one causal story, so the exported graph
-            # connects ingest → scatter-commit → scan → fold → emit.
-            # start() is None (and everything below a no-op) unless a
-            # flight recorder is installed.
+            # one trace context per loop cycle: the session's
+            # stream_ingest span AND the scheduler's own cycle context (a
+            # child — same trace id) share one causal story, so the
+            # exported graph connects ingest → scatter-commit → scan →
+            # fold → emit. start() is None (and everything below a no-op)
+            # unless a flight recorder is installed.
             with tracectx.activate(tracectx.start()):
-                with flight.span("stream_ingest") as isp:
-                    if isp:
-                        isp.set("events", len(evs))
-                    session.apply_events(evs)
+                session.apply_events(evs)
                 batch = gen.batch()
                 t0 = perf_counter()
                 if pipeline:

@@ -236,6 +236,7 @@ class DeviceResidentCluster:
         the values a fresh restage would stage (`sa_lock_init`: all-unlocked
         for providers, the live first-matching-pod pins for ServiceAffinity
         policies), keeping stream and restage cycles byte-identical."""
+        gsp = flight.span("commit_gather")
         nodes, cells = inc.drain_journal()
         dyn = inc._ensure_dyn()
         idx = np.fromiter(sorted(nodes), dtype=np.int32, count=len(nodes))
@@ -260,6 +261,10 @@ class DeviceResidentCluster:
             # trivial [1, N] dummy presence: the padded (0, 0) cells are
             # untouched zeros on both sides
             val = np.zeros(size, np.int32)
+        if gsp:
+            gsp.set("rows", int(len(nodes)))
+            gsp.set("cells", int(len(cells)))
+            gsp.end()
         sp = flight.span("stream_commit", "device")
         self.carry = apply_delta_donated(self.carry, idx, rows, gid, nid, val,
                                          sa_lock_init)
@@ -376,17 +381,25 @@ class StreamSession:
         self.inc.apply(event_type, obj)
 
     def apply_events(self, events) -> None:
-        self.inc.apply_events(events)
+        with flight.root("stream_ingest") as sp:
+            if sp:
+                events = list(events)
+                sp.set("events", len(events))
+            self.inc.apply_events(events)
 
     def ingest(self, watch_buffer) -> int:
         """Drain a WatchBuffer into the host picture. A torn stream (410
         Gone analog) forces a restage on the next cycle and re-raises so
         the caller can relist (or use watch()/sync(), which do)."""
-        try:
-            return self.inc.ingest(watch_buffer)
-        except WatchExpiredError:
-            self.force_restage("watch_expired")
-            raise
+        with flight.root("stream_ingest") as sp:
+            try:
+                n = self.inc.ingest(watch_buffer)
+            except WatchExpiredError:
+                self.force_restage("watch_expired")
+                raise
+            if sp:
+                sp.set("events", n)
+            return n
 
     def watch(self, client, resource: ResourceType, **kwargs) -> Reflector:
         """Attach a Reflector stream feeding this session; its 410-Gone
@@ -438,7 +451,10 @@ class StreamSession:
         # A context already active (serve front door, pipelined degrade)
         # is the parent; start() is None (and activate a no-op) unless a
         # flight recorder is installed.
-        with tracectx.activate(tracectx.start(parent=tracectx.current())):
+        with tracectx.activate(tracectx.start(parent=tracectx.current())), \
+                flight.root("stream_cycle") as sp:
+            if sp:
+                sp.set("pods", len(pods))
             return self._schedule_cycle(pods, _routed)
 
     def _schedule_cycle(self, pods: List[Pod],
@@ -468,15 +484,19 @@ class StreamSession:
             placements = self._stream_cycle(pods, cols)
         else:
             placements = self._restage_cycle(pods, reason)
-        bound = [pl for pl in placements if pl.node_name]
-        with self._persist_suppressed():
-            for pl in bound:
-                inc.apply(MODIFIED, pl.pod)
-        if self.device.valid:
-            # the scan already applied these binds to the resident carry
-            # with identical integer arithmetic — replaying the fold-back
-            # journal next cycle would be a byte-for-byte no-op
-            inc.drain_journal()
+        with flight.span("stream_foldback") as fsp:
+            bound = [pl for pl in placements if pl.node_name]
+            with self._persist_suppressed():
+                for pl in bound:
+                    inc.apply(MODIFIED, pl.pod)
+            if self.device.valid:
+                # the scan already applied these binds to the resident
+                # carry with identical integer arithmetic — replaying the
+                # fold-back journal next cycle would be a byte-for-byte
+                # no-op
+                inc.drain_journal()
+            if fsp:
+                fsp.set("pods", len(bound))
         if cid is not None:
             self.persist.log_bind(cid, bound)
             self.persist.log_emit(cid, placements)
@@ -521,6 +541,7 @@ class StreamSession:
         the resident state can serve it, else (reason, cols-or-None).
         Consumes the forced-restage latch and the column journal (a restage
         rebuilds everything, so a lost patch is harmless)."""
+        sp = flight.span("stream_route")
         inc = self.inc
         reason = self._forced
         self._forced = None
@@ -550,6 +571,10 @@ class StreamSession:
                                               pods, cols)
             if reason is None:
                 reason = self._prepare_statics_delta()
+        if sp:
+            sp.set("pods", len(pods))
+            sp.set("restage", reason or "")
+            sp.end()
         return reason, cols
 
     # -- paths ------------------------------------------------------------
@@ -649,12 +674,9 @@ class StreamSession:
         def dispatch():
             self._apply_statics_patch()
             dev.commit(self.inc, self._commit_sa_lock())
-            p = len(pods)
-            xs_host = pad_infeasible_rows(pod_columns_to_host(cols),
-                                          bucket_size(p) - p)
             carry, placements, intervened = self._dispatch(
-                dev.config, dev.carry, dev.statics,
-                self._stage_xs(xs_host), pods, dev.compiled)
+                dev.config, dev.carry, dev.statics, self._upload(cols, pods),
+                pods, dev.compiled)
             # the donated input buffer is gone either way; the scan's final
             # carry IS the post-bind resident state
             dev.carry = carry
@@ -869,6 +891,17 @@ class StreamSession:
 
         return stage_tree(xs_host, NamedSharding(mesh, P()))
 
+    def _upload(self, cols, pods: List[Pod]):
+        """The batch's pod columns, padded to their bucket, on the device."""
+        with flight.span("stream_upload") as sp:
+            p = len(pods)
+            xs_host = pad_infeasible_rows(pod_columns_to_host(cols),
+                                          bucket_size(p) - p)
+            xs = self._stage_xs(xs_host)
+            if sp:
+                sp.set("bytes", sum(int(a.nbytes) for a in xs_host))
+        return xs
+
     def _shard_verified(self, config, statics, carry, xs,
                         statics_1d, carry_1d, xs_1d) -> bool:
         """First-use verify for the sharded residency, the same seam as the
@@ -936,12 +969,14 @@ class StreamSession:
             corrupt_kind = injector.begin_dispatch()  # may raise DeviceFault
         t0 = perf_counter()
         dsp = flight.span("device_dispatch", "device")
-        with flight.profiled("tpusim:stream_scan"):
+        with flight.span("scan_launch", "device"), \
+                flight.profiled("tpusim:stream_scan"):
             final_carry, choices, counts, _adv = self._scan(
                 config, carry, statics, xs)
         p = len(pods)
-        choices = np.asarray(choices)[:p]
-        counts = np.asarray(counts)[:p]
+        with flight.span("device_wait", "device"):
+            choices = np.asarray(choices)[:p]
+            counts = np.asarray(counts)[:p]
         if injector is not None:
             if corrupt_kind is not None:
                 from tpusim.chaos.engine import DeviceInjector
@@ -1029,6 +1064,13 @@ class StreamSession:
         preserved. Call flush() for the tail."""
         if not pods:
             return self.flush()
+        with flight.root("stream_cycle") as sp:
+            if sp:
+                sp.set("pods", len(pods))
+                sp.set("pipelined", True)
+            return self._pipelined_cycle(pods)
+
+    def _pipelined_cycle(self, pods: List[Pod]) -> Optional[List[Placement]]:
         prev_p, self._pending = self._pending, None
         if prev_p is not None and prev_p.placements is None:
             # defensive: a poll_placed-first driver has already folded
@@ -1085,29 +1127,39 @@ class StreamSession:
         journaled BEFORE the fold sit inside the mark and survive."""
         if p.folded:
             return
-        waited0 = perf_counter()
-        choices = np.asarray(p.choices)[:len(p.pods)]
-        waited = perf_counter() - waited0
-        elapsed = max(waited0 - p.dispatched_at + waited, 1e-9)
-        register().stream_overlap_fraction.set(max(0.0, 1.0 - waited / elapsed))
-        p.choices = choices
-        names = p.compiled.statics.names
-        mark = self.inc.journal_mark()
-        with self._persist_suppressed():
-            for pod, c in zip(p.pods, choices):
-                c = int(c)
-                if c >= 0:
-                    bound = bind_pod(pod, names[c])
-                    self.inc.apply(MODIFIED, bound)
-                    p.bound.append(Placement(pod=bound, node_name=names[c]))
-        self.inc.journal_rollback(mark)
-        p.folded = True
-        if self.persist is not None and p.wal_cycle is not None:
-            # journaled at fold time: cycle N's binds land BEFORE cycle
-            # N+1's watch events, the order the host picture mutates —
-            # under cycle N's trace context so the shipped frame links
-            # back to the dispatching cycle, not the overlapping one
-            with tracectx.activate(p.trace):
+        # the wait and the fold belong to the dispatching cycle's trace,
+        # whichever later call drains them
+        with tracectx.activate(p.trace):
+            waited0 = perf_counter()
+            with flight.span("device_wait", "device"):
+                choices = np.asarray(p.choices)[:len(p.pods)]
+            waited = perf_counter() - waited0
+            elapsed = max(waited0 - p.dispatched_at + waited, 1e-9)
+            register().stream_overlap_fraction.set(
+                max(0.0, 1.0 - waited / elapsed))
+            p.choices = choices
+            names = p.compiled.statics.names
+            fsp = flight.span("stream_foldback")
+            mark = self.inc.journal_mark()
+            with self._persist_suppressed():
+                for pod, c in zip(p.pods, choices):
+                    c = int(c)
+                    if c >= 0:
+                        bound = bind_pod(pod, names[c])
+                        self.inc.apply(MODIFIED, bound)
+                        p.bound.append(Placement(pod=bound,
+                                                 node_name=names[c]))
+            self.inc.journal_rollback(mark)
+            if fsp:
+                fsp.set("pods", len(p.bound))
+                fsp.end()
+            p.folded = True
+            if self.persist is not None and p.wal_cycle is not None:
+                # journaled at fold time: cycle N's binds land BEFORE cycle
+                # N+1's watch events, the order the host picture mutates —
+                # under cycle N's trace context (_fold_binds) so the shipped
+                # frame links back to the dispatching cycle, not the
+                # overlapping one
                 self.persist.log_bind(p.wal_cycle, p.bound)
 
     def _finalize(self, p: Optional[_PendingCycle]
@@ -1147,16 +1199,14 @@ class StreamSession:
         dev = self.device
         self._apply_statics_patch()
         dev.commit(self.inc, self._commit_sa_lock())
-        p = len(pods)
-        xs_host = pad_infeasible_rows(pod_columns_to_host(cols),
-                                      bucket_size(p) - p)
+        xs = self._upload(cols, pods)
         dsp = flight.span("device_dispatch", "device")
-        with flight.profiled("tpusim:stream_scan"):
+        with flight.span("scan_launch", "device"), \
+                flight.profiled("tpusim:stream_scan"):
             final_carry, choices, counts, _adv = self._scan(
-                dev.config, dev.carry, dev.statics,
-                self._stage_xs(xs_host))
+                dev.config, dev.carry, dev.statics, xs)
         if dsp:
-            dsp.set("pods", p)
+            dsp.set("pods", len(pods))
             dsp.end()
         dev.carry = final_carry
         # analytics rides the un-forced final carry: the reduction is
@@ -1198,8 +1248,20 @@ class StreamSession:
         _route would have."""
         if not pods:
             return []
+        with flight.root("overlay_query") as sp:
+            if sp:
+                sp.set("pods", len(pods))
+            return self._overlay_query(pods, _path)
+
+    def _overlay_query(self, pods: List[Pod],
+                       _path: str) -> Optional[List[Placement]]:
         t0 = perf_counter()
-        routed = self._overlay_route(pods)
+        with flight.span("overlay_route") as rsp:
+            routed = self._overlay_route(pods)
+            if rsp:
+                rsp.set("pods", len(pods))
+                rsp.set("fallback", routed if isinstance(routed, str)
+                        else "")
         if isinstance(routed, str):
             register().overlay_fallback.inc(routed)
             flight.note_route("overlay_fallback", len(pods))
@@ -1296,24 +1358,30 @@ class StreamSession:
         dev = self.device
         injector = _backend._CHAOS["injector"]
         breaker = _backend._CHAOS["breaker"]
-        mark = inc.journal_mark()
-        rr_save = np.asarray(dev.carry.rr)
-        sa_save = np.asarray(dev.carry.sa_lock)
+        # reading the per-batch lanes waits for the carry's last writer
+        with flight.span("overlay_save", "device"):
+            mark = inc.journal_mark()
+            rr_save = np.asarray(dev.carry.rr)
+            sa_save = np.asarray(dev.carry.sa_lock)
         try:
             self._apply_statics_patch()
             dev.commit(inc, self._commit_sa_lock())
             corrupt_kind = (injector.begin_dispatch()
                             if injector is not None else None)
             p = len(pods)
-            xs_host = pad_infeasible_rows(pod_columns_to_host(cols),
-                                          bucket_size(p) - p)
+            with flight.span("stream_upload") as usp:
+                xs_host = pad_infeasible_rows(pod_columns_to_host(cols),
+                                              bucket_size(p) - p)
+                if usp:
+                    usp.set("bytes", sum(int(a.nbytes) for a in xs_host))
             with flight.span("overlay_scan", "device"):
                 final_carry, choices, counts, _adv = self._scan(
                     dev.config, dev.carry, dev.statics,
                     self._stage_xs(xs_host))
             dev.carry = final_carry
-            choices = np.asarray(choices)[:p]
-            counts = np.asarray(counts)[:p]
+            with flight.span("device_wait", "device"):
+                choices = np.asarray(choices)[:p]
+                counts = np.asarray(counts)[:p]
         except Exception as exc:
             inc.journal_rollback(mark)
             dev.invalidate()
@@ -1356,6 +1424,7 @@ class StreamSession:
         one compiled restore program."""
         inc = self.inc
         dev = self.device
+        gsp = flight.span("rollback_gather")
         bound = sorted({int(c) for c in choices if int(c) >= 0})
         dyn = inc._ensure_dyn()
         idx = np.fromiter(bound, dtype=np.int32, count=len(bound))
@@ -1379,6 +1448,10 @@ class StreamSession:
             val = inc._presence[gid, nid].astype(np.int32)
         else:
             val = np.zeros(size, np.int32)
+        if gsp:
+            gsp.set("rows", len(bound))
+            gsp.set("cells", len(cell_list))
+            gsp.end()
         sp = flight.span("overlay_rollback", "device")
         dev.carry = overlay_restore_donated(dev.carry, idx, rows, gid, nid,
                                             val, sa_save, rr_save)

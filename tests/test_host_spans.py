@@ -149,6 +149,20 @@ def test_nothing_nests_inside_compile_cluster(rec, path, monkeypatch):
     assert not [s for s in spans if _inside(s, cc)]
 
 
+@pytest.mark.parametrize("path", ["simulate_xla", "simulate_pallas"])
+def test_compile_cluster_counts_classes_and_matcher_calls(rec, path,
+                                                          monkeypatch):
+    spans, _, _ = _traced(rec, path, monkeypatch)
+    [cc] = [s for s in spans if s["name"] == "compile_cluster"]
+    # four alike nodes are one class; one signature in each of the six
+    # tables is one matcher call each
+    assert cc["args"]["node_classes"] == 1
+    assert cc["args"]["row_evals"] == 6
+    assert cc["args"]["nodes"] == 4
+    # staging_ms_per_query reads the span's self time
+    assert not [s for s in spans if _inside(s, cc)]
+
+
 def test_simulate_count_args(rec):
     _simulate()
     by = {s["name"]: s["args"] for s in _spans(rec)}

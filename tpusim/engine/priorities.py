@@ -202,6 +202,9 @@ compute_taint_toleration_priority_reduce = normalize_reduce(MAX_PRIORITY, True)
 # ---------------------------------------------------------------------------
 
 
+PREFER_AVOID_PODS_ANNOTATION = "scheduler.alpha.kubernetes.io/preferAvoidPods"
+
+
 def calculate_node_prefer_avoid_pods_priority_map(pod: Pod, meta,
                                                   node_info: NodeInfo) -> HostPriority:
     node = node_info.node
@@ -215,7 +218,7 @@ def calculate_node_prefer_avoid_pods_priority_map(pod: Pod, meta,
         return HostPriority(node.name, MAX_PRIORITY)
     import json
 
-    ann = node.metadata.annotations.get("scheduler.alpha.kubernetes.io/preferAvoidPods")
+    ann = node.metadata.annotations.get(PREFER_AVOID_PODS_ANNOTATION)
     if not ann:
         return HostPriority(node.name, MAX_PRIORITY)
     try:

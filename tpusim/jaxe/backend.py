@@ -583,6 +583,8 @@ class JaxBackend:
                 if csp:
                     csp.set("pods", len(pods))
                     csp.set("nodes", len(snapshot.nodes))
+                    csp.set("node_classes", out[0].node_classes)
+                    csp.set("row_evals", out[0].row_evals)
             compile_us = since_in_microseconds(compile_start)
             metrics.backend_compile_latency.observe(compile_us)
             analytics.note_compile(

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 import json
+from operator import attrgetter, methodcaller
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -34,6 +35,7 @@ from tpusim.engine.predicates import (
     pod_matches_term_namespace_and_selector,
 )
 from tpusim.engine.priorities import (
+    PREFER_AVOID_PODS_ANNOTATION,
     calculate_node_affinity_priority_map,
     calculate_node_prefer_avoid_pods_priority_map,
     get_zone_key,
@@ -353,6 +355,11 @@ class CompiledCluster:
     n_topo_doms: int = 1         # segment count for topo_dom (incl. invalid 0)
     n_zone_doms: int = 1
     unsupported: List[str] = field(default_factory=list)  # features needing fallback
+    # compile_cluster's staging counts (0 where the incremental path built
+    # the state): distinct node static classes, and engine matcher calls
+    # made across the signature tables
+    node_classes: int = 0
+    row_evals: int = 0
 
 
 def _selector_signature(pod: Pod):
@@ -1083,6 +1090,71 @@ def signature_row_fns(nodes: List[Node], node_infos: List["NodeInfo"]):
     }
 
 
+_condition_fields = attrgetter("type", "status")
+_milli_value = methodcaller("milli_value")
+_taint_fields = attrgetter("key", "value", "effect")
+_ABSENT = object()  # a label the node lacks: equal to no label value
+
+
+def _static_key(node: Node) -> tuple:
+    """What NodeInfo.set_node and node_static_row read of a node: equal keys
+    give equal NodeInfo aggregates and static rows. A cpu quantity enters
+    NodeInfo as milli_value() and every other as value(), which its
+    milli_value() fixes (the ceiling to 1/1000 determines the ceiling to 1).
+    The last element is the taint set, which the taint tables read alone."""
+    status, spec = node.status, node.spec
+    alloc = status.allocatable
+    return (tuple(alloc), tuple(map(_milli_value, alloc.values())),
+            tuple(map(_condition_fields, status.conditions)),
+            spec.unschedulable,
+            tuple(map(_taint_fields, spec.taints)))
+
+
+def _classes(keys: list) -> Tuple[np.ndarray, List[int]]:
+    """Dense class id of each key, numbered by first appearance, and the
+    index of each class's first member."""
+    ids: Dict[object, int] = {}
+    class_of = np.array([ids.setdefault(k, len(ids)) for k in keys],
+                        dtype=np.intp)
+    return class_of, np.unique(class_of, return_index=True)[1].tolist()
+
+
+def _label_keys_read(rep: Pod, kind: str) -> List[str]:
+    """The node label keys that the selector_ok or affinity_count matcher
+    reads for `rep`: NodeSelectorRequirement.matches and the nodeSelector
+    loop look labels up by these keys and no others."""
+    keys: List[str] = []
+    aff = rep.spec.affinity
+    na = aff.node_affinity if aff is not None else None
+    if kind == "selector_ok":
+        keys.extend(rep.spec.node_selector or ())
+        terms = (na.required_terms or []) if na is not None else []
+        for term in terms:
+            keys.extend(e.key for e in term.match_expressions)
+    elif na is not None:
+        for term in na.preferred:
+            if term.weight != 0:
+                keys.extend(e.key for e in term.preference.match_expressions)
+    return keys
+
+
+class _HeadNodeInfos:
+    """node_infos[i] for signature_row_fns, built only for the nodes that a
+    matcher is called on: the static class's NodeInfo where node i heads its
+    class, else one of node i's own (the matchers read node_info.node)."""
+
+    def __init__(self, nodes: List[Node], infos: Dict[int, NodeInfo]):
+        self._nodes = nodes
+        self._infos = infos
+
+    def __getitem__(self, i: int) -> NodeInfo:
+        ni = self._infos.get(i)
+        if ni is None:
+            ni = self._infos[i] = NodeInfo()
+            ni.set_node(self._nodes[i])
+        return ni
+
+
 def fill_pod_request_row(cols: PodColumns, j: int, pod: Pod, req,
                          scalar_idx: Dict[str, int]) -> None:
     """Fill one pod's numeric request columns (shared with delta.py so the
@@ -1112,16 +1184,25 @@ def compile_cluster(snapshot: ClusterSnapshot, pods: List[Pod],
     compute the PodToleratesNodeNoExecuteTaints table — only a policy can
     enable that predicate, so the default path skips the row work and ships
     an all-pass dummy of the right shape.
+
+    Node-side work runs once per class of nodes that read alike, not once
+    per node: one NodeInfo and one static row per static class
+    (_static_key), and one matcher call per group of nodes that a
+    signature's matcher cannot tell apart (the labels it looks up, the
+    taints, the prefer-avoid annotation, the name), broadcast to the
+    group. Nodes that all differ make one class each.
     """
     nodes = snapshot.nodes
     n = len(nodes)
 
-    # single pass: NodeInfos, per-pod requests, and the scalar name space
-    node_infos: List[NodeInfo] = []
-    for node in nodes:
+    # node static classes: one NodeInfo per class, from its first node
+    static_keys = [_static_key(node) for node in nodes]
+    node_class, heads = _classes(static_keys)
+    class_infos: List[NodeInfo] = []
+    for i in heads:
         ni = NodeInfo()
-        ni.set_node(node)
-        node_infos.append(ni)
+        ni.set_node(nodes[i])
+        class_infos.append(ni)
     pod_requests = [get_resource_request(pod) for pod in pods]
     existing_requests = [get_resource_request(pod) for pod in snapshot.pods]
 
@@ -1136,32 +1217,29 @@ def compile_cluster(snapshot: ClusterSnapshot, pods: List[Pod],
 
     for req in pod_requests + existing_requests:
         _note_scalars(req.scalar)
-    for ni in node_infos:
+    for ni in class_infos:  # class heads in node order: the same name order
         _note_scalars(ni.allocatable_resource.scalar)
     s = len(scalar_names)
     scalar_idx = {name: i for i, name in enumerate(scalar_names)}
 
-    # --- node statics ---
-    alloc = {k: np.zeros(n, dtype=np.int64)
-             for k in ("cpu", "mem", "gpu", "eph", "pods")}
-    alloc_scalar = np.zeros((n, s), dtype=np.int64)
-    cond_bits = np.zeros(n, dtype=np.int64)
-    mem_pressure = np.zeros(n, dtype=bool)
-    disk_pressure = np.zeros(n, dtype=bool)
-    for i, node in enumerate(nodes):
-        ni = node_infos[i]
-        row = node_static_row(node, ni, scalar_idx, s)
-        alloc["cpu"][i], alloc["mem"][i], alloc["gpu"][i] = row[0], row[1], row[2]
-        alloc["eph"][i], alloc["pods"][i] = row[3], row[4]
-        alloc_scalar[i] = row[5]
-        cond_bits[i], mem_pressure[i], disk_pressure[i] = row[6], row[7], row[8]
+    # --- node statics: one row per class, gathered to its nodes ---
+    rows = [node_static_row(nodes[i], ni, scalar_idx, s)
+            for i, ni in zip(heads, class_infos)]
 
+    def column(k: int, dtype) -> np.ndarray:
+        return np.array([row[k] for row in rows], dtype=dtype)[node_class]
+
+    class_scalar = np.zeros((len(rows), s), dtype=np.int64)
+    for c, row in enumerate(rows):
+        class_scalar[c] = row[5]
     statics = NodeStatics(
         names=[nd.name for nd in nodes],
-        alloc_cpu=alloc["cpu"], alloc_mem=alloc["mem"], alloc_gpu=alloc["gpu"],
-        alloc_eph=alloc["eph"], allowed_pods=alloc["pods"],
-        alloc_scalar=alloc_scalar, cond_fail_bits=cond_bits,
-        mem_pressure=mem_pressure, disk_pressure=disk_pressure)
+        alloc_cpu=column(0, np.int64), alloc_mem=column(1, np.int64),
+        alloc_gpu=column(2, np.int64), alloc_eph=column(3, np.int64),
+        allowed_pods=column(4, np.int64),
+        alloc_scalar=class_scalar[node_class],
+        cond_fail_bits=column(6, np.int64),
+        mem_pressure=column(7, bool), disk_pressure=column(8, bool))
 
     # --- pod columns + signature interning ---
     p = len(pods)
@@ -1196,15 +1274,61 @@ def compile_cluster(snapshot: ClusterSnapshot, pods: List[Pod],
     unsupported.extend(group_unsupported)
     cols.group_id = groups.group_of_pod
 
-    # --- static [signature, node] tables ---
-    row_fns = signature_row_fns(nodes, node_infos)
+    # --- static [signature, node] tables: one matcher call per group of
+    # nodes that the matcher reads alike for the signature ---
+    row_fns = signature_row_fns(
+        nodes, _HeadNodeInfos(nodes, dict(zip(heads, class_infos))))
+    groupings: Dict[tuple, Tuple[np.ndarray, List[int]]] = {}
+    row_evals = 0
+
+    def groups_of(rep: Pod, kind: str):
+        """(group of each node, first node of each group) over what the
+        kind's matcher reads of a node for `rep`; None where that is
+        nothing that differs between nodes."""
+        if kind in ("selector_ok", "affinity_count"):
+            label_keys = tuple(_label_keys_read(rep, kind))
+            if not label_keys:
+                return None
+            reads = ("labels", label_keys)
+        elif kind == "avoid_score":
+            if _avoid_signature(rep) is None:
+                return None
+            reads = ("avoid",)
+        elif kind == "host_ok":
+            if not rep.spec.node_name:
+                return None
+            reads = ("name",)
+        else:
+            reads = ("taints",)
+        if reads not in groupings:
+            if reads[0] == "labels":
+                keys = [tuple([labels.get(k, _ABSENT) for k in label_keys])
+                        for labels in (nd.metadata.labels for nd in nodes)]
+            elif reads[0] == "avoid":
+                keys = [nd.metadata.annotations.get(PREFER_AVOID_PODS_ANNOTATION)
+                        for nd in nodes]
+            elif reads[0] == "name":
+                keys = [nd.name for nd in nodes]
+            else:
+                keys = [k[-1] for k in static_keys]
+            groupings[reads] = _classes(keys)
+        return groupings[reads]
 
     def table(interner: Interner, kind: str):
+        nonlocal row_evals
         fn, dtype = row_fns[kind]
         t = np.zeros((max(len(interner), 1), n), dtype=dtype)
+        if n == 0:
+            return t
         for sig_id, rep in enumerate(interner.representatives):
-            for i in range(n):
-                t[sig_id, i] = fn(rep, i)
+            groups = groups_of(rep, kind)
+            if groups is None:
+                t[sig_id] = fn(rep, 0)
+                row_evals += 1
+                continue
+            group_of, firsts = groups
+            t[sig_id] = np.array([fn(rep, i) for i in firsts], dtype=dtype)[group_of]
+            row_evals += len(firsts)
         return t
 
     tables = SignatureTables(
@@ -1252,7 +1376,8 @@ def compile_cluster(snapshot: ClusterSnapshot, pods: List[Pod],
                                has_saa_table=need_saa,
                                maxpd_limits=maxpd_limits,
                                n_topo_doms=n_topo_doms, n_zone_doms=n_zone_doms,
-                               unsupported=unsupported)
+                               unsupported=unsupported,
+                               node_classes=len(heads), row_evals=row_evals)
     return compiled, cols
 
 

@@ -169,13 +169,15 @@ def test_empty_pod_batch():
 
 def test_ineligible_workloads_report_reasons(monkeypatch):
     # interpod is fast-path-native since round 5; budget overruns still
-    # report a reason (the topo-dom budget here, forced to 1)
-    nodes = [make_node("n0")]
+    # report a reason (the topo-dom budget here, forced to 1), naming the
+    # key that crossed it. A hostname key carries no domains, so the term
+    # is zone-keyed.
+    nodes = [make_node("n0", labels={"zone": "z0"})]
     pods = [make_pod("p0", milli_cpu=100, memory=2**20, labels={"app": "a"},
                      affinity={"podAffinity": {
                          "requiredDuringSchedulingIgnoredDuringExecution": [
                              {"labelSelector": {"matchLabels": {"app": "a"}},
-                              "topologyKey": "kubernetes.io/hostname"}]}})]
+                              "topologyKey": "zone"}]}})]
     compiled, cols = compile_cluster(ClusterSnapshot(nodes=nodes), pods)
     config = config_for([compiled], most_requested=False,
                         num_reason_bits=NUM_FIXED_BITS)
@@ -185,6 +187,7 @@ def test_ineligible_workloads_report_reasons(monkeypatch):
     plan, reason = plan_fast(config, compiled, cols)
     assert plan is None
     assert "topology domains exceed" in reason
+    assert "'zone'" in reason
 
 
 def test_scalar_resources_eligible_and_exact():

@@ -163,6 +163,55 @@ def test_compile_cluster_counts_classes_and_matcher_calls(rec, path,
     assert not [s for s in spans if _inside(s, cc)]
 
 
+def _simulate_antiaffinity():
+    """Pods one to a node under required hostname anti-affinity, beside a
+    zone-keyed one, with one bound pod."""
+    def aff(key):
+        return {"podAntiAffinity": {
+            "requiredDuringSchedulingIgnoredDuringExecution": [{
+                "labelSelector": {"matchLabels": {"app": "a"}},
+                "topologyKey": key}]}}
+
+    nodes = [make_node(f"n{i}", milli_cpu=4000, memory=2**33,
+                       labels={"zone": f"z{i % 2}"}) for i in range(4)]
+    bound = [make_pod("b0", milli_cpu=100, memory=2**20, labels={"app": "a"},
+                      affinity=aff("kubernetes.io/hostname"),
+                      node_name="n0", phase="Running")]
+    pods = [make_pod(f"q{i}", milli_cpu=100, memory=2**20,
+                     labels={"app": "a"},
+                     affinity=aff("kubernetes.io/hostname" if i else "zone"))
+            for i in range(4)]
+    status = run_simulation(pods, ClusterSnapshot(nodes=nodes, pods=bound),
+                            backend="jax")
+    assert len(status.successful_pods) == 3 and len(status.failed_pods) == 1
+
+
+@pytest.mark.parametrize("path", ["simulate_xla", "simulate_pallas"])
+def test_interpod_stage_nests_in_compile_cluster(rec, path, monkeypatch):
+    """Inter-pod staging has a span of its own inside compile_cluster,
+    and the Pallas plan says which domains its kernel carries."""
+    if path == "simulate_pallas":
+        monkeypatch.setenv("TPUSIM_FAST", "1")
+        monkeypatch.setenv("TPUSIM_FAST_INTERPRET", "1")
+    _simulate_antiaffinity()
+    spans = _spans(rec)
+    [cc] = [s for s in spans if s["name"] == "compile_cluster"]
+    [ip] = [s for s in spans if s["name"] == "interpod_stage"]
+    assert _inside(ip, cc)
+    # two keys, the hostname's four domains and the bucket, one term
+    # signature (namespaces and selector; the key is not part of it), and
+    # two groups: the terms' keys tell the pods apart
+    assert ip["args"] == {**ip["args"], "topo_keys": 2, "hostname_keys": 1,
+                          "topo_doms": 5, "terms": 1, "groups": 2}
+    plan = [s for s in spans if s["name"] == "plan_fast"]
+    if path == "simulate_pallas":
+        # the zones and the bucket stay; the hostname terms read presence
+        assert plan[0]["args"]["interpod_doms"] == 3
+        assert plan[0]["args"]["hostname_terms"] == 1
+    else:
+        assert not plan
+
+
 def test_simulate_count_args(rec):
     _simulate()
     by = {s["name"]: s["args"] for s in _spans(rec)}

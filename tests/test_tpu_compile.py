@@ -277,6 +277,45 @@ def test_pallas_group_variant_compiles(monkeypatch, one_chip, features):
     assert "tpu_custom_call" in exe.as_text()
 
 
+def test_pallas_hostname_antiaffinity_compiles(monkeypatch, one_chip):
+    """scheduler_perf SchedulingPodAntiAffinity at the envelope: 5,000
+    nodes each labelled with its hostname, 1,000 bound and 1,000 pending
+    pods under required hostname anti-affinity. The hostname key is
+    node-wise, so the variant carries no presence_dom."""
+    from tpusim.api.snapshot import ClusterSnapshot, make_node, make_pod
+    from tpusim.jaxe import ensure_x64
+    from tpusim.jaxe.fastscan import ip_const_of, plan_fast
+    from tpusim.jaxe.kernels import config_for
+    from tpusim.jaxe.state import NUM_FIXED_BITS, compile_cluster
+
+    ensure_x64()
+    aff = {"podAntiAffinity": {
+        "requiredDuringSchedulingIgnoredDuringExecution": [{
+            "labelSelector": {"matchLabels": {"color": "green"}},
+            "topologyKey": "kubernetes.io/hostname",
+            "namespaces": ["sched-1", "sched-0"]}]}}
+    labels = {"color": "green", "name": "test"}
+    nodes = [make_node(f"node-{i}", milli_cpu=4000, memory=32 * 2**30)
+             for i in range(NODES)]
+    bound = [make_pod(f"init-{j}", milli_cpu=100, memory=500 * 2**20,
+                      namespace="sched-0", labels=labels, affinity=aff,
+                      node_name=f"node-{5 * j}", phase="Running")
+             for j in range(1000)]
+    pods = [make_pod(f"measure-{j}", milli_cpu=100, memory=500 * 2**20,
+                     namespace="sched-1", labels=labels, affinity=aff)
+            for j in range(1000)]
+    compiled, cols = compile_cluster(ClusterSnapshot(nodes=nodes,
+                                                     pods=bound), pods)
+    config = config_for([compiled], most_requested=False,
+                        num_reason_bits=NUM_FIXED_BITS)
+    plan, why = plan_fast(config, compiled, cols)
+    assert plan is not None, why
+    ip = ip_const_of(plan)
+    assert ip.nodewise == (True,) and ip.kd == 0
+    exe = _compile_fast_scan(monkeypatch, one_chip, plan)
+    assert "tpu_custom_call" in exe.as_text()
+
+
 def test_pallas_preempt_carry_compiles(monkeypatch, one_chip):
     """The preemption hybrid's call: a plan over placed pods, resumed from
     an explicit carry, one power-of-two bucket, carry returned."""

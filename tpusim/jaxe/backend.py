@@ -160,7 +160,8 @@ def plan_signature(plan) -> tuple:
         # kernel (part of the _build_call cache key): same dims with
         # different constants is a different Mosaic program and must earn
         # trust separately
-        sig += (plan.n_topo_keys, plan.n_topo_doms_ip, plan.ta, plan.tb,
+        sig += (plan.n_topo_keys, plan.topo_nodewise, plan.n_topo_doms_ip,
+                plan.ta, plan.tb,
                 plan.tp, plan.hard_weight, plan.exist_anti_key,
                 plan.exist_anti_mask, plan.exist_anti_empty,
                 plan.exist_pref_key, plan.exist_pref_w,
@@ -695,6 +696,9 @@ class JaxBackend:
                 if psp:
                     psp.set("pods", len(pods))
                     psp.set("nodes", len(snapshot.nodes))
+                    if fplan is not None and fplan.has_interpod:
+                        psp.set("interpod_doms", fplan.n_topo_doms_ip)
+                        psp.set("hostname_terms", fplan.hostname_terms)
             if fplan is None:
                 _note_fast_fallback(metrics, why)
                 log.info("pallas fast path ineligible (%s); using the "

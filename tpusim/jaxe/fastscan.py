@@ -23,11 +23,15 @@ Eligibility (checked by `plan_fast`, reasons returned):
     blend's int32 product bound;
   * inter-pod (anti)affinity runs natively (round 5): own terms via per
     -pod match rows + D scalar segment reductions over the presence carry,
-    the existing-pods side via a [Gpad*K, Dpad] presence_dom carry with
+    the existing-pods side via a [Gpad*Kd, Dpad] presence_dom carry with
     per-(group, term) constants baked into the kernel variant, and
     InterPodAffinityPriority in exact int32 — bounded by
     TPUSIM_FAST_MAX_TOPO_KEYS (4), _MAX_TOPO_DOMS (64), _MAX_TERMS (4)
-    and an int32 weight-mass bound;
+    and an int32 weight-mass bound. A kubernetes.io/hostname key whose
+    values are one per node is node-wise (nodewise_keys): its domains are
+    nodes, so both sides read the presence carry at the node and the key
+    has no presence_dom rows and no domains in D. _MAX_TOPO_DOMS counts
+    only the other keys' domains;
   * MaxPD volume counts run natively (round 5): the [N, V] used-volume
     union as a [Vpad, Npad] bit carry with baked type triples/limits,
     bounded by TPUSIM_FAST_MAX_VOLS (32);
@@ -86,6 +90,7 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
+from tpusim.api.types import LABEL_HOSTNAME
 from tpusim.engine.predicates import (
     CHECK_NODE_DISK_PRESSURE_PRED,
     CHECK_NODE_LABEL_PRESENCE_PRED,
@@ -232,16 +237,26 @@ class FastPlan:
     # keys, weights, and validity masks baked into the kernel as static
     # constants, so per-pod operands reduce to pure match bits.
     has_interpod: bool = False
-    n_topo_keys: int = 0           # K (rows of topo_dom; Gpad*K presence_dom rows)
-    n_topo_doms_ip: int = 0        # REAL domain count incl. the invalid-0
-    #                                bucket (the unroll bound; presence_dom's
-    #                                lane axis is padded to 128 separately)
+    n_topo_keys: int = 0           # K (rows of topo_dom and topo_rows)
+    # per key: True for a node-wise key (kubernetes.io/hostname with a value
+    # of its own on every labelled node): its domains are single nodes, so
+    # its terms read the presence carry and it has no presence_dom rows
+    topo_nodewise: Tuple[bool, ...] = ()
+    n_topo_doms_ip: int = 0        # REAL domain count of the non-node-wise
+    #                                keys incl. the invalid-0 bucket (the
+    #                                unroll bound; presence_dom's lane axis
+    #                                is padded to 128 separately)
+    hostname_terms: int = 0        # terms on a node-wise key, over groups
     ta: int = 0                    # own required-affinity term slots
     tb: int = 0                    # own required-anti-affinity term slots
     tp: int = 0                    # own preferred term slots
     hard_weight: int = 10
-    topo_rows: Optional[np.ndarray] = None       # [Kpad8, Npad] int32 dom ids
-    presence_dom: Optional[np.ndarray] = None    # [Gpad*K, Dpad] int32 init
+    # [Kpad8, Npad] int32: dom ids, or for a node-wise key 1 where the node
+    # has the label
+    topo_rows: Optional[np.ndarray] = None
+    # [Gpad*Kd, Dpad] int32 init over the Kd non-node-wise keys (None when
+    # every key is node-wise)
+    presence_dom: Optional[np.ndarray] = None
     ipod: Optional[np.ndarray] = None            # [P, Wip] per-pod packed row
     # static exist-side tables (baked into the kernel; part of its cache key)
     exist_anti_key: Tuple[int, ...] = ()     # [G*Tb] topo-key per term
@@ -297,7 +312,7 @@ class FastCarry:
     misc: object             # [1, LANES] int32; rr at [0, 0]
     scal: Optional[object] = None    # [Srows, Npad] int32
     pres: Optional[object] = None    # [Gpad, Npad] int32
-    pd: Optional[object] = None      # [Gpad*K, Dpad] int32 (interpod)
+    pd: Optional[object] = None      # [Gpad*Kd, Dpad] int32 (interpod)
     uv: Optional[object] = None      # [Vpad8, Npad] 0/1 int32 (maxpd)
 
 
@@ -314,7 +329,7 @@ def init_carry(plan: FastPlan, rr: int = 0) -> FastCarry:
         misc=misc,
         scal=plan.used_scalar if plan.num_scalars else None,
         pres=plan.presence if plan.num_groups else None,
-        pd=plan.presence_dom if plan.has_interpod else None,
+        pd=plan.presence_dom,
         uv=plan.used_vols if plan.has_maxpd else None)
 
 
@@ -378,11 +393,14 @@ def rearm_carry(plan: FastPlan, compiled, rr: int) -> Optional[FastCarry]:
         pres = np.zeros((plan.num_groups, npad), dtype=np.int32)
         pres[:gt.presence.shape[0], :n] = gt.presence.astype(np.int32)
         if plan.has_interpod:
-            if gt.topo_dom.shape[0] != plan.n_topo_keys:
+            if (gt.topo_dom.shape[0] != plan.n_topo_keys
+                    or nodewise_keys(gt) != plan.topo_nodewise):
                 return None  # topology-key universe changed
-            pd = embed_presence_dom(gt.presence, gt.topo_dom,
-                                    plan.n_topo_doms_ip, plan.num_groups,
-                                    plan.presence_dom.shape[1])
+            if plan.presence_dom is not None:
+                pd = embed_presence_dom(
+                    gt.presence, domain_rows(gt.topo_dom, plan.topo_nodewise),
+                    plan.n_topo_doms_ip, plan.num_groups,
+                    plan.presence_dom.shape[1])
     uv = None
     if plan.has_maxpd:
         gt = compiled.groups
@@ -451,12 +469,35 @@ def _gcd_reduce(arrays) -> Tuple[int, list]:
     return g, [np.asarray(a, dtype=np.int64) // g for a in arrays]
 
 
+def nodewise_keys(gt) -> Tuple[bool, ...]:
+    """Per topology key of the group tables: whether it is node-wise —
+    kubernetes.io/hostname, with a value of its own on every node that has
+    the label (ids are interned 1..V in first-seen order, so V labelled
+    nodes hold V values). A domain of such a key is one node: its own terms
+    read the node's matched presence, and existing pods' terms on it read
+    the presence carry at the node (predicates.go
+    NodesHaveSameTopologyKey), so it needs no domain axis. A hostname key
+    whose values repeat keeps its domains."""
+    out = []
+    for k in range(gt.topo_dom.shape[0]):
+        row = gt.topo_dom[k]
+        key = gt.topo_keys[k] if k < len(gt.topo_keys) else None
+        out.append(key == LABEL_HOSTNAME and row.size > 0
+                   and int(row.max()) == int(np.count_nonzero(row)))
+    return tuple(out)
+
+
+def domain_rows(topo_dom, nodewise: Tuple[bool, ...]) -> np.ndarray:
+    """The topo_dom rows of the keys that keep their domains."""
+    return topo_dom[[k for k, nw in enumerate(nodewise) if not nw]]
+
+
 def embed_presence_dom(presence, topo_dom, d_doms: int, gpad: int,
                       dpad: int) -> np.ndarray:
     """[G, K, D] presence_dom -> the kernel's [Gpad*K, Dpad] row-interleaved
-    carry layout (row g*K + k): ONE definition shared by plan_fast and
-    rearm_carry so the embedding can never diverge between the initial
-    plan and a post-preemption re-arm."""
+    carry layout (row g*K + k), over the domain keys' topo_dom rows: ONE
+    definition shared by plan_fast and rearm_carry so the embedding can
+    never diverge between the initial plan and a post-preemption re-arm."""
     from tpusim.jaxe.kernels import _presence_dom_init
 
     pd3 = _presence_dom_init(presence, topo_dom, d_doms)
@@ -594,7 +635,11 @@ def plan_fast(config: EngineConfig, compiled: CompiledCluster,
     ip_dims = None
     if config.has_interpod:
         k_keys = int(gt.topo_dom.shape[0])
-        d_doms = int(config.n_topo_doms)
+        nodewise = nodewise_keys(gt)
+        # the kernel unrolls over the domains of the other keys only
+        doms = {k: int(gt.topo_dom[k].max(initial=0)) + 1
+                for k in range(k_keys) if not nodewise[k]}
+        d_doms = max(doms.values(), default=1)
         ta = int(gt.aff_valid.shape[1])
         tb = int(gt.anti_valid.shape[1])
         tp = int(gt.pref_w.shape[1])
@@ -605,8 +650,13 @@ def plan_fast(config: EngineConfig, compiled: CompiledCluster,
             return None, (f"{k_keys} topology keys exceed the fast-path "
                           f"budget ({max_k}; TPUSIM_FAST_MAX_TOPO_KEYS)")
         if d_doms > max_d:
+            k_max = max(doms, key=doms.get, default=None)
+            on_key = ("" if k_max is None else " on key %r" % (
+                gt.topo_keys[k_max] if k_max < len(gt.topo_keys)
+                else f"#{k_max}"))
             return None, (f"{d_doms} topology domains exceed the fast-path "
-                          f"budget ({max_d}; TPUSIM_FAST_MAX_TOPO_DOMS)")
+                          f"budget ({max_d}; TPUSIM_FAST_MAX_TOPO_DOMS)"
+                          f"{on_key}")
         if max(ta, tb, tp) > max_t:
             return None, (f"{max(ta, tb, tp)} inter-pod terms exceed the "
                           f"fast-path budget ({max_t}; "
@@ -625,7 +675,7 @@ def plan_fast(config: EngineConfig, compiled: CompiledCluster,
             return None, ("inter-pod priority counts exceed int32 "
                           f"(weight mass {w_own + w_exist} x "
                           f"{total_pods} pods)")
-        ip_dims = (k_keys, d_doms, ta, tb, tp)
+        ip_dims = (k_keys, nodewise, d_doms, ta, tb, tp)
     n_scal = len(compiled.scalar_names)
     if NUM_FIXED_BITS + n_scal > PAD_SENTINEL_BIT:
         return None, (f"{n_scal} scalar resource kinds exceed the int32 "
@@ -862,18 +912,28 @@ def plan_fast(config: EngineConfig, compiled: CompiledCluster,
 
     topo_rows = presence_dom = ip_tbl = None
     ip_static = {}
-    k_keys = d_doms_real = ta = tb = tp = 0
+    k_keys = d_doms_real = ta = tb = tp = host_terms = 0
+    nodewise = ()
     if config.has_interpod:
-        k_keys, d_doms, ta, tb, tp = ip_dims
+        k_keys, nodewise, d_doms, ta, tb, tp = ip_dims
         d_doms_real = d_doms
         kpad8 = max(-(-k_keys // SUBLANES) * SUBLANES, SUBLANES)
         dpad = max(-(-d_doms // LANES) * LANES, LANES)
         topo_rows = np.zeros((kpad8, npad), dtype=np.int32)
-        topo_rows[:k_keys, :n] = gt.topo_dom.astype(np.int32)
+        topo_rows[:k_keys, :n] = np.where(
+            np.array(nodewise, bool)[:, None], gt.topo_dom > 0,
+            gt.topo_dom).astype(np.int32)
         # pad rows and pad nodes keep domain 0 ("label missing": never
         # matches, and pad nodes are infeasible everywhere anyway)
-        presence_dom = embed_presence_dom(gt.presence, gt.topo_dom, d_doms,
-                                          gpad, dpad)
+        if not all(nodewise):
+            presence_dom = embed_presence_dom(
+                gt.presence, domain_rows(gt.topo_dom, nodewise), d_doms,
+                gpad, dpad)
+        on_node = np.array(nodewise, bool)
+        host_terms = int(
+            (gt.aff_valid & ~gt.aff_empty & on_node[gt.aff_key]).sum()
+            + (gt.anti_valid & ~gt.anti_empty & on_node[gt.anti_key]).sum()
+            + ((gt.pref_w != 0) & on_node[gt.pref_key]).sum())
         # every per-pod interpod operand is a pure function of the pod's
         # GROUP, so the packed rows live in a [Gpad, Wip] table gathered by
         # group id per chunk on device — no O(P) host materialization
@@ -1022,7 +1082,8 @@ def plan_fast(config: EngineConfig, compiled: CompiledCluster,
         n_zone_doms=zpad if config.has_services else 0,
         gcds=(g_cpu, g_mem, g_gpu, g_eph), scalar_gcds=tuple(scal_gcds),
         has_interpod=config.has_interpod, n_topo_keys=k_keys,
-        n_topo_doms_ip=d_doms_real, ta=ta, tb=tb, tp=tp,
+        topo_nodewise=nodewise, n_topo_doms_ip=d_doms_real,
+        hostname_terms=host_terms, ta=ta, tb=tb, tp=tp,
         hard_weight=config.hard_weight, topo_rows=topo_rows,
         presence_dom=presence_dom, ipod=ip_tbl, **ip_static,
         has_maxpd=config.has_maxpd and any(mp_enabled),
@@ -1051,9 +1112,11 @@ class IpConst:
     then carry only match bits."""
 
     k_keys: int
+    nodewise: Tuple[bool, ...]   # per key (FastPlan.topo_nodewise)
     kpad8: int              # sublane-padded rows of the static topo block
-    d_doms: int             # REAL domain count (the unroll bound)
-    dpad: int               # lane-padded presence_dom width
+    d_doms: int             # REAL domain count of the domain keys (the
+    #                         unroll bound)
+    dpad: int               # lane-padded presence_dom width (0: none)
     ta: int
     tb: int
     tp: int
@@ -1067,13 +1130,24 @@ class IpConst:
     exist_aff_key: Tuple[int, ...]
     exist_aff_mask: Tuple[int, ...]
 
+    @property
+    def kd(self) -> int:
+        """Keys that keep their domains: presence_dom's rows per group."""
+        return self.nodewise.count(False)
+
+    def dom_index(self, k: int) -> int:
+        """Key k's row among the domain keys."""
+        return self.nodewise[:k].count(False)
+
 
 def ip_const_of(plan: FastPlan) -> Optional[IpConst]:
     if not plan.has_interpod:
         return None
     return IpConst(
-        k_keys=plan.n_topo_keys, kpad8=plan.topo_rows.shape[0],
-        d_doms=plan.n_topo_doms_ip, dpad=plan.presence_dom.shape[1],
+        k_keys=plan.n_topo_keys, nodewise=plan.topo_nodewise,
+        kpad8=plan.topo_rows.shape[0], d_doms=plan.n_topo_doms_ip,
+        dpad=(0 if plan.presence_dom is None
+              else plan.presence_dom.shape[1]),
         ta=plan.ta, tb=plan.tb, tp=plan.tp, hard_weight=plan.hard_weight,
         wip=plan.ipod.shape[1],
         exist_anti_key=plan.exist_anti_key,
@@ -1239,8 +1313,10 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
             if ip is not None:
                 topo_r = refs[at]      # [Kpad8, Npad] static domain rows
                 iprow_r = refs[at + 1]  # per-pod packed interpod rows
-                ipd_r = refs[at + 2]   # [Gpad*K, Dpad] presence_dom init
-                at += 3
+                at += 2
+                if ip.kd:
+                    ipd_r = refs[at]   # [Gpad*Kd, Dpad] presence_dom init
+                    at += 1
         if pol is not None:
             if pol.has_label:
                 ltbl_r = refs[at]      # [Lpad8, Npad] 0/1 pass masks
@@ -1273,7 +1349,7 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
         if group_bound:
             opres_r = refs[at]
             at += 1
-            if ip is not None:
+            if ip is not None and ip.kd:
                 opd_r = refs[at]
                 at += 1
         if mp is not None:
@@ -1295,7 +1371,7 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                 ous_r[:] = ius_r[:]
             if group_bound:
                 opres_r[:] = ipres_r[:]
-                if ip is not None:
+                if ip is not None and ip.kd:
                     opd_r[:] = ipd_r[:]
             if mp is not None:
                 ouv_r[:] = iuv_r[:]
@@ -1382,9 +1458,9 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                 gid_s = gid_r[j, 0]
                 pres_rows = [opres_r[g2:g2 + 1, :] for g2 in range(gpad)]
             if ip is not None:
-                K, D = ip.k_keys, ip.d_doms
+                K, D, KD = ip.k_keys, ip.d_doms, ip.kd
                 L = IpLayout(ip.ta, ip.tb, ip.tp, gpad)
-                pd_rows = [opd_r[r:r + 1, :] for r in range(gpad * K)]
+                pd_rows = [opd_r[r:r + 1, :] for r in range(gpad * KD)]
                 topo_k = [topo_r[k:k + 1, :] for k in range(K)]
 
                 def ip_own_term(match_off, key_off, t):
@@ -1395,7 +1471,9 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                     segment reductions (pd[g,k,d] is the domain-d sum of
                     presence[g], so Σ_g match·pd == Σ_{n∈d} mcount):
                     pad nodes carry domain 0 and zero presence, so they
-                    never contaminate a real domain's sum."""
+                    never contaminate a real domain's sum. On a node-wise
+                    key a domain is one node: dc_at is mcount, and domsel
+                    is 1 where the node has the label."""
                     mcount = jnp.zeros_like(cond)
                     for g2 in range(gpad):
                         mcount = mcount + jnp.where(
@@ -1405,12 +1483,17 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                     domsel = jnp.zeros_like(cond)
                     for k in range(K):
                         domsel = jnp.where(key_t == k, topo_k[k], domsel)
+                    if not KD:
+                        return mcount, mcount, domsel
                     dc_at = jnp.zeros_like(cond)
                     for d in range(D):
                         in_d = domsel == d
                         seg_d = jnp.sum(jnp.where(in_d, mcount, 0),
                                         dtype=jnp.int32)
                         dc_at = dc_at + jnp.where(in_d, seg_d, 0)
+                    for k in range(K):
+                        if ip.nodewise[k]:
+                            dc_at = jnp.where(key_t == k, mcount, dc_at)
                     return mcount, dc_at, domsel
             ports_alias_on = ps is not None and bool(ps.ports_slots)
             if has_ports and (general_on or part(POD_FITS_HOST_PORTS_PRED)
@@ -1634,7 +1717,8 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                 # existing pods' anti-affinity vs me (symmetric; runs first
                 # in the reference's check order). Keys/masks are static:
                 # only referenced (group, term) pairs generate any code.
-                Bk = [jnp.zeros_like(pd_rows[0]) for _ in range(K)]
+                # Bk[k]: per domain of key k, or per node on a node-wise key
+                Bk = [None] * K
                 fail_all = jnp.int32(0)
                 for g2 in range(gpad):
                     for t in range(ip.tb):
@@ -1642,8 +1726,10 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                         if ip.exist_anti_mask[idx]:
                             k_gt = ip.exist_anti_key[idx]
                             mbit = iprow_r[j, L.ex_anti + idx] != 0
-                            Bk[k_gt] = Bk[k_gt] + jnp.where(
-                                mbit, pd_rows[g2 * K + k_gt], 0)
+                            src = (pres_rows[g2] if ip.nodewise[k_gt] else
+                                   pd_rows[g2 * KD + ip.dom_index(k_gt)])
+                            Bk[k_gt] = (0 if Bk[k_gt] is None else Bk[k_gt]) \
+                                + jnp.where(mbit, src, 0)
                         if ip.exist_anti_empty[idx]:
                             gp = jnp.sum(pres_rows[g2],
                                          dtype=jnp.int32) > 0
@@ -1652,6 +1738,12 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                                 mbit & gp).astype(jnp.int32)
                 exist_fail = fail_cond & False
                 for k in range(K):
+                    if Bk[k] is None:
+                        continue
+                    if ip.nodewise[k]:
+                        exist_fail = exist_fail | (
+                            (topo_k[k] > 0) & (Bk[k] > 0))
+                        continue
                     for d in range(1, D):
                         exist_fail = exist_fail | (
                             (topo_k[k] == d) & (Bk[k][0, d] > 0))
@@ -1819,25 +1911,35 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                     w_t = iprow_r[j, L.pref_w + t]
                     counts_row = counts_row + jnp.where(
                         pdomsel > 0, pdc_at, 0) * w_t
-                Wk = [jnp.zeros_like(pd_rows[0]) for _ in range(K)]
+                # Wk[k]: per domain of key k, or per node on a node-wise key
+                Wk = [None] * K
+
+                def add_w(k_gt, g2, mbit, w):
+                    src = (pres_rows[g2] if ip.nodewise[k_gt] else
+                           pd_rows[g2 * KD + ip.dom_index(k_gt)])
+                    Wk[k_gt] = (0 if Wk[k_gt] is None else Wk[k_gt]) \
+                        + jnp.where(mbit, src * w, 0)
+
                 for g2 in range(gpad):
                     for t in range(ip.tp):
                         idx = g2 * ip.tp + t
                         w_s = ip.exist_pref_w[idx]
                         if w_s:
-                            k_gt = ip.exist_pref_key[idx]
-                            mbit = iprow_r[j, L.ex_pref + idx] != 0
-                            Wk[k_gt] = Wk[k_gt] + jnp.where(
-                                mbit, pd_rows[g2 * K + k_gt] * w_s, 0)
+                            add_w(ip.exist_pref_key[idx], g2,
+                                  iprow_r[j, L.ex_pref + idx] != 0, w_s)
                     for t in range(ip.ta):
                         idx = g2 * ip.ta + t
                         if ip.exist_aff_mask[idx]:
-                            k_gt = ip.exist_aff_key[idx]
-                            mbit = iprow_r[j, L.ex_aff + idx] != 0
-                            Wk[k_gt] = Wk[k_gt] + jnp.where(
-                                mbit,
-                                pd_rows[g2 * K + k_gt] * ip.hard_weight, 0)
+                            add_w(ip.exist_aff_key[idx], g2,
+                                  iprow_r[j, L.ex_aff + idx] != 0,
+                                  ip.hard_weight)
                 for k in range(K):
+                    if Wk[k] is None:
+                        continue
+                    if ip.nodewise[k]:
+                        counts_row = counts_row + jnp.where(
+                            topo_k[k] > 0, Wk[k], 0)
+                        continue
                     for d in range(1, D):
                         counts_row = counts_row + jnp.where(
                             topo_k[k] == d, Wk[k][0, d], 0)
@@ -1921,20 +2023,23 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
                     mb = mvrow_r[j, v] != 0
                     ouv_r[v:v + 1, :] = jnp.where(
                         pick & mb, 1, uv_rows[v])
-            if ip is not None:
+            if ip is not None and KD:
                 # presence_dom[gid, k, dom_k(choice)] += 1: the chosen
                 # node's domain id per key is a one-hot-extracted scalar
                 # (pick is one-hot), then a lane-one-hot masked row add —
-                # all-False pick (no feasible node) adds nothing
+                # all-False pick (no feasible node) adds nothing. Node-wise
+                # keys ride the presence update above.
                 found_i = found.astype(jnp.int32)
                 for k in range(K):
+                    if ip.nodewise[k]:
+                        continue
                     chosen_dom = jnp.sum(jnp.where(pick, topo_k[k], 0),
                                          dtype=jnp.int32)
                     ohrow = (jax.lax.broadcasted_iota(
                         jnp.int32, pd_rows[0].shape, 1)
                         == chosen_dom).astype(jnp.int32) * found_i
                     for g2 in range(gpad):
-                        r = g2 * K + k
+                        r = g2 * KD + ip.dom_index(k)
                         opd_r[r:r + 1, :] = jnp.where(
                             gid_s == g2, pd_rows[r] + ohrow, pd_rows[r])
 
@@ -2016,11 +2121,12 @@ def _build_call(npad: int, k: int, most_requested: bool, num_bits: int,
         if ip is not None:
             group_in.append(const_row(rows=ip.kpad8))  # static topo rows
             group_in.append(smem_rows(ip.wip))         # per-pod ip rows
-            group_in.append(const_row(ip.dpad,
-                                      rows=gpad * ip.k_keys))  # pd init
+            if ip.kd:
+                group_in.append(const_row(ip.dpad,
+                                          rows=gpad * ip.kd))  # pd init
         group_out.append(const_row(rows=gpad))         # presence out
-        if ip is not None:
-            group_out.append(const_row(ip.dpad, rows=gpad * ip.k_keys))
+        if ip is not None and ip.kd:
+            group_out.append(const_row(ip.dpad, rows=gpad * ip.kd))
     if mp is not None:
         group_out.append(const_row(rows=mp.vpad8))     # used-vols out
     # policy-residue inputs (order mirrors the kernel's unpack); the
@@ -2072,8 +2178,8 @@ def _build_call(npad: int, k: int, most_requested: bool, num_bits: int,
            jax.ShapeDtypeStruct((k, 1), i32)]
         + ([jax.ShapeDtypeStruct((srows, npad), i32)] if num_scalars else [])
         + ([jax.ShapeDtypeStruct((gpad, npad), i32)] if group_bound else [])
-        + ([jax.ShapeDtypeStruct((gpad * ip.k_keys, ip.dpad), i32)]
-           if ip is not None else [])
+        + ([jax.ShapeDtypeStruct((gpad * ip.kd, ip.dpad), i32)]
+           if ip is not None and ip.kd else [])
         + ([jax.ShapeDtypeStruct((mp.vpad8, npad), i32)]
            if mp is not None else [])
     )
@@ -2190,10 +2296,12 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
         pres_carry = jnp.asarray(carry_in.pres)
         zone_oh = (jnp.asarray(plan.zone_onehot)
                    if plan.has_spread else None)
+    pd_carry = None
     if ipc is not None:
         topo_dev = jnp.asarray(plan.topo_rows)
         ip_tbl_dev = jnp.asarray(plan.ipod)
-        pd_carry = jnp.asarray(carry_in.pd)
+        if ipc.kd:
+            pd_carry = jnp.asarray(carry_in.pd)
     if mpc is not None:
         vol_tbl_dev = jnp.asarray(plan.vol_tbl)
         uv_carry = jnp.asarray(carry_in.uv)
@@ -2305,7 +2413,8 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
                 # table (pad rows gather row 0 of a zero-padded table;
                 # ghost pods are infeasible everywhere regardless)
                 args.append(ip_tbl_dev[gids[:, 0]])
-                args.append(pd_carry)
+                if ipc.kd:
+                    args.append(pd_carry)
         if polc is not None:
             # residue-class policy operands (ghost pods gather row 0 /
             # all-zero rows; they are infeasible everywhere regardless)
@@ -2342,7 +2451,7 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
         if gpad:
             pres_carry = out[oat]
             oat += 1
-        if ipc is not None:
+        if pd_carry is not None:
             pd_carry = out[oat]
             oat += 1
         if mpc is not None:
@@ -2374,6 +2483,6 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
         rows=list(carry), misc=misc,
         scal=scal_carry if plan.num_scalars else None,
         pres=pres_carry if gpad else None,
-        pd=pd_carry if ipc is not None else None,
+        pd=pd_carry,
         uv=uv_carry if mpc is not None else None)
     return out3 + (carry_out,)

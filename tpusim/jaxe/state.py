@@ -46,6 +46,7 @@ from tpusim.engine.resources import (
     get_resource_request,
     is_pod_best_effort,
 )
+from tpusim.obs import recorder as flight
 
 # ---------------------------------------------------------------------------
 # failure reason bit layout (decoded back to error.go strings for the report)
@@ -290,6 +291,8 @@ class GroupTables:
     # (namespace, selector) per first-sel sig, index 0 = None; the backend's
     # ServiceAffinity first-POD analysis resolves locks against these
     saa_defs: list = field(default_factory=list)
+    # the topologyKey of each topo_dom row
+    topo_keys: list = field(default_factory=list)
 
 
 @dataclass
@@ -687,7 +690,32 @@ def _compile_groups(snapshot: ClusterSnapshot, pods: List[Pod],
     if not (has_ports or has_interpod or has_services or has_volumes):
         return (_trivial_groups(len(pods), n), False, False, False, 1, 1, [],
                 {}, no_vol_meta)
+    args = (snapshot, pods, nodes, node_index, need_saa, placed, unplaced,
+            has_ports, has_interpod, has_services, has_volumes)
+    if not has_interpod:
+        return _group_tables(*args)
+    # inter-pod staging: topology interning, presence, the term and match
+    # tables (the whole group build, which the terms drive here)
+    with flight.span("interpod_stage") as sp:
+        out = _group_tables(*args)
+        if sp:
+            gt = out[0]
+            sp.set("topo_keys", len(gt.topo_keys))
+            sp.set("hostname_keys", gt.topo_keys.count(LABEL_HOSTNAME))
+            sp.set("topo_doms", out[4])
+            sp.set("terms", int(gt.term_match.shape[0]) - 1)
+            sp.set("groups", int(gt.presence.shape[0]))
+    return out
 
+
+def _group_tables(snapshot: ClusterSnapshot, pods: List[Pod],
+                  nodes: List[Node], node_index: Dict[str, int],
+                  need_saa: bool, placed: List[Pod], unplaced: List[Pod],
+                  has_ports: bool, has_interpod: bool, has_services: bool,
+                  has_volumes: bool):
+    """_compile_groups past its feature scan: the same return tuple."""
+    n = len(nodes)
+    no_vol_meta = (False, False, False, DEFAULT_MAXPD_LIMITS)
     max_groups, max_raw, max_work, max_presence = _group_budgets()
 
     def fallback(reason: str):
@@ -1011,7 +1039,8 @@ def _compile_groups(snapshot: ClusterSnapshot, pods: List[Pod],
         aff_self=aff_self, aff_unplaced=aff_unplaced,
         anti_valid=anti_valid, anti_err=anti_err, anti_empty=anti_empty,
         anti_term=anti_term, anti_key=anti_key, anti_hostname=anti_hostname,
-        pref_w=pref_w, pref_term=pref_term, pref_key=pref_key)
+        pref_w=pref_w, pref_term=pref_term, pref_key=pref_key,
+        topo_keys=topo_keys)
     return (tables, has_ports, has_services, has_interpod,
             n_topo_doms, n_zone_doms, [], sig_to_gid,
             (has_disk, has_maxpd, has_zone, maxpd_limits))

@@ -249,6 +249,18 @@ def test_stream_cycle_count_args(rec):
     assert by["stream_foldback"]["pods"] == 5
 
 
+@pytest.mark.parametrize("path", ["simulate_pallas", "simulate_xla",
+                                  "stream_cycle"])
+def test_decode_counts_the_binds_that_share_the_pending_spec(rec, path,
+                                                             monkeypatch):
+    """Every placed pod's bound copy shares its pending pod's spec parts:
+    6 of the query's 7 pods fit, and all 5 of the cycle's."""
+    spans, _, _ = _traced(rec, path, monkeypatch)
+    decode = [s for s in spans if s["name"] == "decode_placements"]
+    assert [s["args"]["shared_binds"] for s in decode] == [
+        5 if path == "stream_cycle" else 6]
+
+
 def test_overlay_and_ingest_count_args(rec):
     session = _session()
     rec.events.clear()

@@ -819,6 +819,35 @@ class Pod:
         the round-trip for any pod built through from_obj."""
         return _structural_copy(self)
 
+    def bound_copy(self) -> "Pod":
+        """The copy the Bind and Update seams write (`backends.bind_pod`,
+        `backends.mark_unschedulable`): they set `spec.node_name` and the
+        status, nothing else. New: the Pod, its ObjectMeta (labels,
+        annotations and owner references copied), a shallow PodSpec (so
+        `node_name`, `priority` and the other scalars are its own) and the
+        PodStatus (conditions copied). Shared with this pod: the spec's
+        `containers`, `init_containers`, `affinity`, `tolerations`,
+        `volumes` and `node_selector`.
+
+        The sharing contract: nothing writes those parts in place once a pod
+        is built, on either pod; a writer assigns a new value to its own
+        spec's field instead, or works on `copy()`, which stays the
+        independent deep copy."""
+        meta, status = self.metadata, self.status
+        spec = object.__new__(PodSpec)
+        spec.__dict__.update(self.spec.__dict__)
+        return Pod(
+            metadata=ObjectMeta(
+                name=meta.name, namespace=meta.namespace, uid=meta.uid,
+                labels=dict(meta.labels), annotations=dict(meta.annotations),
+                owner_references=_structural_copy(meta.owner_references)),
+            spec=spec,
+            status=PodStatus(
+                phase=status.phase,
+                conditions=_structural_copy(status.conditions),
+                reason=status.reason, message=status.message,
+                nominated_node_name=status.nominated_node_name))
+
 
 # ---------------------------------------------------------------------------
 # nodes

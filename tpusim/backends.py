@@ -42,8 +42,9 @@ class Placement:
 
 def bind_pod(pod: Pod, node_name: str) -> Pod:
     """The Bind intercept's state mutation (reference: simulator.go:108-128):
-    set nodeName, mark Running."""
-    bound = pod.copy()
+    set nodeName, mark Running. The result shares the pending pod's spec
+    parts that nothing writes (`Pod.bound_copy`)."""
+    bound = pod.bound_copy()
     bound.spec.node_name = node_name
     bound.status.phase = "Running"
     return bound
@@ -51,8 +52,9 @@ def bind_pod(pod: Pod, node_name: str) -> Pod:
 
 def mark_unschedulable(pod: Pod, message: str) -> Pod:
     """The Update intercept (reference: simulator.go:163-185 + scheduler.go error
-    path): Pending phase, PodScheduled=False condition, Reason=Unschedulable."""
-    failed = pod.copy()
+    path): Pending phase, PodScheduled=False condition, Reason=Unschedulable.
+    Shares the pending pod's spec parts as `bind_pod` does."""
+    failed = pod.bound_copy()
     failed.status.phase = "Pending"
     failed.status.conditions.append(PodCondition(
         type="PodScheduled", status="False", reason="Unschedulable", message=message))

@@ -459,6 +459,13 @@ def decode_placements(pods: List[Pod], choices: np.ndarray, counts: np.ndarray,
     return placements, scheduled
 
 
+def shared_binds(pods: List[Pod], placements: List[Placement]) -> int:
+    """Placed pods whose bound copy shares the pending pod's containers
+    (`Pod.bound_copy`): the `decode_placements` span's `shared_binds`."""
+    return sum(1 for pod, pl in zip(pods, placements)
+               if pl.node_name and pl.pod.spec.containers is pod.spec.containers)
+
+
 class JaxBackend:
     name = "jax"
 
@@ -947,9 +954,11 @@ class JaxBackend:
             since_in_microseconds(dispatch_start))
 
         strings = reason_strings(compiled.scalar_names)
-        with flight.span("decode_placements"):
+        with flight.span("decode_placements") as dec:
             placements, _ = decode_placements(pods, choices, counts,
                                               compiled.statics.names, strings)
+            if dec:
+                dec.set("shared_binds", shared_binds(pods, placements))
         prov = provenance.get_log()
         if prov is not None:
             topk = None

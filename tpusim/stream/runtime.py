@@ -999,9 +999,12 @@ class StreamSession:
         metrics.scheduling_algorithm_latency.observe(
             since_in_microseconds(t0))
         strings = reason_strings(compiled.scalar_names)
-        with flight.span("decode_placements"):
+        with flight.span("decode_placements") as dec:
             placements, _ = _backend.decode_placements(
                 pods, choices, counts, compiled.statics.names, strings)
+            if dec:
+                dec.set("shared_binds",
+                        _backend.shared_binds(pods, placements))
         # decision provenance (ISSUE 13): capture the decoded batch only —
         # no EngineConfig change, so residency/donation and the restage
         # classification are untouched (failure text is already the

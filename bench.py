@@ -442,10 +442,10 @@ def measure_config(name: str, snapshot, pods, platform: str,
         # one shared gate (env flag + interpreter override + tpu backend):
         # off-TPU the kernel would run in the Pallas interpreter, which is
         # meaningless as a benchmark
-        from tpusim.jaxe.backend import _fast_path_enabled
         from tpusim.jaxe.fastscan import fast_scan, plan_fast
+        from tpusim.jaxe.trust import FAST
 
-        if fast_env == "1" and not _fast_path_enabled()[0]:
+        if fast_env == "1" and not FAST.mode()[0]:
             log("  TPUSIM_FAST requested but backend is not TPU; "
                 "using the XLA scan (set TPUSIM_FAST_INTERPRET=1 to force "
                 "the interpreter for correctness checks)")
@@ -1716,32 +1716,32 @@ def measure_shard_scaling(platform: str) -> dict:
     backend route. One uniform batch through the FULL JaxBackend dispatch
     (compile → pad → stage → shard_map scan) at TPUSIM_SHARDS ∈ {1, 2, 4},
     each point stamped with its staging overhead (the shard:stage span:
-    pad + NamedSharding placement, paid per batch). TPUSIM_SHARD_VERIFY=0
-    for the curve — the verify replay runs the single-device scan beside
-    every first sharded batch, which is the seam's cost, not the route's.
-    TPUSIM_FAST=0 keeps the Pallas plan from absorbing the batch before
-    the shard decision. On the CPU host the mesh is virtual devices
+    pad + NamedSharding placement, paid per batch). The verify replay runs
+    the single-device scan beside each shard count's first, untimed batch,
+    which pins the route for the timed ones. TPUSIM_FAST=0 keeps the
+    Pallas plan from absorbing the batch before the shard decision. On the CPU host the mesh is virtual devices
     sharing one socket, so the curve here measures partition overhead
     (expect <= 1.0x); the TPU capture stages the same curve on a real
     mesh where the per-shard O(N/k) evaluate actually parallelizes."""
     import jax
 
     from tpusim.backends import placement_hash
-    from tpusim.jaxe.backend import JaxBackend, reset_fast_auto
+    from tpusim.jaxe import trust
+    from tpusim.jaxe.backend import JaxBackend
     from tpusim.obs import recorder as flight
 
     num_nodes = 8_192 if platform != "cpu" else 512
     num_pods = num_nodes * 4  # exactly capacity: every pod places
     shard_counts = [k for k in (1, 2, 4) if k <= len(jax.devices())]
     timed_runs = 3
-    overrides = {"TPUSIM_FAST": "0", "TPUSIM_SHARD_VERIFY": "0"}
+    overrides = {"TPUSIM_FAST": "0"}
     saved = {k: os.environ.get(k) for k in (*overrides, "TPUSIM_SHARDS")}
     os.environ.update(overrides)
     curve, hashes = [], set()
     try:
         for k in shard_counts:
             os.environ["TPUSIM_SHARDS"] = str(k)
-            reset_fast_auto()
+            trust.reset_all()
             snapshot, pods = uniform_workload(num_pods, num_nodes)
             backend = JaxBackend()
             hashes.add(placement_hash(backend.schedule(pods, snapshot)))
@@ -1770,7 +1770,7 @@ def measure_shard_scaling(platform: str) -> dict:
                 os.environ.pop(key, None)
             else:
                 os.environ[key] = val
-        reset_fast_auto()
+        trust.reset_all()
     if len(hashes) != 1:
         raise AssertionError(
             f"shard ladder produced {len(hashes)} distinct placement "

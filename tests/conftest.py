@@ -50,12 +50,15 @@ def pytest_collection_modifyitems(config, items):
 
 @pytest.fixture(autouse=True)
 def _isolate_fast_auto():
-    """Reset the jaxe backend's process-wide trust state (fast-path AUTO
-    flags, victim-kernel trust, chaos breaker seam) around every test: a
-    test tripping the verify path must not flip fast-path
-    eligibility for the rest of the session (ISSUE 4 satellite)."""
-    from tpusim.jaxe.backend import reset_fast_auto
+    """Reset the process-wide trust registry (every route's AUTO state) and
+    the chaos breaker seam around every test: a test tripping the verify
+    path must not flip fast-path eligibility for the rest of the
+    session."""
+    from tpusim.jaxe import trust
+    from tpusim.jaxe.backend import uninstall_chaos
 
-    reset_fast_auto()
+    trust.reset_all()
+    uninstall_chaos()
     yield
-    reset_fast_auto()
+    trust.reset_all()
+    uninstall_chaos()

@@ -209,21 +209,21 @@ def test_fuzz_device_faults_sharded_axis(monkeypatch, faults):
 
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices")
-    from tpusim.jaxe.backend import _SHARD_AUTO, reset_fast_auto
+    from tpusim.jaxe import trust
 
     snap, pods = _workload(num_nodes=3, num_pods=6)
     expected = run_simulation(pods, snap, backend="reference")
     monkeypatch.setenv("TPUSIM_SHARDS", "2")  # 3 nodes: uneven pad to 4
-    reset_fast_auto()
+    trust.reset_all()
     status = run_simulation(pods, snap, backend="jax",
                             chaos_plan=_device_plan(faults))
     assert status.chaos_violations == []
-    assert not _SHARD_AUTO["disabled"], \
+    assert not trust.SHARD.disabled, \
         "injected device fault tripped the shard verify seam"
     if "exception" not in faults.values():
         # corrupt faults let the dispatch complete: the sharded route ran
         # and pinned its signature before the corruption was injected
-        assert _SHARD_AUTO["verified_sigs"], \
+        assert trust.SHARD.verified, \
             "corrupt fault kept the sharded route from pinning"
     assert sorted((p.key(), p.spec.node_name)
                   for p in status.successful_pods) \

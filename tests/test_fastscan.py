@@ -241,38 +241,35 @@ def _outcomes(placements):
 def test_auto_mode_env_gates(monkeypatch):
     """AUTO (env unset): default-on only on TPU, with verification requested
     until the first self-check passes; explicit 0/1 still win."""
-    from tpusim.jaxe import backend
+    from tpusim.jaxe.trust import FAST
 
     monkeypatch.delenv("TPUSIM_FAST", raising=False)
     monkeypatch.delenv("TPUSIM_FAST_INTERPRET", raising=False)
-    monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
-    monkeypatch.setitem(backend._FAST_AUTO, "verified_sigs", set())
     # this suite runs on the CPU backend: AUTO must stay off (the
     # interpreter is not a fast path)
-    assert backend._fast_path_enabled() == (False, True)
+    assert FAST.mode() == (False, True)
     monkeypatch.setenv("TPUSIM_FAST", "0")
-    assert backend._fast_path_enabled() == (False, False)
+    assert FAST.mode() == (False, False)
     monkeypatch.setenv("TPUSIM_FAST", "1")
     monkeypatch.setenv("TPUSIM_FAST_INTERPRET", "1")
-    assert backend._fast_path_enabled() == (True, False)
+    assert FAST.mode() == (True, False)
     # a failed self-check pins the process off even in AUTO
     monkeypatch.delenv("TPUSIM_FAST", raising=False)
-    monkeypatch.setitem(backend._FAST_AUTO, "disabled", True)
-    assert backend._fast_path_enabled() == (False, False)
+    FAST.disabled = True
+    assert FAST.mode() == (False, False)
 
 
 def _run_auto(monkeypatch, corrupt=None, boom=False, num_pods=120):
     """Drive JaxBackend through the AUTO fast path on CPU (interpreter) by
     forcing the gate open with verification on; returns (baseline, auto)."""
     from tpusim.jaxe import backend, fastscan
+    from tpusim.jaxe.trust import FAST
 
     snapshot, pods = build(3, num_nodes=20, num_pods=num_pods)
     monkeypatch.delenv("TPUSIM_FAST", raising=False)
     baseline = backend.JaxBackend().schedule(pods, snapshot)
 
-    monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
-    monkeypatch.setitem(backend._FAST_AUTO, "verified_sigs", set())
-    monkeypatch.setattr(backend, "_fast_path_enabled", lambda: (True, True))
+    monkeypatch.setattr(FAST, "mode", lambda: (True, True))
     real = fastscan.fast_scan
     calls = []
 
@@ -288,36 +285,36 @@ def _run_auto(monkeypatch, corrupt=None, boom=False, num_pods=120):
 
     monkeypatch.setattr(fastscan, "fast_scan", wrapped)
     auto = backend.JaxBackend().schedule(pods, snapshot)
-    return backend, baseline, auto, calls
+    return FAST, baseline, auto, calls
 
 
 def test_auto_verification_passes_and_trusts(monkeypatch):
-    backend, baseline, auto, calls = _run_auto(monkeypatch)
+    fast, baseline, auto, calls = _run_auto(monkeypatch)
     assert calls, "pallas fast path did not engage"
     assert _outcomes(auto) == _outcomes(baseline)
-    assert backend._FAST_AUTO["verified_sigs"]
-    assert backend._FAST_AUTO["disabled"] is False
+    assert fast.verified
+    assert fast.disabled is False
 
 
 def test_auto_small_batch_skips_fast_path(monkeypatch):
-    """An unverified batch below TPUSIM_FAST_VERIFY_MIN must not run the
+    """An unverified batch below trust.PIN_MIN_PODS must not run the
     kernel at all: running it plus a full XLA replay would be strictly
     slower than plain XLA, and passing on tiny evidence must not pin
     process-wide trust either."""
-    backend, baseline, auto, calls = _run_auto(monkeypatch, num_pods=20)
+    fast, baseline, auto, calls = _run_auto(monkeypatch, num_pods=20)
     assert not calls  # routed straight to the XLA scan
     assert _outcomes(auto) == _outcomes(baseline)
-    assert not backend._FAST_AUTO["verified_sigs"]
-    assert backend._FAST_AUTO["disabled"] is False
+    assert not fast.verified
+    assert fast.disabled is False
 
 
 def test_auto_verification_mismatch_falls_back(monkeypatch):
     """A kernel that lowers but miscomputes must lose to the XLA scan: the
     guardrail discards the fast results and pins the process off."""
-    backend, baseline, auto, _calls = _run_auto(
+    fast, baseline, auto, _calls = _run_auto(
         monkeypatch, corrupt=lambda c: -1 if c >= 0 else 0)
     assert _outcomes(auto) == _outcomes(baseline)
-    assert backend._FAST_AUTO["disabled"] is True
+    assert fast.disabled is True
 
 
 def test_auto_fast_path_exception_propagates(monkeypatch):
@@ -330,12 +327,12 @@ def test_auto_fast_path_exception_propagates(monkeypatch):
 def test_auto_fast_path_transient_reruns_on_xla(monkeypatch):
     """A one-off device OOM reruns that batch on the XLA scan, on the same
     device, and leaves the fast path on for the next batch (ADVICE r4)."""
-    backend, baseline, auto, calls = _run_auto(
+    fast, baseline, auto, calls = _run_auto(
         monkeypatch, boom="RESOURCE_EXHAUSTED: out of memory allocating")
     assert calls
     assert _outcomes(auto) == _outcomes(baseline)
-    assert backend._FAST_AUTO["transient"] == 1
-    assert backend._FAST_AUTO["disabled"] is False
+    assert fast.transient == 1
+    assert fast.disabled is False
 
 
 def test_too_many_scalar_kinds_fall_back():
@@ -607,50 +604,45 @@ def test_failure_classification(monkeypatch):
     permanently disable the fast path — but three in a row do. A lowering
     or compile error is not classified at all: the caller re-raises it,
     even when it names an exhausted resource (a VMEM overflow)."""
-    from tpusim.jaxe import backend
+    from tpusim.jaxe.trust import FAST
 
-    monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
-    monkeypatch.setitem(backend._FAST_AUTO, "transient", 0)
     oom = RuntimeError("RESOURCE_EXHAUSTED: out of memory while allocating")
-    assert backend._note_fast_failure(oom)
-    assert backend._FAST_AUTO["disabled"] is False
-    assert backend._note_fast_failure(oom)
-    assert backend._FAST_AUTO["disabled"] is False
-    assert backend._note_fast_failure(oom)
-    assert backend._FAST_AUTO["disabled"] is True
+    assert FAST.note_failure(oom)
+    assert FAST.disabled is False
+    assert FAST.note_failure(oom)
+    assert FAST.disabled is False
+    assert FAST.note_failure(oom)
+    assert FAST.disabled is True
 
-    monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
-    monkeypatch.setitem(backend._FAST_AUTO, "transient", 0)
+    FAST.reset()
     for msg in ("Mosaic failed to compile TPU kernel: unsupported block",
                 "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem"):
-        assert not backend._note_fast_failure(RuntimeError(msg))
-    assert backend._FAST_AUTO["transient"] == 0
-    assert backend._FAST_AUTO["disabled"] is False
+        assert not FAST.note_failure(RuntimeError(msg))
+    assert FAST.transient == 0
+    assert FAST.disabled is False
 
 
 def test_forced_mode_honors_disabled(monkeypatch):
     """ADVICE r4: a persistently failing kernel under TPUSIM_FAST=1 must not
     re-attempt (and re-upload the plan) on every batch."""
-    from tpusim.jaxe import backend
+    from tpusim.jaxe.trust import FAST
 
     monkeypatch.setenv("TPUSIM_FAST", "1")
     monkeypatch.setenv("TPUSIM_FAST_INTERPRET", "1")
-    monkeypatch.setitem(backend._FAST_AUTO, "disabled", True)
-    assert backend._fast_path_enabled() == (False, False)
+    FAST.disabled = True
+    assert FAST.mode() == (False, False)
 
 
 def test_trust_is_per_kernel_signature(monkeypatch):
     """ADVICE r4 (medium): trust pinned by one kernel variant must not
     exempt a different variant — a workload with different feature flags
     or node padding re-verifies."""
-    from tpusim.jaxe import backend
-
-    backend_, baseline, auto, calls = _run_auto(monkeypatch)
-    sigs = backend_._FAST_AUTO["verified_sigs"]
+    fast, baseline, auto, calls = _run_auto(monkeypatch)
+    sigs = fast.verified
     assert len(sigs) == 1
     sig = next(iter(sigs))
     # same variant: no re-verification wanted; different npad: verify again
-    assert backend_._FAST_AUTO["disabled"] is False
+    assert fast.disabled is False
     other = (sig[0] + 128,) + sig[1:]
     assert other not in sigs
 

@@ -360,7 +360,7 @@ def test_fuzz_preemption_banded_saturated_parity():
     a fallback to tolerate. The occasional host-ports pod flips the whole
     run to the general class, covering the class-dispatch seam itself."""
     from tpusim.api.types import ContainerPort
-    from tpusim.jaxe.backend import _VICTIM_AUTO
+    from tpusim.jaxe.trust import VICTIM
     from tpusim.jaxe.preempt import (
         PREEMPT_CLASS_STATS,
         reset_preempt_class_stats,
@@ -371,7 +371,7 @@ def test_fuzz_preemption_banded_saturated_parity():
         _bound_compile_state(seed)
         rng = random.Random(7000 + seed)
         if seed % 2 == 0:
-            _VICTIM_AUTO["verified_sigs"].clear()
+            VICTIM.verified.clear()
         n_nodes = rng.randint(3, 8)
         nodes = [make_node(f"n{i}", milli_cpu=rng.choice([1000, 2000, 4000]),
                            memory=rng.choice([2, 4, 8]) * 1024 * MB,

@@ -145,13 +145,13 @@ def test_gang_select_oracle_kernel_parity(m, n, seed):
 
 
 def test_gang_auto_seam_verifies_then_trusts(monkeypatch):
-    from tpusim.gang import kernel as gk
+    from tpusim.jaxe.trust import GANG
 
     monkeypatch.delenv("TPUSIM_GANG_KERNEL", raising=False)
     st = run_simulation([*_gang("g", 4)], _cluster(), backend="jax")
     assert len(st.successful_pods) == 4
-    assert gk._GANG_AUTO["verified_sigs"], "first gang must verify its sig"
-    assert not gk._GANG_AUTO["disabled"]
+    assert GANG.verified, "first gang must verify its sig"
+    assert not GANG.disabled
 
 
 def test_gang_kernel_env_force_host(monkeypatch):

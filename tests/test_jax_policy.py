@@ -972,22 +972,3 @@ def test_fast_fallback_counter_classifies_blockers(monkeypatch):
     jx = run_simulation(list(pods), snapshot, backend="jax", policy=policy)
     assert sig(jx) == sig(ref)  # the XLA fallback stays byte-identical
     assert fallback.get("sa_segs_budget") >= before + 1
-
-
-def test_reset_fast_auto_restores_boot_state():
-    """The test-isolation satellite: reset_fast_auto clears the process-wide
-    trust/breaker state the autouse conftest fixture depends on."""
-    from tpusim.jaxe import backend
-
-    backend._FAST_AUTO["disabled"] = True
-    backend._FAST_AUTO["verified_sigs"].add(("sig",))
-    backend._FAST_AUTO["transient"] = 2
-    backend._VICTIM_AUTO["disabled"] = True
-    backend._VICTIM_AUTO["verified_sigs"].add(("v",))
-    backend.reset_fast_auto()
-    assert backend._FAST_AUTO == {"disabled": False, "verified_sigs": set(),
-                                  "transient": 0}
-    assert backend._VICTIM_AUTO == {"disabled": False,
-                                    "verified_sigs": set()}
-    assert backend._CHAOS == {"injector": None, "breaker": None,
-                              "verify": "all"}

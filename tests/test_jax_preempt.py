@@ -263,37 +263,34 @@ def test_preempt_fast_path_engages_and_matches(monkeypatch):
 
 
 def test_preempt_fast_verify_once_small_chunk0(monkeypatch):
-    """A chunk0 below TPUSIM_FAST_VERIFY_MIN must verify ONLY the first
+    """A chunk0 below trust.PIN_MIN_PODS must verify ONLY the first
     chunk (later chunks run on a chained carry that no from-scratch replay
     matches) and must not spuriously disable the fast path."""
     import bench
-    from tpusim.jaxe import backend, fastscan
+    from tpusim.jaxe import backend
     from tpusim.jaxe.preempt import run_with_preemption
+    from tpusim.jaxe.trust import FAST
 
     snap, pods = bench.zipf_workload(400, 30, priorities=True, seed=23)
     monkeypatch.delenv("TPUSIM_FAST", raising=False)
     base = run_with_preemption(pods, snap)
 
     monkeypatch.setenv("TPUSIM_FAST_INTERPRET", "1")
-    monkeypatch.setenv("TPUSIM_PREEMPT_CHUNK0", "32")  # < min_pin (64)
-    monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
-    monkeypatch.setitem(backend._FAST_AUTO, "verified_sigs", set())
+    monkeypatch.setenv("TPUSIM_PREEMPT_CHUNK0", "32")  # < PIN_MIN_PODS
     # AUTO mode off-TPU never engages; force the gate while keeping
     # auto-mode verification on
-    monkeypatch.setattr(backend, "_fast_path_enabled", lambda: (True, True))
+    monkeypatch.setattr(FAST, "mode", lambda: (True, True))
     verifies = []
-    real_verify = backend._auto_verify_and_pin
+    real_check = backend.FastRoute.check
 
-    def counting_verify(*a, **kw):
+    def counting_check(*a, **kw):
         verifies.append(1)
-        return real_verify(*a, **kw)
+        return real_check(*a, **kw)
 
-    # run_with_preemption imports these names from backend at call time,
-    # so patching the backend module covers the hybrid too
-    monkeypatch.setattr(backend, "_auto_verify_and_pin", counting_verify)
+    monkeypatch.setattr(backend.FastRoute, "check", counting_check)
     fast = run_with_preemption(pods, snap)
     assert len(verifies) == 1
-    assert backend._FAST_AUTO["disabled"] is False
+    assert FAST.disabled is False
     assert {p.metadata.name: p.spec.node_name
             for p in fast.successful_pods} \
         == {p.metadata.name: p.spec.node_name for p in base.successful_pods}

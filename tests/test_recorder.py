@@ -234,25 +234,18 @@ class TestChromeValidity:
 class TestAutoTransitions:
     @pytest.fixture(autouse=True)
     def _fresh_auto_state(self):
-        from tpusim.jaxe import backend as jb
-
-        saved = {k: (set(v) if isinstance(v, set) else v)
-                 for k, v in jb._FAST_AUTO.items()}
-        jb._FAST_AUTO.update(disabled=False, verified_sigs=set(),
-                             transient=0)
         register().reset()
-        yield
-        jb._FAST_AUTO.update(saved)
 
     def test_verify_pass_pins_and_counts(self, monkeypatch):
-        from tpusim.jaxe import backend as jb
+        from tpusim.jaxe.backend import FastRoute
+        from tpusim.jaxe.trust import FAST
 
         monkeypatch.setattr("tpusim.jaxe.fastscan.verify_against_xla",
                             lambda *a, **kw: True)
         cols = types.SimpleNamespace(req_cpu=np.zeros(128))
         sig = ("variant", 0)
-        assert jb._auto_verify_and_pin(None, None, cols, None, None, sig)
-        assert sig in jb._FAST_AUTO["verified_sigs"]
+        assert FastRoute(sig=sig).check(None, None, cols, None, None)
+        assert FAST.trusted(sig)
         m = register()
         assert m.backend_auto_transitions.get("verify_pass") == 1
         assert m.backend_auto_transitions.get("pin") == 1
@@ -263,14 +256,15 @@ class TestAutoTransitions:
                 '{transition="pin"} 1') in text
 
     def test_verify_fail_disables_and_counts(self, monkeypatch):
-        from tpusim.jaxe import backend as jb
+        from tpusim.jaxe.backend import FastRoute
+        from tpusim.jaxe.trust import FAST
 
         monkeypatch.setattr("tpusim.jaxe.fastscan.verify_against_xla",
                             lambda *a, **kw: False)
         cols = types.SimpleNamespace(req_cpu=np.zeros(128))
-        assert not jb._auto_verify_and_pin(None, None, cols, None, None,
-                                           ("v", 1))
-        assert jb._FAST_AUTO["disabled"]
+        assert not FastRoute(sig=("v", 1)).check(None, None, cols, None,
+                                                 None)
+        assert FAST.disabled
         assert register().backend_auto_transitions.get("verify_fail") == 1
 
     def test_trust_bridge_counts(self):
@@ -278,15 +272,14 @@ class TestAutoTransitions:
         assert register().backend_auto_transitions.get("trust") == 1
 
     def test_forced_discard_transient_then_permanent(self):
-        from tpusim.jaxe import backend as jb
+        from tpusim.jaxe.trust import FAST
 
         for _ in range(3):
-            assert jb._note_fast_failure(
-                RuntimeError("RESOURCE_EXHAUSTED: hbm"))
+            assert FAST.note_failure(RuntimeError("RESOURCE_EXHAUSTED: hbm"))
         m = register()
         assert m.backend_auto_transitions.get("discard_transient") == 3
         assert m.backend_auto_transitions.get("discard_permanent") == 1
-        assert jb._FAST_AUTO["disabled"]
+        assert FAST.disabled
         # the discard is visible on the exposition surface (--metrics-out)
         assert ('tpusim_backend_auto_transitions_total'
                 '{transition="discard_permanent"} 1') in m.expose()
@@ -294,10 +287,10 @@ class TestAutoTransitions:
     def test_compile_failure_is_not_discarded(self):
         """A lowering error is the caller's to raise: no transition is
         recorded and the path is not quietly switched off."""
-        from tpusim.jaxe import backend as jb
+        from tpusim.jaxe.trust import FAST
 
-        assert not jb._note_fast_failure(ValueError("Mosaic lowering failed"))
+        assert not FAST.note_failure(ValueError("Mosaic lowering failed"))
         m = register()
         assert m.backend_auto_transitions.get("discard_permanent") == 0
         assert m.backend_auto_transitions.get("discard_transient") == 0
-        assert not jb._FAST_AUTO["disabled"]
+        assert not FAST.disabled

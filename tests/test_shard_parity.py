@@ -21,7 +21,9 @@ from tpusim.api.snapshot import ClusterSnapshot, make_node, make_pod
 from tpusim.backends import placement_hash
 from tpusim.framework.metrics import register
 from tpusim.gang.group import mark_gang
-from tpusim.jaxe.backend import _SHARD_AUTO, JaxBackend, reset_fast_auto
+from tpusim.jaxe import trust
+from tpusim.jaxe.backend import JaxBackend
+from tpusim.jaxe.trust import SHARD
 from tpusim.obs import analytics
 from tpusim.simulator import run_simulation
 
@@ -54,7 +56,7 @@ def _strip(stats):
 def _schedule(monkeypatch, k, snapshot, pods):
     """One full backend run at shard count k with analytics retained."""
     monkeypatch.setenv("TPUSIM_SHARDS", str(k))
-    reset_fast_auto()
+    trust.reset_all()
     log = analytics.install(analytics.ClusterAnalytics(
         keep_inputs=True, sample_interval_s=0.0))
     try:
@@ -84,7 +86,7 @@ def test_backend_parity_matrix(monkeypatch, k):
     assert problems == []
     assert _strip(stats) == _strip(base_stats)
     # the route actually ran sharded and pinned its signature
-    assert _SHARD_AUTO["verified_sigs"] and not _SHARD_AUTO["disabled"]
+    assert SHARD.verified and not SHARD.disabled
     m = register()
     assert m.shard_count.value == k
     occupancy = sum(m.shard_node_occupancy.get(str(s)) for s in range(k))
@@ -97,14 +99,14 @@ def test_shards_one_never_builds_a_mesh(monkeypatch):
     no mesh, no verify pin, byte-identical trace to the default."""
     snapshot, pods = _workload(num_pods=24)
     monkeypatch.delenv("TPUSIM_SHARDS", raising=False)
-    reset_fast_auto()
+    trust.reset_all()
     base = JaxBackend().schedule(pods, snapshot)
     for env in ("1", "0", "not-a-number"):
         monkeypatch.setenv("TPUSIM_SHARDS", env)
-        reset_fast_auto()
+        trust.reset_all()
         got = JaxBackend().schedule(pods, snapshot)
         assert placement_hash(got) == placement_hash(base)
-        assert not _SHARD_AUTO["verified_sigs"], \
+        assert not SHARD.verified, \
             f"TPUSIM_SHARDS={env} took the sharded route"
 
 
@@ -133,7 +135,7 @@ def test_gang_decisions_match_across_shards(monkeypatch, k):
 
     def run(shards):
         monkeypatch.setenv("TPUSIM_SHARDS", str(shards))
-        reset_fast_auto()
+        trust.reset_all()
         st = run_simulation(feed(), cluster(), backend="jax")
         binds = sorted((p.metadata.name, p.spec.node_name)
                        for p in st.successful_pods)
@@ -161,4 +163,4 @@ def test_chunked_sharded_route_parity(monkeypatch):
     got, _, problems = _schedule(monkeypatch, 2, snapshot, pods)
     assert problems == []
     assert _signature(got) == _signature(base)
-    assert _SHARD_AUTO["verified_sigs"] and not _SHARD_AUTO["disabled"]
+    assert SHARD.verified and not SHARD.disabled

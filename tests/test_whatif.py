@@ -12,7 +12,9 @@ import pytest
 
 from tpusim.api.snapshot import ClusterSnapshot, make_node, make_pod
 from tpusim.backends import get_backend
+from tpusim.jaxe import trust
 from tpusim.jaxe.sharding import make_mesh
+from tpusim.jaxe.trust import FAST
 from tpusim.jaxe.whatif import run_what_if
 
 needs_8_devices = pytest.mark.skipif(
@@ -171,14 +173,11 @@ class TestFastLoop:
     def test_fast_loop_matches_vmap_program(self, monkeypatch):
         scenarios = self._scenarios()
         vmap_results = run_what_if(scenarios)
-        from tpusim.jaxe import backend, fastscan
+        from tpusim.jaxe import fastscan
 
-        monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
-        monkeypatch.setitem(backend._FAST_AUTO, "verified_sigs", set())
-        monkeypatch.setattr(backend, "_fast_path_enabled",
-                            lambda: (True, True))
+        monkeypatch.setattr(FAST, "mode", lambda: (True, True))
         # 25-pod scenarios are real evidence at this threshold
-        monkeypatch.setenv("TPUSIM_FAST_VERIFY_MIN", "16")
+        monkeypatch.setattr(trust, "PIN_MIN_PODS", 16)
         runs = []
         real = fastscan.fast_scan
         monkeypatch.setattr(
@@ -192,7 +191,7 @@ class TestFastLoop:
             assert (fr.scheduled, fr.unschedulable) == \
                 (vr.scheduled, vr.unschedulable)
         # scenario 0's self-verification pinned process-wide trust
-        assert backend._FAST_AUTO["verified_sigs"]
+        assert FAST.verified
 
     def test_ineligible_scenario_keeps_vmap_program(self, monkeypatch):
         scenarios = self._scenarios()
@@ -204,15 +203,12 @@ class TestFastLoop:
                 "requiredDuringSchedulingIgnoredDuringExecution": [
                     {"labelSelector": {"matchLabels": {"app": "a"}},
                      "topologyKey": "kubernetes.io/hostname"}]}})
-        from tpusim.jaxe import backend, fastscan
+        from tpusim.jaxe import fastscan
 
         # inter-pod terms are fast-path eligible; a zero topology-key
         # budget makes this scenario's one key exceed it
         monkeypatch.setenv("TPUSIM_FAST_MAX_TOPO_KEYS", "0")
-        monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
-        monkeypatch.setitem(backend._FAST_AUTO, "verified_sigs", set())
-        monkeypatch.setattr(backend, "_fast_path_enabled",
-                            lambda: (True, True))
+        monkeypatch.setattr(FAST, "mode", lambda: (True, True))
         monkeypatch.setattr(
             fastscan, "fast_scan",
             lambda plan, **kw: (_ for _ in ()).throw(
@@ -224,12 +220,9 @@ class TestFastLoop:
         """A kernel that fails to lower is an error, never a quiet re-run
         on the vmap program."""
         scenarios = self._scenarios()
-        from tpusim.jaxe import backend, fastscan
+        from tpusim.jaxe import fastscan
 
-        monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
-        monkeypatch.setitem(backend._FAST_AUTO, "verified_sigs", set())
-        monkeypatch.setattr(backend, "_fast_path_enabled",
-                            lambda: (True, True))
+        monkeypatch.setattr(FAST, "mode", lambda: (True, True))
         monkeypatch.setattr(
             fastscan, "fast_scan",
             lambda plan, **kw: (_ for _ in ()).throw(
@@ -242,19 +235,16 @@ class TestFastLoop:
         the same device, and leaves the fast path on (ADVICE r4)."""
         scenarios = self._scenarios()
         vmap_results = run_what_if(scenarios)
-        from tpusim.jaxe import backend, fastscan
+        from tpusim.jaxe import fastscan
 
-        monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
-        monkeypatch.setitem(backend._FAST_AUTO, "verified_sigs", set())
-        monkeypatch.setattr(backend, "_fast_path_enabled",
-                            lambda: (True, True))
+        monkeypatch.setattr(FAST, "mode", lambda: (True, True))
         monkeypatch.setattr(
             fastscan, "fast_scan",
             lambda plan, **kw: (_ for _ in ()).throw(
                 RuntimeError("RESOURCE_EXHAUSTED: out of memory")))
         results = run_what_if(scenarios)
-        assert backend._FAST_AUTO["transient"] == 1
-        assert backend._FAST_AUTO["disabled"] is False
+        assert FAST.transient == 1
+        assert FAST.disabled is False
         for fr, vr in zip(results, vmap_results):
             assert placements_key(fr.placements) == \
                 placements_key(vr.placements)
@@ -266,10 +256,10 @@ def test_fuzz_what_if_fast_loop_parity(monkeypatch):
     import os
     import random
 
-    from tpusim.jaxe import backend, fastscan
+    from tpusim.jaxe import fastscan
 
     seeds = min(max(int(os.environ.get("TPUSIM_FUZZ_SEEDS", "2")), 1), 10)
-    orig_gate = backend._fast_path_enabled
+    orig_gate = FAST.mode
     orig_fast = fastscan.fast_scan
     for seed in range(seeds):
         rng = random.Random(7000 + seed)
@@ -290,16 +280,13 @@ def test_fuzz_what_if_fast_loop_parity(monkeypatch):
             scenarios.append((ClusterSnapshot(nodes=nodes), pods))
         # the reference run must NOT take the fast loop (on TPU the AUTO
         # gate is default-on and earlier tests may have pinned trust)
-        monkeypatch.setattr(backend, "_fast_path_enabled",
-                            lambda: (False, False))
+        monkeypatch.setattr(FAST, "mode", lambda: (False, False))
         vmap_results = run_what_if(scenarios)
-        monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
-        monkeypatch.setitem(backend._FAST_AUTO, "verified_sigs", set())
+        FAST.reset()
         # verify OFF: the fast results must stand on their own — with
         # verification on, a divergence would silently fall back to the
         # vmap program and the parity assert would compare vmap vs vmap
-        monkeypatch.setattr(backend, "_fast_path_enabled",
-                            lambda: (True, False))
+        monkeypatch.setattr(FAST, "mode", lambda: (True, False))
         runs = []
         monkeypatch.setattr(
             fastscan, "fast_scan",
@@ -307,7 +294,7 @@ def test_fuzz_what_if_fast_loop_parity(monkeypatch):
         fast_results = run_what_if(scenarios)
         # un-patch before the next seed's vmap reference run
         monkeypatch.setattr(fastscan, "fast_scan", orig_fast)
-        monkeypatch.setattr(backend, "_fast_path_enabled", orig_gate)
+        monkeypatch.setattr(FAST, "mode", orig_gate)
         assert runs, f"seed {seed}: fast loop did not engage"
         for i, (fr, vr) in enumerate(zip(fast_results, vmap_results)):
             assert placements_key(fr.placements) == \
@@ -620,7 +607,7 @@ def test_policy_what_if_fast_loop_matches_vmap(monkeypatch):
     import numpy as np
 
     from tpusim.engine.policy import decode_policy
-    from tpusim.jaxe import backend, fastscan
+    from tpusim.jaxe import fastscan
 
     policy = decode_policy({
         "kind": "Policy", "apiVersion": "v1",
@@ -641,7 +628,6 @@ def test_policy_what_if_fast_loop_matches_vmap(monkeypatch):
 
     monkeypatch.setenv("TPUSIM_FAST", "1")
     monkeypatch.setenv("TPUSIM_FAST_INTERPRET", "1")
-    monkeypatch.setitem(backend._FAST_AUTO, "disabled", False)
     calls = []
     real = fastscan.fast_scan
 

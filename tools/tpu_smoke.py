@@ -970,9 +970,9 @@ def run_gang_variant():
         prev = os.environ.get("TPUSIM_GANG_KERNEL")
         os.environ["TPUSIM_GANG_KERNEL"] = kernel_env
         try:
-            from tpusim.jaxe.backend import reset_fast_auto
+            from tpusim.jaxe import trust
 
-            reset_fast_auto()
+            trust.reset_all()
             st = run_simulation(feed(), cluster(), backend="jax")
         finally:
             if prev is None:
@@ -1032,7 +1032,7 @@ def run_sharded_variant():
     if len(jax.devices()) < 2:
         return None
     from tpusim.backends import Placement, placement_hash
-    from tpusim.jaxe.backend import _SHARD_AUTO, reset_fast_auto
+    from tpusim.jaxe import trust
     from tpusim.jaxe.kernels import _SHARDED_SCAN_PROGRAMS
     from tpusim.simulator import run_simulation
 
@@ -1059,7 +1059,7 @@ def run_sharded_variant():
         os.environ["TPUSIM_SHARDS"] = str(shards)
         try:
             if reset:
-                reset_fast_auto()
+                trust.reset_all()
             st = run_simulation(feed(tag), cluster(), backend="jax")
         finally:
             if prev is None:
@@ -1080,10 +1080,10 @@ def run_sharded_variant():
         raise AssertionError(
             f"sharded route diverges from single-device "
             f"({shard_hash[:16]} != {base_hash[:16]})")
-    if _SHARD_AUTO["disabled"] or not _SHARD_AUTO["verified_sigs"]:
+    if trust.SHARD.disabled or not trust.SHARD.verified:
         raise AssertionError(
             "sharded run never pinned a verified signature "
-            f"(disabled={_SHARD_AUTO['disabled']})")
+            f"(disabled={trust.SHARD.disabled})")
 
     # zero-retrace: a warm batch over the same shapes must reuse the
     # compiled shard_map program (the pinned sig skips re-verification)

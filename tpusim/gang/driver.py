@@ -153,10 +153,11 @@ def _joint_solve(backend, inc, members: List[Pod], compiled, cols
     xs = pod_columns_to_device(cols)
     n_nodes = len(compiled.statics.names)
     lanes = None
-    from tpusim.jaxe.backend import _SHARD_AUTO, _shard_count
+    from tpusim.jaxe.sharding import shard_count
+    from tpusim.jaxe.trust import SHARD
 
-    n_shards = _shard_count()
-    if n_shards > 1 and not _SHARD_AUTO["disabled"]:
+    n_shards = shard_count()
+    if n_shards > 1 and SHARD.mode()[0]:
         # cross-shard gang lanes (ISSUE 16 sub-problem b): per-member
         # filter/score runs per node shard with collective reductions,
         # the stitched output re-gathers the full (member, node) matrix

@@ -1,40 +1,22 @@
-"""AUTO verify-then-trust seam for the batched gang packing kernel.
-
-Mirrors the victim-selection seam in jaxe/backend.py (`_VICTIM_AUTO`):
-TPUSIM_GANG_KERNEL=0 forces the host oracle, =1 forces the device kernel
-without verification (benchmark/debug), unset = AUTO — the first gang solved
-per (members, nodes) pow2-bucketed signature runs BOTH sides and compares
-choices; a match pins the signature (later gangs of that shape skip the
-host compute), any disagreement disables the kernel process-wide and the
-host result is used, so AUTO can never change behavior.
+"""The batched gang packing kernel behind AUTO verify-then-trust
+(jaxe/trust.py, `GANG`): TPUSIM_GANG_KERNEL=0 forces the host oracle, =1
+forces the device kernel without verification (benchmark/debug), unset =
+AUTO — the first gang solved per (members, nodes) pow2-bucketed signature
+runs BOTH sides and compares choices; a match pins the signature (later
+gangs of that shape skip the host compute), any disagreement disables the
+kernel process-wide and the host result is used, so AUTO can never change
+behavior.
 """
 
 from __future__ import annotations
 
 import math
-import os
 from typing import List
 
 import numpy as np
 
 from tpusim.gang import oracle as _oracle
-from tpusim.obs.recorder import note_auto_transition
-
-# process-wide trust state; reset by jaxe.backend.reset_fast_auto() for test
-# isolation alongside _FAST_AUTO/_VICTIM_AUTO
-_GANG_AUTO = {"disabled": False, "verified_sigs": set()}
-
-
-def gang_kernel_enabled() -> tuple:
-    """(enabled, auto_mode) for the batched gang select kernel."""
-    env = os.environ.get("TPUSIM_GANG_KERNEL")
-    if env == "0":
-        return False, False
-    if _GANG_AUTO["disabled"]:
-        return False, False
-    if env == "1":
-        return True, False
-    return True, True
+from tpusim.jaxe.trust import GANG
 
 
 def _sig(m: int, n: int) -> str:
@@ -51,8 +33,7 @@ def gang_choices(feasible: np.ndarray, score: np.ndarray,
     """Solve the joint packing for one gang, routing host/device per the
     AUTO seam. All inputs are host numpy; the device path ships them through
     jit and the result is compared (or trusted) per signature."""
-    enabled, auto = gang_kernel_enabled()
-    host: List[int] = []
+    enabled, auto = GANG.mode()
 
     def run_host() -> List[int]:
         return _oracle.select_oracle(
@@ -93,14 +74,8 @@ def gang_choices(feasible: np.ndarray, score: np.ndarray,
     if not auto:
         return device
     sig = _sig(*feasible.shape)
-    if sig in _GANG_AUTO["verified_sigs"]:
-        note_auto_transition("trust", sig)
+    if GANG.trusted(sig):
+        GANG.note_reuse(sig)
         return device
     host = run_host()
-    if host == device:
-        _GANG_AUTO["verified_sigs"].add(sig)
-        note_auto_transition("verify_pass", sig)
-        return device
-    _GANG_AUTO["disabled"] = True
-    note_auto_transition("verify_fail", sig)
-    return host
+    return device if GANG.verdict(sig, host == device) else host

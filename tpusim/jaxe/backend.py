@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import logging
 import os
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import numpy as np
 
@@ -37,6 +37,7 @@ from tpusim.backends import (
     placement_hash,
 )
 from tpusim.engine.generic_scheduler import NO_NODE_AVAILABLE_MSG
+from tpusim.framework.metrics import register, since_in_microseconds
 from tpusim.engine.providers import (
     CLUSTER_AUTOSCALER_PROVIDER,
     DEFAULT_PROVIDER,
@@ -54,55 +55,16 @@ from tpusim.jaxe.kernels import (
     schedule_scan_chunked,
     statics_to_device,
 )
+from tpusim.jaxe.sharding import shard_mesh, stage_node_sharded, stage_tree
 from tpusim.jaxe.state import NUM_FIXED_BITS, compile_cluster, reason_strings
+from tpusim.jaxe import trust
+from tpusim.jaxe.trust import FAST, SHARD
 from tpusim.obs import analytics
 from tpusim.obs import provenance
 from tpusim.obs import recorder as flight
 from tpusim.obs import tracectx
 
 log = logging.getLogger(__name__)
-
-
-# process-wide fast-path auto-mode state: `disabled` flips the first time a
-# self-verification chunk disagrees with the XLA scan (never re-enabled);
-# `verified_sigs` holds the kernel signatures (the _build_call variant:
-# shape pads + feature flags) whose first large-enough batch verified —
-# each distinct Pallas/Mosaic kernel variant earns trust separately
-# (ADVICE r4: a process whose first verified batch was group-free must not
-# run the group-featured kernel unverified); `transient` counts consecutive
-# runtime failures such as a one-off device OOM — that batch reruns on the
-# XLA scan on the same device, and three in a row disable the path. Any
-# other kernel failure (a lowering or compile error) raises: there is no
-# quiet route around the device.
-_FAST_AUTO = {"disabled": False, "verified_sigs": set(), "transient": 0}
-
-_TRANSIENT_MARKERS = ("RESOURCE_EXHAUSTED", "out of memory", "OOM",
-                      "UNAVAILABLE", "DEADLINE_EXCEEDED", "CANCELLED")
-# a compile-time refusal can carry a runtime marker too (a VMEM or HBM
-# overflow is RESOURCE_EXHAUSTED) and is never transient
-_COMPILE_MARKERS = ("Mosaic", "compil", "lower", "vmem", "VMEM")
-_MAX_TRANSIENT_FAILURES = 3
-
-
-def reset_fast_auto() -> None:
-    """Reset every process-wide fast-path/victim-kernel trust flag to its
-    boot state. Test isolation ONLY: a test that trips the transient path or
-    a verify failure would otherwise leak `disabled`/pinned-signature state
-    into every later test in the process (ordering could then flip fast-path
-    eligibility mid-session). Wired as an autouse fixture in
-    tests/conftest.py; production code never calls it."""
-    _FAST_AUTO["disabled"] = False
-    _FAST_AUTO["verified_sigs"] = set()
-    _FAST_AUTO["transient"] = 0
-    _VICTIM_AUTO["disabled"] = False
-    _VICTIM_AUTO["verified_sigs"] = set()
-    _SHARD_AUTO["disabled"] = False
-    _SHARD_AUTO["verified_sigs"] = set()
-    from tpusim.gang.kernel import _GANG_AUTO  # lazy: gang imports backend
-    _GANG_AUTO["disabled"] = False
-    _GANG_AUTO["verified_sigs"] = set()
-    # disarm any leftover chaos seam (breaker + injector) the same way
-    uninstall_chaos()
 
 
 # plan_fast ineligibility reasons, classified into low-cardinality counter
@@ -138,12 +100,12 @@ def _fast_fallback_key(why: str) -> str:
     return "other"
 
 
-def _note_fast_fallback(metrics, why: str) -> None:
-    """Surface a plan_fast rejection as observability (ISSUE 4 satellite):
-    a labeled counter keyed by blocker class plus a flight-recorder instant
-    carrying the full reason string."""
+def _note_fast_fallback(why: str) -> None:
+    """Surface a plan_fast rejection as observability: a labeled counter
+    keyed by blocker class plus a flight-recorder instant carrying the full
+    reason string."""
     key = _fast_fallback_key(why)
-    metrics.fast_fallback.inc(key)
+    register().fast_fallback.inc(key)
     flight.note_fast_fallback(key, why)
 
 
@@ -180,164 +142,79 @@ def plan_signature(plan) -> tuple:
     return sig
 
 
-def _note_fast_failure(exc: Exception) -> bool:
-    """Classify a fast-path failure. A transient runtime error (ADVICE r4)
-    returns True: the caller reruns that batch on the XLA scan, and
-    _MAX_TRANSIENT_FAILURES in a row disable the path for the process.
-    Anything else returns False and the caller re-raises it."""
-    msg = f"{type(exc).__name__}: {exc}"
-    if (not any(m in msg for m in _TRANSIENT_MARKERS)
-            or any(m in msg for m in _COMPILE_MARKERS)):
-        return False
-    _FAST_AUTO["transient"] += 1
-    flight.note_auto_transition("discard_transient")
-    if _FAST_AUTO["transient"] >= _MAX_TRANSIENT_FAILURES:
-        _FAST_AUTO["disabled"] = True
-        flight.note_auto_transition("discard_permanent")
-        log.warning("pallas fast path: %d consecutive transient failures; "
-                    "disabling it for this process", _FAST_AUTO["transient"])
-    else:
-        log.warning("pallas fast path: transient failure %d/%d (%s); this "
-                    "batch reruns on the XLA scan", _FAST_AUTO["transient"],
-                    _MAX_TRANSIENT_FAILURES, msg)
-    return True
+class FastRoute(NamedTuple):
+    """The fast decision for one batch: the Pallas plan (None: the XLA
+    scan), its kernel signature, whether AUTO must verify the kernel's
+    results before they are used, and whether the route runs in AUTO."""
+    plan: object = None
+    sig: Optional[tuple] = None
+    verify: bool = False
+    auto: bool = False
+
+    def check(self, config, compiled, cols, choices, counts, *,
+              limit: Optional[int] = None, statics=None, carry=None) -> bool:
+        """AUTO's check of the kernel's results: the leading pods (at most
+        `limit`, where the caller ran fewer than the batch) replayed through
+        the XLA scan bit for bit, then FAST's verdict. The variant pins only
+        from trust.PIN_MIN_PODS replayed pods. statics/carry: the
+        policy-grafted XLA trees, where the caller has them."""
+        from tpusim.jaxe.fastscan import verify_against_xla
+
+        m = min(trust.REPLAY_PODS, len(np.asarray(cols.req_cpu)),
+                limit if limit is not None else trust.REPLAY_PODS)
+        ok = verify_against_xla(config, compiled, cols, choices, counts, m,
+                                statics=statics, carry=carry)
+        return FAST.verdict(self.sig, ok, pin=m >= trust.PIN_MIN_PODS)
 
 
-def _auto_verify_and_pin(config, compiled, cols, choices, counts,
-                         sig: tuple, limit: int = None,
-                         statics=None, carry=None) -> bool:
-    """AUTO-mode guardrail (shared by run_batch and the what-if fast loop):
-    replay the leading pods through the XLA scan and compare bit-for-bit.
-    Returns True when the fast results may be used; on disagreement the
-    fast path is disabled for the process. Trust is pinned per kernel
-    signature, only on a batch of TPUSIM_FAST_VERIFY_MIN+ pods."""
-    from tpusim.jaxe.fastscan import verify_against_xla
+def fast_route(config, compiled, cols, n_pods: int, *, ptabs=None,
+               placed_pods=None, refuse: Optional[str] = None,
+               pinned_only: bool = False) -> FastRoute:
+    """The fast decision every Pallas caller shares: FAST's mode, then
+    plan_fast (in the `plan_fast` span) and the plan's signature; an
+    ineligible plan is counted and logged as a fast_fallback.
 
-    m = min(int(os.environ.get("TPUSIM_FAST_VERIFY_PODS", 512)),
-            len(np.asarray(cols.req_cpu)))
-    if limit is not None:
-        # the caller produced fewer rows than the full batch (the
-        # preemption hybrid verifies on its first speculation chunk)
-        m = min(m, limit)
-    if not verify_against_xla(config, compiled, cols, choices, counts, m,
-                              statics=statics, carry=carry):
-        _FAST_AUTO["disabled"] = True
-        flight.note_auto_transition("verify_fail", str(sig))
-        log.warning("pallas fast path DISAGREES with the XLA scan on the "
-                    "first %d pods; disabling it for this process and "
-                    "re-running on the XLA scan", m)
-        return False
-    flight.note_auto_transition("verify_pass", str(sig))
-    min_pin = int(os.environ.get("TPUSIM_FAST_VERIFY_MIN", 64))
-    if m >= min_pin:
-        _FAST_AUTO["verified_sigs"].add(sig)
-        flight.note_auto_transition("pin", str(sig))
-        log.info("pallas fast path self-verified on the first %d pods; "
-                 "trusting kernel variant %s for this process", m, sig)
-    else:
-        log.info("pallas fast path verified on %d pods (< %d): keeping "
-                 "per-batch verification on", m, min_pin)
-    return True
+    refuse: what this batch needs that only the XLA scan gives, counted as
+    a fast_fallback without planning. pinned_only: the batch is too small
+    to pin a variant, so in AUTO it runs the kernel only on a variant
+    already pinned; with none pinned it is not even planned, and a dropped
+    batch is noted as `defer`."""
+    on, auto = FAST.mode()
+    if not on:
+        return FastRoute()
+    if refuse is not None:
+        _note_fast_fallback(refuse)
+        return FastRoute(auto=auto)
+    defer = auto and pinned_only
+    if not (defer and not FAST.verified):
+        from tpusim.jaxe.fastscan import plan_fast
 
-
-def _fast_path_enabled() -> tuple[bool, bool]:
-    """Returns (enabled, auto_mode).
-
-    TPUSIM_FAST=1 forces the Pallas fused-scan fast path (jaxe.fastscan) on
-    for eligible workloads (group-free, plus ports/disk-conflict/spreading/
-    volume-zone group features within the fast-path budgets), =0 forces it
-    off. Unset = AUTO: on
-    TPU the fast path is default-ON with first-chunk self-verification —
-    before trusting a kernel variant's first fast run, the backend re-runs
-    the leading pods through the XLA scan and compares choices bit-for-bit,
-    falling back (and disabling the fast path for the process) on any
-    disagreement. Off-TPU the kernel would run in the Pallas interpreter —
-    far slower than the XLA scan — so non-TPU backends require the explicit
-    opt-in with TPUSIM_FAST_INTERPRET=1 (correctness runs).
-
-    A process-wide `disabled` flag (a verify disagreement, or repeated
-    transient failures) is honored in BOTH modes: a failing kernel is not
-    re-attempted, and re-uploaded, on every batch (ADVICE r4)."""
-    env = os.environ.get("TPUSIM_FAST")
-    if env == "0":
-        return False, False
-    if _FAST_AUTO["disabled"]:
-        return False, False
-    if env == "1":
-        if os.environ.get("TPUSIM_FAST_INTERPRET") == "1":
-            return True, False
-        import jax
-
-        return jax.default_backend() == "tpu", False
-    # AUTO (round-3 VERDICT item 2: default-on on TPU, kill-switch kept)
-    import jax
-
-    return jax.default_backend() == "tpu", True
-
-# process-wide trust state for the device-side preemption victim-selection
-# kernel (jaxe/preempt.py), mirroring _FAST_AUTO: `disabled` flips on the
-# first device/host disagreement (never re-enabled); `verified_sigs` holds
-# (candidate_bucket, victim_bucket, zero_req) kernel-variant signatures whose
-# first device-selected preemption byte-matched the full host oracle
-# (selectVictimsOnNode + pickOneNodeForPreemption on cloned NodeInfos) —
-# pow2-bucketed shapes mean each compiled variant earns trust separately.
-_VICTIM_AUTO = {"disabled": False, "verified_sigs": set()}
+        with flight.span("plan_fast") as psp:
+            plan, why = plan_fast(config, compiled, cols,
+                                  placed_pods=placed_pods, ptabs=ptabs)
+            if psp:
+                psp.set("pods", n_pods)
+                psp.set("nodes", len(compiled.statics.names))
+                if plan is not None and plan.has_interpod:
+                    psp.set("interpod_doms", plan.n_topo_doms_ip)
+                    psp.set("hostname_terms", plan.hostname_terms)
+        if plan is None:
+            _note_fast_fallback(why)
+            log.info("pallas fast path ineligible (%s); using the XLA scan",
+                     why)
+            return FastRoute(auto=auto)
+        sig = plan_signature(plan)
+        route = FastRoute(plan, sig, auto and not FAST.trusted(sig), auto)
+        if not (defer and route.verify):
+            return route
+    flight.note_auto_transition("defer")
+    log.info("pallas fast path deferred: %d pods is below the "
+             "self-verification threshold; using the XLA scan", n_pods)
+    return FastRoute(auto=auto)
 
 
-def victim_kernel_enabled() -> tuple[bool, bool]:
-    """Returns (enabled, auto_mode) for the preemption victim-selection
-    kernel.
-
-    TPUSIM_PREEMPT_DEVICE=0 forces the host pipeline, =1 forces the device
-    kernel WITHOUT first-use verification (benchmark/debug). Unset = AUTO:
-    default-ON on every backend — the kernel is a jitted XLA scan (not
-    Pallas), fast on CPU too — with first-preemption-per-variant
-    verification against the host oracle; any disagreement disables the
-    kernel for the process and the host result is used, so AUTO can never
-    change behavior. The `disabled` flag is honored in both modes."""
-    env = os.environ.get("TPUSIM_PREEMPT_DEVICE")
-    if env == "0":
-        return False, False
-    if _VICTIM_AUTO["disabled"]:
-        return False, False
-    if env == "1":
-        return True, False
-    return True, True
-
-
-# process-wide trust state for the node-sharded scan route (ISSUE 16),
-# mirroring _FAST_AUTO: `disabled` flips the first time a sharded dispatch's
-# choices/counts disagree with the single-device replay (never re-enabled);
-# `verified_sigs` holds (shard_count, config) pairs whose first batch
-# verified — a different shard count or engine config compiles a different
-# collective program and earns trust separately.
-_SHARD_AUTO = {"disabled": False, "verified_sigs": set()}
-
-
-def _shard_count() -> int:
-    """TPUSIM_SHARDS=k (k > 1) opts the XLA scan into the node-sharded
-    shard_map route over a k-device mesh. Unset, 1, 0, or garbage selects
-    the single-device route — k=1 must not even build a mesh, so those
-    placement chains stay byte-identical to pre-shard builds. A k beyond
-    the device count raises: the sharded route never quietly becomes the
-    single-device one."""
-    try:
-        k = int(os.environ.get("TPUSIM_SHARDS", "1"))
-    except ValueError:
-        return 1
-    if k <= 1:
-        return 1
-    import jax
-
-    if len(jax.devices()) < k:
-        raise ValueError(
-            f"TPUSIM_SHARDS={k} needs {k} devices; this process has "
-            f"{len(jax.devices())} ({jax.devices()[0].platform})")
-    return k
-
-
-def _dispatch_sharded(config, mesh, n_shards, statics, carry, xs,
-                      use_chunks, scan_chunk, metrics):
+def _dispatch_sharded(config, mesh, statics, carry, xs, use_chunks,
+                      scan_chunk):
     """One node-sharded dispatch: pad the node axis shard-even, place the
     trees per the mesh's node shardings, run the shard_map scan (chunked
     or single), and stamp the tpusim_shard_* telemetry. Returns
@@ -348,31 +225,15 @@ def _dispatch_sharded(config, mesh, n_shards, statics, carry, xs,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from tpusim.jaxe.kernels import sharded_scan_fn
-    from tpusim.jaxe.sharding import (
-        node_shardings,
-        pad_node_axis,
-        stage_tree,
-    )
 
     sconfig = _dc_replace(config, shard_axis="node")
-    with flight.span("shard:stage") as ssp:
-        st, ca, n_real = pad_node_axis(statics, carry, n_shards)
-        st_sh, ca_sh = node_shardings(mesh)
-        st = stage_tree(st, st_sh)
-        ca = stage_tree(ca, ca_sh)
-        if ssp:
-            ssp.set("shards", n_shards)
-            ssp.set("nodes", n_real)
-    per = st.alloc_cpu.shape[0] // n_shards
-    metrics.shard_count.set(n_shards)
-    for s in range(n_shards):
-        metrics.shard_node_occupancy.set(
-            str(s), max(0, min(n_real - s * per, per)))
+    n_shards = mesh.shape["node"]
+    st, ca, _ = stage_node_sharded(mesh, statics, carry)
     # estimated collective payload: each pod step moves ~12 psum/pmax/pmin
     # scalars plus an n_shards-wide tie-count all_gather, 8 bytes each, on
     # every shard (the analytics/gang collectives are separate dispatches)
     n_pods = int(np.asarray(xs.req_cpu).shape[0])
-    metrics.shard_collective_bytes.set(
+    register().shard_collective_bytes.set(
         float(n_pods) * (12 + n_shards) * 8 * n_shards)
     rep = NamedSharding(mesh, P())
     with flight.span("shard:scan", "device") as sp:
@@ -397,7 +258,7 @@ _KNOWN_PROVIDERS = {DEFAULT_PROVIDER} | _MOST_REQUESTED_PROVIDERS
 # process-wide chaos seam (ISSUE 3): when installed, every JaxBackend
 # dispatch flows through the circuit breaker (closed → open on repeated
 # device faults → half-open re-probe → closed) and the DeviceInjector
-# scripts per-dispatch exceptions/corruptions. Upgrades the _FAST_AUTO
+# scripts per-dispatch exceptions/corruptions. Upgrades trust.FAST's
 # three-strikes-and-permanently-out policy into a RECOVERING state
 # machine: a flaky device degrades to the host pipeline and comes back,
 # and under chaos no placement is ever emitted unverified (verify="all")
@@ -569,7 +430,7 @@ class JaxBackend:
             return placements
         from time import perf_counter
 
-        from tpusim.framework.metrics import register, since_in_microseconds
+        n_pods = len(pods)
 
         metrics = register()
 
@@ -614,7 +475,7 @@ class JaxBackend:
                 raise NotImplementedError(
                     f"jax backend does not yet carry state for: {detail}")
             log.warning("jax backend falling back to reference for: %s", detail)
-            flight.note_route("reference_fallback", len(pods))
+            flight.note_route("reference_fallback", n_pods)
             return self._reference(pods, snapshot)
 
         # the engine configuration and the route: everything between the
@@ -649,84 +510,37 @@ class JaxBackend:
         # asked for a score breakdown compiles the top-k lanes into the
         # scan. explain_k is a jit static, so provenance-off programs (the
         # default) are byte-identical to pre-provenance ones.
+        names = compiled.statics.names
+        n_nodes = len(names)
         explain_k = provenance.requested_top_k()
         if explain_k > 0:
             from dataclasses import replace as _explain_replace
 
             config = _explain_replace(
-                config,
-                explain_k=min(explain_k, len(compiled.statics.names)))
+                config, explain_k=min(explain_k, n_nodes))
+        explain = config.explain_k > 0
 
         ensure_x64()
+        if esp:
+            esp.end()
         # fast-path decision BEFORE any device upload: when the Pallas plan
         # engages, the statics/carry/pod-column HBM transfers below would be
-        # pure wasted latency on exactly the hot path the feature accelerates
-        fplan = None
-        fast_verify = False
-        fast_sig = None
-        fast_on, auto_mode = _fast_path_enabled()
-        if fast_on and config.explain_k > 0:
-            # the score-breakdown lanes are an XLA-scan feature: the Pallas
-            # kernel carries failure provenance natively (reason counts) but
-            # not per-part top-k scores (DEVIATIONS.md)
-            fast_on = False
-            _note_fast_fallback(
-                metrics, "explain lanes (top-k score breakdown) route "
-                "through the XLA scan")
-        if fast_on and analytics.get() is not None:
-            # the analytics reduction folds the scan's final carry, which
-            # the Pallas kernel never materializes (it emits choices/counts
-            # only) — same precedent as the explain lanes above
-            fast_on = False
-            _note_fast_fallback(
-                metrics, "cluster analytics rides the XLA scan's final "
-                "carry")
-        if (fast_on and auto_mode and not _FAST_AUTO["verified_sigs"]
-                and len(pods) < int(os.environ.get(
-                    "TPUSIM_FAST_VERIFY_MIN", 64))):
-            # no variant is trusted yet, so this small batch would be
-            # deferred after planning anyway — skip the O(nodes+pods)
-            # gcd reduction entirely (the pre-signature fast exit)
-            fast_on = False
-            flight.note_auto_transition("defer")
-            log.info("pallas fast path deferred: %d pods is below "
-                     "the self-verification threshold; using the "
-                     "XLA scan", len(pods))
-        if esp:
-            esp.set("fast", bool(fast_on))
-            esp.end()
-        if fast_on:
-            from tpusim.jaxe.fastscan import plan_fast
+        # pure wasted latency on exactly the hot path the feature accelerates.
+        # The score-breakdown lanes are an XLA-scan feature (the kernel
+        # carries reason counts, not per-part top-k scores, DEVIATIONS.md),
+        # and the analytics reduction folds the scan's final carry, which the
+        # kernel never materializes. A batch below trust.PIN_MIN_PODS cannot
+        # pin a variant, and running the kernel AND the XLA replay would be
+        # strictly slower than plain XLA: it takes only a pinned variant.
+        refuse = ("explain lanes (top-k score breakdown) route through the "
+                  "XLA scan" if explain
+                  else "cluster analytics rides the XLA scan's final carry"
+                  if analytics.get() is not None else None)
+        fast = fast_route(config, compiled, cols, n_pods, ptabs=ptabs,
+                          refuse=refuse,
+                          pinned_only=n_pods < trust.PIN_MIN_PODS)
+        fplan, fast_sig, fast_verify, auto_mode = fast
 
-            with flight.span("plan_fast") as psp:
-                fplan, why = plan_fast(config, compiled, cols, ptabs=ptabs)
-                if psp:
-                    psp.set("pods", len(pods))
-                    psp.set("nodes", len(snapshot.nodes))
-                    if fplan is not None and fplan.has_interpod:
-                        psp.set("interpod_doms", fplan.n_topo_doms_ip)
-                        psp.set("hostname_terms", fplan.hostname_terms)
-            if fplan is None:
-                _note_fast_fallback(metrics, why)
-                log.info("pallas fast path ineligible (%s); using the "
-                         "XLA scan", why)
-            else:
-                fast_sig = plan_signature(fplan)
-                fast_verify = (auto_mode and fast_sig
-                               not in _FAST_AUTO["verified_sigs"])
-            if fplan is not None and fast_verify and len(pods) < int(
-                    os.environ.get("TPUSIM_FAST_VERIFY_MIN", 64)):
-                # AUTO mode, variant not yet trusted: a batch too small to
-                # pin trust (< TPUSIM_FAST_VERIFY_MIN) would run the kernel
-                # AND a full XLA replay — strictly slower than plain XLA.
-                # Small batches gain nothing from the fast path anyway;
-                # route them straight to the XLA scan.
-                fplan = None
-                fast_verify = False
-                flight.note_auto_transition("defer")
-                log.info("pallas fast path deferred: %d pods is below "
-                         "the self-verification threshold; using the "
-                         "XLA scan", len(pods))
         def _xla_statics():
             if cp is None:
                 return statics_to_device(compiled)
@@ -748,34 +562,38 @@ class JaxBackend:
                 carry = carry._replace(sa_lock=ptabs.sa_lock_init)
             return carry
 
-        usp = (flight.span("batch_upload") if fplan is None
-               else flight.NOOP_SPAN)
-        statics = None if fplan is not None else _xla_statics()
         # Batches beyond TPUSIM_SCAN_CHUNK pods run through the
         # double-buffered chunked scan: pod columns stay host-side and stream
         # to HBM chunk by chunk, bit-identical to the single dispatch
         # (SURVEY.md §7 hard part 6 — 1M-pod batches).
         scan_chunk = int(os.environ.get("TPUSIM_SCAN_CHUNK", 131072))
-        use_chunks = (fplan is None and config.explain_k == 0
-                      and scan_chunk > 0 and len(pods) > scan_chunk)
+        chunked = not explain and 0 < scan_chunk < n_pods
+
+        def _xla_inputs():
+            # (statics, carry, use_chunks, xs): the XLA scan's uploads
+            return (_xla_statics(), _xla_carry(), chunked,
+                    pod_columns_to_host(cols) if chunked
+                    else pod_columns_to_device(cols))
+
+        statics = carry = xs = None
+        use_chunks = False
         if fplan is None:
-            carry = _xla_carry()
-            xs = (pod_columns_to_host(cols) if use_chunks
-                  else pod_columns_to_device(cols))
-        if usp:
-            usp.set("pods", len(pods))
-            usp.end()
+            with flight.span("batch_upload") as usp:
+                statics, carry, use_chunks, xs = _xla_inputs()
+                if usp:
+                    usp.set("pods", n_pods)
         # On TPU the per-pod filter→score→select→bind pipeline is one fused
         # device program, so the whole batch dispatch lands in the algorithm
         # histogram (the per-phase split of metrics.go has no device analog);
         # e2e additionally covers host-side result materialization.
         # chaos seam: scripted dispatch faults raise here (outside the fast
         # path's own try/except — an injected device death is not a Mosaic
-        # lowering failure and must reach the circuit breaker, not flip
-        # _FAST_AUTO); scripted corruptions apply to the results below
-        _corrupt_kind = None
-        if _CHAOS["injector"] is not None:
-            _corrupt_kind = _CHAOS["injector"].begin_dispatch()
+        # lowering failure and must reach the circuit breaker, not count
+        # against trust.FAST); scripted corruptions apply to the results
+        # below
+        injector = _CHAOS["injector"]
+        _corrupt_kind = (None if injector is None
+                         else injector.begin_dispatch())
         dispatch_start = perf_counter()
         dsp = flight.span("device_dispatch", "device")
 
@@ -783,16 +601,20 @@ class JaxBackend:
             # pay the uploads the fast path deferred and rebuild the
             # XLA-scan inputs (set via nonlocal) with a fresh dispatch
             # clock; whether the path stays disabled for the process is
-            # the caller's call (_note_fast_failure / _auto_verify_and_pin)
+            # trust.FAST's call (note_failure / the verdict)
             nonlocal fplan, statics, carry, use_chunks, xs, dispatch_start
             fplan = None
-            statics = _xla_statics()
-            carry = _xla_carry()
-            use_chunks = (config.explain_k == 0
-                          and scan_chunk > 0 and len(pods) > scan_chunk)
-            xs = (pod_columns_to_host(cols) if use_chunks
-                  else pod_columns_to_device(cols))
+            statics, carry, use_chunks, xs = _xla_inputs()
             dispatch_start = perf_counter()
+
+        def _scan_single(carry):
+            # the single-device XLA scan: (final carry, choices, counts,
+            # explain lanes or None)
+            if use_chunks:
+                return schedule_scan_chunked(config, carry, statics, xs,
+                                             scan_chunk)[:3] + (None,)
+            out = schedule_scan(config, carry, statics, xs)
+            return out[:3] + (out[4] if explain else None,)
 
         if fplan is not None:
             from tpusim.jaxe.fastscan import fast_scan
@@ -804,110 +626,67 @@ class JaxBackend:
                 # a transient runtime error reruns this batch on the XLA
                 # scan, on the same device; a lowering or compile error
                 # propagates
-                if not _note_fast_failure(exc):
+                if not FAST.note_failure(exc):
                     raise
                 _discard_fast_path()
             else:
-                _FAST_AUTO["transient"] = 0
-                if fast_verify and not _auto_verify_and_pin(
-                        config, compiled, cols, choices, counts, fast_sig,
+                FAST.note_success()
+                if fast_verify and not fast.check(
+                        config, compiled, cols, choices, counts,
                         statics=_xla_statics(), carry=_xla_carry()):
-                    # the kernel lowered but miscomputed: the guardrail
+                    # the kernel lowered but miscomputed: the verdict
                     # already disabled it process-wide; rerun on XLA
                     _discard_fast_path()
                 elif auto_mode and not fast_verify:
                     # already-pinned variant ran without re-verification
-                    flight.note_auto_transition("trust", str(fast_sig))
-        # node-sharded route decision (ISSUE 16): TPUSIM_SHARDS=k > 1 runs
-        # the same fused scan as a shard_map over a k-device node mesh —
-        # bit-identical placements via cross-shard collectives, so every
-        # ineligibility is a classified fallback to the single-device scan,
-        # never a behavior change
-        n_shards = _shard_count()
-        shard_mesh = None
+                    FAST.note_reuse(str(fast_sig))
+        # node-sharded route (TPUSIM_SHARDS=k > 1): the same fused scan as
+        # a shard_map over a k-device node mesh
+        mesh = (shard_mesh(config, "batch routed single-device")
+                if fplan is None else None)
         shard_statics = None
-        if fplan is None and n_shards > 1 and not _SHARD_AUTO["disabled"]:
-            from tpusim.jaxe.kernels import shard_route_eligible
-
-            ok, why = shard_route_eligible(config)
-            if not ok:
-                metrics.shard_fallback.inc(why)
-                flight.note_fast_fallback(
-                    "shard_" + why,
-                    f"TPUSIM_SHARDS={n_shards} batch routed single-device")
-                log.info("sharded route ineligible (%s); using the "
-                         "single-device scan", why)
-            else:
-                from tpusim.jaxe.sharding import make_mesh
-
-                shard_mesh = make_mesh(n_shards, snap=1)
         explain_lanes = None
         final_carry = None  # bound-and-dropped unless analytics reads it
         if fplan is None:  # fast path off, ineligible, or discarded above
             with flight.span("scan_launch", "device"), \
                     flight.profiled("tpusim:schedule_scan"):
-                if shard_mesh is not None:
+                if mesh is not None:
                     (final_carry, choices, counts,
                      shard_statics) = _dispatch_sharded(
-                         config, shard_mesh, n_shards, statics, carry,
-                         xs, use_chunks, scan_chunk, metrics)
-                elif use_chunks:
-                    final_carry, choices, counts, _ = schedule_scan_chunked(
-                        config, carry, statics, xs, scan_chunk)
-                elif config.explain_k > 0:
-                    (final_carry, choices, counts, _,
-                     explain_lanes) = schedule_scan(config, carry,
-                                                    statics, xs)
+                         config, mesh, statics, carry, xs, use_chunks,
+                         scan_chunk)
                 else:
                     (final_carry, choices, counts,
-                     _) = schedule_scan(config, carry, statics, xs)
+                     explain_lanes) = _scan_single(carry)
         with flight.span("device_wait", "device"):
             choices = np.asarray(choices)
             counts = np.asarray(counts)
-        if shard_mesh is not None:
-            # verify-then-trust, the same seam as the fast path: the first
-            # batch per (shard count, config) replays its leading pods
-            # through the single-device scan bit-for-bit; a disagreement
-            # disables the sharded route for the process and this batch
-            # reruns single-device (TPUSIM_SHARD_VERIFY=0 skips, bench only)
+        if mesh is not None:
+            # verify-then-trust (trust.SHARD): the first batch per (shard
+            # count, config) replays its leading pods through the
+            # single-device scan bit-for-bit; a disagreement disables the
+            # sharded route for the process and this batch reruns
+            # single-device
+            n_shards = mesh.shape["node"]
             shard_sig = (n_shards, config)
-            if os.environ.get("TPUSIM_SHARD_VERIFY") == "0":
-                pass
-            elif shard_sig in _SHARD_AUTO["verified_sigs"]:
-                flight.note_auto_transition("shard_trust", str(n_shards))
+            if SHARD.trusted(shard_sig):
+                SHARD.note_reuse(str(n_shards))
             else:
                 from tpusim.jaxe.fastscan import verify_against_xla
 
-                if verify_against_xla(config, compiled, cols, choices,
-                                      counts, statics=statics,
-                                      carry=_xla_carry()):
-                    _SHARD_AUTO["verified_sigs"].add(shard_sig)
-                    flight.note_auto_transition("shard_pin", str(n_shards))
-                else:
-                    _SHARD_AUTO["disabled"] = True
+                same = verify_against_xla(
+                    config, compiled, cols, choices, counts,
+                    trust.REPLAY_PODS, statics=statics, carry=_xla_carry())
+                if not SHARD.verdict(shard_sig, same, label=str(n_shards)):
                     metrics.shard_count.set(0)
-                    flight.note_auto_transition("shard_verify_fail",
-                                                str(n_shards))
-                    log.warning(
-                        "sharded scan DISAGREES with the single-device "
-                        "scan on the leading pods (shards=%d); disabling "
-                        "the sharded route for this process and re-running "
-                        "single-device", n_shards)
-                    shard_mesh = None
+                    mesh = None
                     shard_statics = None
                     with flight.profiled("tpusim:schedule_scan"):
-                        if use_chunks:
-                            (final_carry, choices, counts,
-                             _) = schedule_scan_chunked(
-                                 config, _xla_carry(), statics, xs,
-                                 scan_chunk)
-                        else:
-                            (final_carry, choices, counts,
-                             _) = schedule_scan(config, _xla_carry(),
-                                                statics, xs)
+                        final_carry, choices, counts, _ = _scan_single(
+                            _xla_carry())
                     choices = np.asarray(choices)
                     counts = np.asarray(counts)
-        if _CHAOS["injector"] is not None:
+        if injector is not None:
             if _corrupt_kind is not None:
                 from tpusim.chaos.engine import DeviceInjector
 
@@ -917,7 +696,6 @@ class JaxBackend:
             # choices and NaN counts never reach decode_placements
             from tpusim.chaos.engine import DeviceOutputError
 
-            n_nodes = len(compiled.statics.names)
             if choices.size and (int(choices.max()) >= n_nodes
                                  or int(choices.min()) < -1):
                 raise DeviceOutputError(
@@ -925,21 +703,17 @@ class JaxBackend:
             if np.isnan(np.asarray(counts, dtype=np.float64)).any():
                 raise DeviceOutputError("NaN in device reason counts")
         if fplan is not None:
-            # the interpreter only engages on the explicit TPUSIM_FAST=1
-            # opt-in (see _fast_path_enabled)
-            route = ("fastscan_interpret"
-                     if os.environ.get("TPUSIM_FAST") == "1"
-                     and os.environ.get("TPUSIM_FAST_INTERPRET") == "1"
+            route = ("fastscan_interpret" if trust.fast_interpret()
                      else "fastscan")
-        elif shard_mesh is not None:
+        elif mesh is not None:
             route = "xla_sharded_chunked" if use_chunks else "xla_sharded"
         else:
             route = "xla_chunked" if use_chunks else "xla_scan"
-        flight.note_route(route, len(pods))
+        flight.note_route(route, n_pods)
         if dsp:
             dsp.set("route", route)
-            dsp.set("pods", len(pods))
-            if shard_mesh is not None:
+            dsp.set("pods", n_pods)
+            if mesh is not None:
                 dsp.set("shards", n_shards)
             if fast_sig is not None:
                 dsp.set("sig", str(fast_sig))
@@ -947,16 +721,16 @@ class JaxBackend:
         # trace-id exemplar (ISSUE 20): a dispatch-latency spike on the
         # dashboard resolves to the exact device-dispatch trace
         _ctx = tracectx.current()
+        exemplar = _ctx.trace_id if _ctx is not None else None
         metrics.backend_dispatch_latency.observe(
-            since_in_microseconds(dispatch_start),
-            exemplar=_ctx.trace_id if _ctx is not None else None)
+            since_in_microseconds(dispatch_start), exemplar=exemplar)
         metrics.scheduling_algorithm_latency.observe(
             since_in_microseconds(dispatch_start))
 
         strings = reason_strings(compiled.scalar_names)
         with flight.span("decode_placements") as dec:
-            placements, _ = decode_placements(pods, choices, counts,
-                                              compiled.statics.names, strings)
+            placements, _ = decode_placements(pods, choices, counts, names,
+                                              strings)
             if dec:
                 dec.set("shared_binds", shared_binds(pods, placements))
         prov = provenance.get_log()
@@ -964,11 +738,10 @@ class JaxBackend:
             topk = None
             if explain_lanes is not None:
                 top_idx, top_scores, top_parts = explain_lanes
-                p = len(pods)
-                topk = {"idx": np.asarray(top_idx)[:p],
-                        "scores": np.asarray(top_scores)[:p],
-                        "parts": np.asarray(top_parts)[:p],
-                        "names": compiled.statics.names,
+                topk = {"idx": np.asarray(top_idx)[:n_pods],
+                        "scores": np.asarray(top_scores)[:n_pods],
+                        "parts": np.asarray(top_parts)[:n_pods],
+                        "names": names,
                         "part_names": explain_part_names(config),
                         "sentinel": EXPLAIN_SENTINEL}
             prov.capture_batch(placements, "backend", topk=topk)
@@ -978,9 +751,9 @@ class JaxBackend:
             # sharded route both trees are padded + node-sharded, so the
             # reduction runs the two-level cross-shard merge
             analytics.capture(
-                shard_statics if shard_mesh is not None else statics,
-                final_carry, len(compiled.statics.names), "backend",
-                names=compiled.statics.names, mesh=shard_mesh)
+                shard_statics if mesh is not None else statics,
+                final_carry, n_nodes, "backend",
+                names=names, mesh=mesh)
         # e2e additionally covers host-side result materialization
         metrics.e2e_scheduling_latency.observe(
             since_in_microseconds(dispatch_start))

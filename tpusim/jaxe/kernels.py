@@ -1373,7 +1373,7 @@ schedule_scan_donated = jax.jit(_schedule_scan_impl,
 # the (shard-even padded) node axis; per-step reductions and host selection
 # merge through the collectives threaded above, so placements are
 # bit-identical to the single-device scan — the backend's verify-then-trust
-# seam (_SHARD_AUTO) replays the first batch per signature to prove it.
+# seam (trust.SHARD) replays the first batch per signature to prove it.
 
 def node_partition_specs(axis: str = "node"):
     """(Statics, Carry, PodX) PartitionSpec trees for the node-sharded route,

@@ -72,12 +72,7 @@ from tpusim.framework.report import Status
 from tpusim.framework.store import ADDED
 from tpusim.framework.store import DELETED as EV_DELETED
 from tpusim.jaxe import ensure_x64
-from tpusim.jaxe.backend import (
-    _MOST_REQUESTED_PROVIDERS,
-    _VICTIM_AUTO,
-    format_fit_error,
-    victim_kernel_enabled,
-)
+from tpusim.jaxe.backend import _MOST_REQUESTED_PROVIDERS, format_fit_error
 from tpusim.jaxe.delta import IncrementalCluster
 from tpusim.jaxe.kernels import (
     PodX,
@@ -91,6 +86,7 @@ from tpusim.jaxe.kernels import (
 )
 from tpusim.jaxe.policyc import classify_preemption_class
 from tpusim.jaxe.state import NUM_FIXED_BITS, reason_strings, victim_order_columns
+from tpusim.jaxe.trust import FAST, VICTIM
 from tpusim.obs import recorder as flight
 
 log = logging.getLogger(__name__)
@@ -105,20 +101,13 @@ log = logging.getLogger(__name__)
 #   "fallback"        kernel disagreed with the oracle: disabled for the
 #                     process, host result used
 # Read by tests and bench.py (stamped into the config-6 record); reset with
-# reset_preempt_class_stats().
-PREEMPT_CLASS_STATS: Counter = Counter()
+# reset_preempt_class_stats(). flight.note_victim_path counts them.
+PREEMPT_CLASS_STATS: Counter = flight.VICTIM_PATHS
 
 
 def reset_preempt_class_stats() -> None:
     PREEMPT_CLASS_STATS.clear()
 
-
-def _note_victim_path(path: str) -> None:
-    """One preemption's victim-selection path: bumps the in-module Counter
-    (read by tests/bench) and the tpusim_backend_victim_path_total metric
-    family + recorder instant in one place."""
-    PREEMPT_CLASS_STATS[path] += 1
-    flight.note_victim_path(path)
 
 def _next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
@@ -426,7 +415,7 @@ def _device_preempt(cc, vtable: _VictimTable, compiled, cols, row, pod: Pod,
                       "the host pipeline", pod.key(), payload)
         return "skip", payload
     name = compiled.statics.names[win]
-    if auto_mode and sig not in _VICTIM_AUTO["verified_sigs"]:
+    if auto_mode and not VICTIM.trusted(sig):
         # first preemption on this kernel variant: run the FULL host
         # pipeline alongside and compare (node, ordered victim keys)
         node_infos = cc.refresh_node_info_snapshot()
@@ -449,22 +438,12 @@ def _device_preempt(cc, vtable: _VictimTable, compiled, cols, row, pod: Pod,
         agree = (host_node is not None and host_node.name == name
                  and [v.key() for v in host_victims]
                  == [v.key() for v in payload])
-        if agree:
-            _VICTIM_AUTO["verified_sigs"].add(sig)
-            _note_victim_path("device_verified")
-            log.info("preempt-victim kernel verified against the host "
-                     "oracle (variant %s); trusting it for this process",
-                     sig)
-        else:
-            _VICTIM_AUTO["disabled"] = True
-            _note_victim_path("fallback")
-            log.error(
-                "preempt-victim kernel DISAGREES with the host oracle for "
-                "pod %s (device: %s + %d victims; host: %s + %d victims); "
-                "disabling it for this process and using the host result",
-                pod.key(), name, len(payload),
-                host_node.name if host_node is not None else None,
-                len(host_victims))
+        if not VICTIM.verdict(sig, agree):
+            log.error("preempt-victim kernel for pod %s: device %s + %d "
+                      "victims, host %s + %d victims; using the host result",
+                      pod.key(), name, len(payload),
+                      host_node.name if host_node is not None else None,
+                      len(host_victims))
         node, victims = cc.commit_preemption(pod, host_node, host_victims,
                                              host_to_clear)
         if node is None:
@@ -475,7 +454,7 @@ def _device_preempt(cc, vtable: _VictimTable, compiled, cols, row, pod: Pod,
     metrics.preemption_attempts.inc()
     to_clear = cc.scheduler._get_lower_priority_nominated_pods(pod, name)
     metrics.preemption_evaluation.observe(since_in_microseconds(start))
-    _note_victim_path("device")
+    flight.note_victim_path("device")
     node, victims = cc.commit_preemption(pod, by_name[name], payload,
                                          to_clear)
     return "committed", (node, victims)
@@ -582,14 +561,8 @@ def run_with_preemption(pods: List[Pod], snapshot: ClusterSnapshot,
     # gcds (exact — placed pods' requests joined the gcd fold). Host arms
     # (victim selection, binds, report) are untouched: placements stay
     # byte-identical to the XLA hybrid, pinned by the differential suites.
-    from tpusim.jaxe.backend import (
-        _FAST_AUTO,
-        _auto_verify_and_pin,
-        _fast_path_enabled,
-        _note_fast_failure,
-        plan_signature,
-    )
-    from tpusim.jaxe.fastscan import fast_scan, init_carry, plan_fast, rearm_carry
+    from tpusim.jaxe.backend import FastRoute, fast_route
+    from tpusim.jaxe.fastscan import fast_scan, init_carry, rearm_carry
 
     # placed-pod values for the gcd fold: initial snapshot placements plus
     # every pod bound during the run (appended at both bind arms below — a
@@ -687,37 +660,24 @@ def run_with_preemption(pods: List[Pod], snapshot: ClusterSnapshot,
             names = compiled.statics.names
             base = pos            # plan/column row i holds feed[base + i]
 
-            # fast-path decision BEFORE the statics upload (same rule as
-            # backend.schedule): when the kernel engages, the XLA-scan
-            # inputs are never materialized
-            fplan = fcarry = fsig = None
-            fverify = False
-            fast_on, auto_mode = _fast_path_enabled()
-            if mesh is not None:
-                fast_on = False  # Pallas plan is single-device; mesh -> XLA
-            if fast_on:
-                fplan, why = plan_fast(config, compiled, cols,
-                                       placed_pods=placed_for_gcd)
-                if fplan is None:
-                    log.info("preemption fast path ineligible (%s); using "
-                             "the XLA scan", why)
-                else:
-                    fsig = plan_signature(fplan)
-                    if (auto_mode
-                            and fsig not in _FAST_AUTO["verified_sigs"]
-                            and not (pos == 0 and rr_start == 0)):
-                        # verification replays from carry_init (rr=0): an
-                        # unverified variant can only earn trust on the
-                        # run's very first chunk — later compiles of an
-                        # untrusted variant stay on the XLA scan
-                        log.info("preemption fast path deferred: kernel "
-                                 "variant unverified and the run is past "
-                                 "its first chunk")
-                        fplan = fsig = None
-                    else:
-                        fcarry = init_carry(fplan, rr=rr_start)
-                        fverify = (auto_mode and fsig
-                                   not in _FAST_AUTO["verified_sigs"])
+            # fast-path decision BEFORE the statics upload (the shared
+            # backend.fast_route): when the kernel engages, the XLA-scan
+            # inputs are never materialized. The Pallas plan is
+            # single-device, so a mesh keeps the XLA scan.
+            fast = (fast_route(config, compiled, cols, len(feed) - pos,
+                               placed_pods=placed_for_gcd)
+                    if mesh is None else FastRoute())
+            if fast.verify and not (pos == 0 and rr_start == 0):
+                # verification replays from carry_init (rr=0): an
+                # unverified variant can only earn trust on the run's very
+                # first chunk — later compiles of an untrusted variant stay
+                # on the XLA scan
+                log.info("preemption fast path deferred: kernel variant "
+                         "unverified and the run is past its first chunk")
+                fast = FastRoute(auto=fast.auto)
+            fplan = fast.plan
+            fverify = fast.verify
+            fcarry = None if fplan is None else init_carry(fplan, rr=rr_start)
             statics = xs_all = carry = None
             if fplan is None:
                 statics = statics_to_device(compiled)
@@ -750,13 +710,13 @@ def run_with_preemption(pods: List[Pod], snapshot: ClusterSnapshot,
                         # a transient runtime error: the outer loop
                         # recompiles feed[pos:] and re-decides the engine;
                         # a lowering or compile error propagates
-                        if not _note_fast_failure(exc):
+                        if not FAST.note_failure(exc):
                             raise
                         if dsp:
                             dsp.set("error", type(exc).__name__)
                             dsp.end()
                         break
-                    _FAST_AUTO["transient"] = 0
+                    FAST.note_success()
                     if fverify:
                         # ONCE, on the run's first chunk only (the plan
                         # gate guarantees pos==0, rr_start==0 there):
@@ -765,9 +725,8 @@ def run_with_preemption(pods: List[Pod], snapshot: ClusterSnapshot,
                         # chained-carry state — comparing those would be
                         # pods-vs-different-pods
                         fverify = False
-                        if not _auto_verify_and_pin(
-                                config, compiled, cols, choices, counts,
-                                fsig, limit=take):
+                        if not fast.check(config, compiled, cols, choices,
+                                          counts, limit=take):
                             if dsp:
                                 dsp.end()
                             break
@@ -847,7 +806,7 @@ def run_with_preemption(pods: List[Pod], snapshot: ClusterSnapshot,
                     dev_status = None
                     dev_payload = None
                     if vclass == "arithmetic":
-                        vk_on, vk_auto = victim_kernel_enabled()
+                        vk_on, vk_auto = VICTIM.mode()
                         preq_pod = get_resource_request(pod)
                         if (vk_on and not preq_pod.scalar
                                 and not pod.spec.volumes):
@@ -904,7 +863,7 @@ def run_with_preemption(pods: List[Pod], snapshot: ClusterSnapshot,
                         fit_err = FitError(pod, len(cc.nodes), failed)
                         cand = (bound.candidates(pod)
                                 if bound is not None else None)
-                        _note_victim_path("host")
+                        flight.note_victim_path("host")
                         node, victims = cc.attempt_preemption(
                             pod, fit_err,
                             candidate_filter=(cand.__contains__

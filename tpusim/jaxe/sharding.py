@@ -24,6 +24,8 @@ registries, so new state fields inherit padding + sharding automatically.
 
 from __future__ import annotations
 
+import logging
+import os
 import re
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -32,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpusim.framework.metrics import register
 from tpusim.jaxe.kernels import (
     CARRY_AXES,
     PAD_FILLS,
@@ -40,7 +43,13 @@ from tpusim.jaxe.kernels import (
     Carry,
     PodX,
     Statics,
+    shard_route_eligible,
 )
+from tpusim.jaxe.trust import SHARD
+from tpusim.obs import recorder as flight
+
+log = logging.getLogger(__name__)
+
 
 def _infeasible_sentinel():
     # computed lazily: jnp.int64 truncates to int32 before ensure_x64() runs
@@ -92,6 +101,44 @@ def make_mesh(n_devices: Optional[int] = None, snap: int = 1,
         raise _factoring_error(n, "snap", snap, "node")
     grid = np.array(devices).reshape(snap, n // snap)
     return Mesh(grid, ("snap", "node"))
+
+
+def shard_count() -> int:
+    """TPUSIM_SHARDS=k (k > 1) opts the XLA scan into the node-sharded
+    shard_map route over a k-device mesh. Unset, 1, 0, or garbage selects
+    the single-device route — k=1 must not even build a mesh, so those
+    placement chains stay byte-identical to pre-shard builds. A k beyond
+    the device count raises: the sharded route never quietly becomes the
+    single-device one."""
+    try:
+        k = int(os.environ.get("TPUSIM_SHARDS", "1"))
+    except ValueError:
+        return 1
+    if k > 1 and len(jax.devices()) < k:
+        raise ValueError(
+            f"TPUSIM_SHARDS={k} needs {k} devices; this process has "
+            f"{len(jax.devices())} ({jax.devices()[0].platform})")
+    return max(k, 1)
+
+
+def shard_mesh(config, detail: str) -> Optional[Mesh]:
+    """The node-sharded route's decision: a shard_count()-device node mesh
+    when that count is above one, the route is not disabled by a failed
+    verification (trust.SHARD) and the config is eligible. An ineligible
+    config is a classified fallback to the single-device scan (the
+    `shard_fallback` counter and a `fallback:shard_*` instant carrying
+    `detail`), never a behaviour change: the sharded scan is bit-identical."""
+    n = shard_count()
+    if n <= 1 or not SHARD.mode()[0]:
+        return None
+    ok, why = shard_route_eligible(config)
+    if not ok:
+        register().shard_fallback.inc(why)
+        flight.note_fast_fallback("shard_" + why,
+                                  f"TPUSIM_SHARDS={n} {detail}")
+        log.info("TPUSIM_SHARDS=%d %s (%s)", n, detail, why)
+        return None
+    return make_mesh(n, snap=1)
 
 
 def make_scenario_mesh(n_devices: Optional[int] = None,
@@ -246,6 +293,29 @@ def node_shardings(mesh: Mesh) -> Tuple[Statics, Carry]:
     and scalar axes replicated."""
     return (_sharding_tree(Statics, STATICS_AXES, mesh),
             _sharding_tree(Carry, CARRY_AXES, mesh))
+
+
+def stage_node_sharded(mesh: Mesh, statics, carry):
+    """The node-sharded route's staging (`shard:stage` span): pad the node
+    axis shard-even, place statics and carry per the mesh's node
+    shardings, and stamp the tpusim_shard_* layout gauges. Returns
+    (statics, carry, layout)."""
+    n_shards = mesh.shape["node"]
+    with flight.span("shard:stage") as ssp:
+        statics, carry, n_real = pad_node_axis(statics, carry, n_shards)
+        st_sh, ca_sh = node_shardings(mesh)
+        statics, carry = stage_tree(statics, st_sh), stage_tree(carry, ca_sh)
+        if ssp:
+            ssp.set("shards", n_shards)
+            ssp.set("nodes", n_real)
+    per = statics.alloc_cpu.shape[0] // n_shards
+    m = register()
+    m.shard_count.set(n_shards)
+    for s in range(n_shards):
+        m.shard_node_occupancy.set(str(s),
+                                   max(0, min(n_real - s * per, per)))
+    return statics, carry, {"shards": n_shards, "nodes": n_real,
+                            "nodes_per_shard": per}
 
 
 def shard_for_mesh(mesh: Mesh, statics: Statics, carry: Carry, xs: PodX

@@ -39,6 +39,7 @@ from tpusim.jaxe.backend import (
     _KNOWN_PROVIDERS,
     _MOST_REQUESTED_PROVIDERS,
     decode_placements,
+    fast_route,
 )
 from tpusim.jaxe.kernels import (
     CARRY_AXES,
@@ -50,6 +51,7 @@ from tpusim.jaxe.kernels import (
     carry_init_host,
     config_for,
     _schedule_scan_impl,
+    _tree_to_device,
     pod_columns_to_host,
     statics_to_host,
 )
@@ -62,6 +64,7 @@ from tpusim.jaxe.sharding import (
     stage_tree,
 )
 from tpusim.jaxe.state import NUM_FIXED_BITS, compile_cluster, reason_strings
+from tpusim.jaxe.trust import FAST
 
 log = logging.getLogger(__name__)
 
@@ -403,54 +406,35 @@ def _try_fast_loop(scenarios, config, compiled_list, ptabs_list, host_trees):
     program (ineligible scenario, fast path off/disabled, a transient
     runtime failure, or a failed AUTO self-verification). A kernel that
     fails to lower or compile raises."""
-    from tpusim.framework.metrics import register
-    from tpusim.jaxe.backend import (
-        _FAST_AUTO,
-        _auto_verify_and_pin,
-        _fast_path_enabled,
-        _note_fast_failure,
-        _note_fast_fallback,
-        plan_signature,
-    )
-    from tpusim.jaxe.fastscan import fast_scan, plan_fast
+    from tpusim.jaxe.fastscan import fast_scan
 
-    fast_on, auto_mode = _fast_path_enabled()
-    if not fast_on:
-        return None
-    plans = []
+    routes = []
     for b, (compiled, cols) in enumerate(compiled_list):
-        plan, why = plan_fast(config, compiled, cols,
-                              ptabs=ptabs_list[b])
-        if plan is None:
-            _note_fast_fallback(register(), why)
-            log.info("what-if fast loop ineligible (scenario %d: %s); "
-                     "using the batched vmap program", b, why)
+        route = fast_route(config, compiled, cols, len(cols.req_cpu),
+                           ptabs=ptabs_list[b])
+        if route.plan is None:
             return None
-        plans.append(plan)
+        routes.append(route)
     choices_list = []
     counts_list = []
-    for b, plan in enumerate(plans):
+    for b, route in enumerate(routes):
         try:
-            choices, counts, _adv = fast_scan(plan)
+            choices, counts, _adv = fast_scan(route.plan)
         except Exception as exc:
-            if not _note_fast_failure(exc):
+            if not FAST.note_failure(exc):
                 raise
             return None
-        _FAST_AUTO["transient"] = 0
-        sig = plan_signature(plan)
-        if auto_mode and sig not in _FAST_AUTO["verified_sigs"]:
-            # every scenario verifies until its kernel variant is trusted —
+        FAST.note_success()
+        if route.auto and not FAST.trusted(route.sig):
+            # every scenario verifies until its kernel variant is pinned —
             # a small scenario 0 passing trivially must not exempt the rest
-            # of the batch (trust pins only at TPUSIM_FAST_VERIFY_MIN+ pods)
+            # of the batch (a variant pins only from trust.PIN_MIN_PODS
+            # pods); the replay runs on the same policy-grafted statics and
+            # carry the batched vmap program would use for this scenario
             compiled, cols = compiled_list[b]
-            # replay against the same policy-grafted statics/carry the
-            # batched vmap program would use for this scenario
-            from tpusim.jaxe.kernels import _tree_to_device
-
             hs, hc, _ = host_trees[b]
-            if not _auto_verify_and_pin(
-                    config, compiled, cols, choices, counts, sig,
-                    statics=_tree_to_device(hs), carry=hc):
+            if not route.check(config, compiled, cols, choices, counts,
+                               statics=_tree_to_device(hs), carry=hc):
                 return None
         choices_list.append(choices)
         counts_list.append(counts)

@@ -36,7 +36,7 @@ import gc
 import json
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Any, Callable, Deque, Dict, List, Optional
 
 from tpusim.framework import metrics as _metrics
@@ -477,9 +477,15 @@ def note_fast_fallback(reason: str, detail: Optional[str] = None) -> None:
                     {"why": detail} if detail is not None else None)
 
 
+# per-process count of each preemption's victim-selection path (read by
+# tests as jaxe.preempt.PREEMPT_CLASS_STATS)
+VICTIM_PATHS: Counter = Counter()
+
+
 def note_victim_path(path: str) -> None:
     """Preemption victim-selection path: device/device_verified/host/
-    fallback (mirrors jaxe.preempt.PREEMPT_CLASS_STATS)."""
+    fallback (see jaxe.preempt.PREEMPT_CLASS_STATS)."""
+    VICTIM_PATHS[path] += 1
     _metrics.register().backend_victim_path.inc(path)
     rec = _active
     if rec is not None:

@@ -106,8 +106,9 @@ from tpusim.jaxe.policyc import (
     remap_policy_columns,
     sa_lock_init_rows,
 )
-from tpusim.jaxe.sharding import stage_tree
+from tpusim.jaxe.sharding import shard_mesh, stage_node_sharded, stage_tree
 from tpusim.jaxe.state import NUM_FIXED_BITS, reason_strings
+from tpusim.jaxe.trust import SHARD
 from tpusim.obs import analytics
 from tpusim.obs import provenance
 from tpusim.obs import recorder as flight
@@ -752,7 +753,11 @@ class StreamSession:
                 sa_pin=ptabs.sa_pin, sa_val=ptabs.sa_val)
             if cp.spec.sa_enabled:
                 carry_host = carry_host._replace(sa_lock=ptabs.sa_lock_init)
-        self._decide_shard_layout(config)
+        # the residency layout is re-decided here only: stream cycles
+        # inherit whatever the twin was staged as
+        self._shard_mesh = shard_mesh(config,
+                                      "stream residency staying single-device")
+        self._shard_layout = None
         statics, carry0 = self._stage_resident(statics_host, carry_host)
         p = len(pods)
         xs_host = pad_infeasible_rows(pod_columns_to_host(cols),
@@ -763,7 +768,7 @@ class StreamSession:
                 stage_tree(statics_host), stage_tree(carry_host),
                 stage_tree(xs_host)):
             # first-use verify disagreed: residency drops back to the
-            # single-device layout for the process (_SHARD_AUTO.disabled)
+            # single-device layout for the process (trust.SHARD disabled)
             statics, carry0 = self._stage_resident(statics_host, carry_host)
             xs = stage_tree(xs_host)
 
@@ -829,56 +834,14 @@ class StreamSession:
         self._note_path(path, len(pods))
         return placements
 
-    def _decide_shard_layout(self, config) -> None:
-        """Re-decide the residency layout at restage time: TPUSIM_SHARDS>1
-        (eligible, not process-disabled) shards the twin's
-        node axis; anything else is a classified fallback to the
-        single-device layout. Restage is the only place the layout can
-        change — stream cycles inherit whatever the twin was staged as."""
-        self._shard_mesh = None
-        self._shard_layout = None
-        n_shards = _backend._shard_count()
-        if n_shards <= 1 or _backend._SHARD_AUTO["disabled"]:
-            return
-        from tpusim.jaxe.kernels import shard_route_eligible
-
-        ok, why = shard_route_eligible(config)
-        if not ok:
-            register().shard_fallback.inc(why)
-            flight.note_fast_fallback(
-                "shard_" + why, "stream residency staying single-device")
-            log.info("stream residency staying single-device (%s)", why)
-            return
-        from tpusim.jaxe.sharding import make_mesh
-
-        self._shard_mesh = make_mesh(n_shards, snap=1)
-
     def _stage_resident(self, statics_host, carry_host):
         """Stage the restage's host trees as the resident twin: default
         placement, or shard-even padded + node-sharded over the mesh."""
         mesh = self._shard_mesh
         if mesh is None:
             return stage_tree(statics_host), stage_tree(carry_host)
-        from tpusim.jaxe.sharding import node_shardings, pad_node_axis
-
-        n_shards = mesh.shape["node"]
-        with flight.span("shard:stage") as ssp:
-            st_h, ca_h, n_real = pad_node_axis(statics_host, carry_host,
-                                               n_shards)
-            st_sh, ca_sh = node_shardings(mesh)
-            statics = stage_tree(st_h, st_sh)
-            carry = stage_tree(ca_h, ca_sh)
-            if ssp:
-                ssp.set("shards", n_shards)
-                ssp.set("nodes", n_real)
-        per = st_h.alloc_cpu.shape[0] // n_shards
-        self._shard_layout = {"shards": n_shards, "nodes": n_real,
-                              "nodes_per_shard": per}
-        m = register()
-        m.shard_count.set(n_shards)
-        for s in range(n_shards):
-            m.shard_node_occupancy.set(
-                str(s), max(0, min(n_real - s * per, per)))
+        statics, carry, self._shard_layout = stage_node_sharded(
+            mesh, statics_host, carry_host)
         return statics, carry
 
     def _stage_xs(self, xs_host):
@@ -907,16 +870,13 @@ class StreamSession:
         """First-use verify for the sharded residency, the same seam as the
         backend route: run the restage batch through BOTH programs on the
         fresh restage trees and compare choices/counts bit-for-bit. A
-        match pins (shards, config) in _SHARD_AUTO (later restages and
+        match pins (shards, config) in trust.SHARD (later restages and
         every stream cycle trust); a mismatch disables the sharded route
         process-wide and the caller re-stages single-device."""
-        import os as _os
-
         mesh = self._shard_mesh
         n_shards = mesh.shape["node"]
         sig = (n_shards, config)
-        if _os.environ.get("TPUSIM_SHARD_VERIFY") == "0" \
-                or sig in _backend._SHARD_AUTO["verified_sigs"]:
+        if SHARD.trusted(sig):
             return True
         from dataclasses import replace as _dc_replace
 
@@ -926,17 +886,11 @@ class StreamSession:
             _dc_replace(config, shard_axis="node"), mesh)(carry, statics,
                                                           xs)
         _, ch, cnt, _ = schedule_scan(config, carry_1d, statics_1d, xs_1d)
-        if np.array_equal(np.asarray(sch), np.asarray(ch)) \
-                and np.array_equal(np.asarray(scnt), np.asarray(cnt)):
-            _backend._SHARD_AUTO["verified_sigs"].add(sig)
-            flight.note_auto_transition("shard_pin", str(n_shards))
+        same = (np.array_equal(np.asarray(sch), np.asarray(ch))
+                and np.array_equal(np.asarray(scnt), np.asarray(cnt)))
+        if SHARD.verdict(sig, same, label=str(n_shards)):
             return True
-        _backend._SHARD_AUTO["disabled"] = True
         register().shard_count.set(0)
-        flight.note_auto_transition("shard_verify_fail", str(n_shards))
-        log.warning("sharded stream residency DISAGREES with the "
-                    "single-device scan (shards=%d); disabling the sharded "
-                    "route for this process", n_shards)
         self._shard_mesh = None
         self._shard_layout = None
         return False

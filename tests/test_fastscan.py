@@ -936,3 +936,165 @@ def test_every_group_feature_combined_parity():
     assert np.array_equal(f_counts,
                           np.asarray(counts)[:, :f_counts.shape[1]])
     assert np.array_equal(f_adv, np.asarray(advanced))
+
+
+def _launch_groups_interpod():
+    """Host ports, spreading and inter-pod anti-affinity on a node-wise
+    (hostname) key and on a zone key: group rows, presence and the
+    presence_dom carry all stream through the chunk program."""
+    nodes = [make_node(f"n{i}", milli_cpu=4000, memory=8 * 1024**3,
+                       labels={LABEL_ZONE_FAILURE_DOMAIN: f"z{i % 3}"})
+             for i in range(10)]
+    existing = [make_pod("e0", node_name="n0", phase="Running",
+                         milli_cpu=100, labels={"app": "a"})]
+    pods = []
+    for i in range(40):
+        app = "ab"[i % 2]
+        kw = {"labels": {"app": app}}
+        if i % 3 == 0:
+            kw["affinity"] = {"podAntiAffinity": {
+                "requiredDuringSchedulingIgnoredDuringExecution": [{
+                    "labelSelector": {"matchLabels": {"app": app}},
+                    "topologyKey": ("kubernetes.io/hostname" if i % 2
+                                    else LABEL_ZONE_FAILURE_DOMAIN)}]}}
+        p = make_pod(f"p{i}", milli_cpu=100 * (1 + i % 4),
+                     memory=2**27, **kw)
+        if i % 5 == 0:
+            p.spec.containers[0].ports = [ContainerPort.from_obj(
+                {"containerPort": 80, "hostPort": 8080})]
+        pods.append(p)
+    return ClusterSnapshot(nodes=nodes, pods=existing,
+                           services=[_service("web", {"app": "a"})]), pods
+
+
+def _launch_scalars():
+    nodes = [make_node(f"n{i}", milli_cpu=4000, memory=8 * 1024**3,
+                       scalars={"example.com/widget": 3 - i % 3})
+             for i in range(9)]
+    pods = [make_pod(f"p{i}", milli_cpu=300, memory=2**27,
+                     scalars={"example.com/widget": 1 + i % 2} if i % 3
+                     else None)
+            for i in range(45)]
+    return ClusterSnapshot(nodes=nodes), pods
+
+
+def _host_carry(fc):
+    """The same carry as numpy arrays, so that the next call uploads it."""
+    from tpusim.jaxe.fastscan import FastCarry
+
+    def host(a):
+        return None if a is None else np.asarray(a)
+
+    return FastCarry(rows=[host(a) for a in fc.rows], misc=host(fc.misc),
+                     scal=host(fc.scal), pres=host(fc.pres),
+                     pd=host(fc.pd), uv=host(fc.uv))
+
+
+def _in_two(plan, cut, chunk, host=False, **kw):
+    """Pods [0, cut) then [cut, P), the second resuming from the first's
+    carry: (choices, counts, advanced) over all P, and the final carry."""
+    a = fast_scan(plan, chunk=chunk, stop=cut, return_carry=True, **kw)
+    b = fast_scan(plan, chunk=chunk, start=cut, return_carry=True,
+                  carry_in=_host_carry(a[3]) if host else a[3], **kw)
+    return tuple(np.concatenate(pair) for pair in zip(a[:3], b[:3])), b[3]
+
+
+# (workload, how the span is launched); every case covers the whole batch
+LAUNCH_CASES = {
+    # 180 pods in chunks of 64: the last chunk is 52 real rows and ghosts
+    "ragged_tail": (lambda: build(0),
+                    lambda plan: fast_scan(plan, chunk=64)),
+    # the second call starts at pod 37 from a carry uploaded from the host
+    "unaligned_start": (lambda: build(0),
+                        lambda plan: _in_two(plan, 37, 64, host=True)[0]),
+    "chained_carry": (lambda: build(0), None),
+    # a 10-pod span still runs one 64-row chunk, then the rest
+    "fixed_chunk_short": (lambda: build(0),
+                          lambda plan: _in_two(plan, 10, 64,
+                                               fixed_chunk=True)[0]),
+    "groups_interpod": (_launch_groups_interpod,
+                        lambda plan: _in_two(plan, 21, 16)[0]),
+    "scalars": (_launch_scalars, lambda plan: _in_two(plan, 21, 16)[0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LAUNCH_CASES))
+def test_chunk_launch_matches_xla_scan(case):
+    """Each chunk is one upload of its packed pods and one compiled call
+    that gathers the signature rows on the device; placements, reason
+    counts and the rr advance match the XLA scan bit for bit however the
+    span is cut into calls and chunks."""
+    workload, launch = LAUNCH_CASES[case]
+    snapshot, pods = workload()
+    compiled, cols = compile_cluster(snapshot, pods)
+    assert not compiled.unsupported
+    config = config_for(
+        [compiled], most_requested=False,
+        num_reason_bits=NUM_FIXED_BITS + len(compiled.scalar_names))
+    plan, reason = plan_fast(config, compiled, cols)
+    assert plan is not None, reason
+    if case == "groups_interpod":
+        assert plan.num_groups and plan.has_interpod and plan.has_ports
+        assert plan.presence_dom is not None and any(plan.topo_nodewise)
+    if case == "scalars":
+        assert plan.num_scalars == 1
+    _, choices, counts, advanced = schedule_scan(
+        config, carry_init(compiled), statics_to_device(compiled),
+        pod_columns_to_device(cols))
+    if launch is None:
+        # two chained calls against one call over the whole span: the same
+        # answers and the same carry out
+        got, carry = _in_two(plan, 128, 64)
+        whole = fast_scan(plan, chunk=64, return_carry=True)
+        assert all(np.array_equal(x, y) for x, y in zip(got, whole[:3]))
+        for x, y in zip(carry.rows + [carry.misc],
+                        whole[3].rows + [whole[3].misc]):
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+    else:
+        got = launch(plan)
+    assert np.array_equal(got[0], np.asarray(choices))
+    assert np.array_equal(got[1], np.asarray(counts))
+    assert np.array_equal(got[2], np.asarray(advanced))
+    assert 0 < int((got[0] >= 0).sum()) < len(pods)
+
+
+def test_signature_count_reuses_the_kernel_program(monkeypatch):
+    """The signature tables reach only the gather program: two batches on
+    the same nodes whose selector tables differ in row count (1 and 16)
+    run one compiled kernel program, and both match the XLA scan."""
+    from tpusim.jaxe import fastscan
+
+    nodes = [make_node(f"n{i}", milli_cpu=2000, memory=4 * 1024**3,
+                       labels={"zone": f"z{i % 3}"}) for i in range(12)]
+    plain = [make_pod(f"a{i}", milli_cpu=300, memory=2**27)
+             for i in range(16)]
+    pinned = [make_pod(f"b{i}", milli_cpu=300, memory=2**27,
+                       node_selector={"zone": f"z{i}"} if i else None)
+              for i in range(16)]
+    fastscan._build_call.cache_clear()
+    programs = []
+    build = fastscan._build_call
+
+    def recorded(*args, **kwargs):
+        programs.append(build(*args, **kwargs))
+        return programs[-1]
+
+    monkeypatch.setattr(fastscan, "_build_call", recorded)
+    rows = []
+    for pods in (plain, pinned):
+        compiled, cols = compile_cluster(ClusterSnapshot(nodes=nodes), pods)
+        config = config_for([compiled], most_requested=False,
+                            num_reason_bits=NUM_FIXED_BITS)
+        plan, reason = plan_fast(config, compiled, cols)
+        assert plan is not None, reason
+        rows.append(plan.selector_ok.shape[0])
+        got = fast_scan(plan, chunk=16)
+        _, choices, counts, advanced = schedule_scan(
+            config, carry_init(compiled), statics_to_device(compiled),
+            pod_columns_to_device(cols))
+        assert np.array_equal(got[0], np.asarray(choices))
+        assert np.array_equal(got[1], np.asarray(counts))
+        assert np.array_equal(got[2], np.asarray(advanced))
+    assert rows[0] < rows[1]
+    assert programs[0] is programs[1]
+    assert programs[0]._cache_size() == 1

@@ -5,6 +5,7 @@ id, CPU time and collector time, and a leaf span at every phase boundary
 beneath it, with counts as args. With the recorder off nothing of this
 runs: no event, no gc hook, no profiler metadata."""
 
+import collections
 import gc
 import itertools
 
@@ -222,8 +223,27 @@ def test_simulate_count_args(rec):
 
 
 def test_pallas_count_args(rec, monkeypatch):
+    import jax
+
+    from tpusim.jaxe import fastscan
+
     monkeypatch.setenv("TPUSIM_FAST", "1")
     monkeypatch.setenv("TPUSIM_FAST_INTERPRET", "1")
+    made = collections.Counter()
+
+    def counted(name, fn):
+        def call(*args, **kwargs):
+            made[name] += 1
+            return fn(*args, **kwargs)
+        return call
+
+    build = fastscan._build_call
+    monkeypatch.setattr(jax, "device_put",
+                        counted("upload", jax.device_put))
+    monkeypatch.setattr(fastscan, "_chunk_operands",
+                        counted("call", fastscan._chunk_operands))
+    monkeypatch.setattr(fastscan, "_build_call", lambda *a, **kw: counted(
+        "call", build(*a, **kw)))
     _simulate()
     by = {s["name"]: s["args"] for s in _spans(rec)}
     assert by["plan_fast"] == {**by["plan_fast"], "pods": 7, "nodes": 4}
@@ -231,6 +251,13 @@ def test_pallas_count_args(rec, monkeypatch):
     assert by["plan_upload"]["bytes"] > 0
     assert by["kernel_launch"]["pods"] == 7
     assert by["kernel_launch"]["chunks"] >= 1
+    # the node side is one upload; each chunk one upload, the gather
+    # program and the kernel program; the spans count the calls made
+    assert by["plan_upload"]["transfers"] == 1
+    assert by["kernel_launch"]["dispatches"] == \
+        3 * by["kernel_launch"]["chunks"]
+    assert made["upload"] + made["call"] == \
+        by["plan_upload"]["transfers"] + by["kernel_launch"]["dispatches"]
 
 
 def test_stream_cycle_count_args(rec):

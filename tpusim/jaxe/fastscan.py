@@ -80,7 +80,7 @@ from __future__ import annotations
 import math
 import os
 from dataclasses import dataclass
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import Optional, Tuple
 
 import jax
@@ -2057,6 +2057,94 @@ def _make_kernel(most_requested: bool, num_bits: int, num_scalars: int,
     return kernel
 
 
+# The launch of one chunk is one upload and two compiled calls. The plan's
+# per-pod columns for a whole fast_scan span are packed on the host into one
+# [rows, W] int32 array (_pod_layout), and each chunk uploads its [k, W]
+# slice; the node side (statics, signature tables, the carry) is one batched
+# upload a call. _chunk_operands unpacks a chunk's columns and gathers each
+# pod's signature-table rows on the device, then the kernel program
+# (_build_call) runs on them. The tables reach only the small gather
+# program, so a table's row count never picks a kernel program of its own.
+POD_SCALARS = ("req_cpu", "req_mem", "req_gpu", "req_eph", "nz_cpu",
+               "nz_mem", "zero_request", "best_effort")
+POD_IDS = ("sel_id", "tol_id", "aff_id", "avoid_id", "host_id")
+STATICS = ("alloc_cpu", "alloc_mem", "alloc_gpu", "alloc_eph", "allowed",
+           "cond_bits", "mem_pressure", "disk_pressure")
+SIG_TABLES = ("selector_ok", "taint_ok", "intolerable", "aff_count",
+              "avoid_score", "host_ok")
+# each table gathered per pod, by the pod column that indexes it
+GATHER_BY = {"selector_ok": "sel_id", "taint_ok": "tol_id",
+             "intolerable": "tol_id", "aff_count": "aff_id",
+             "avoid_score": "avoid_id", "host_ok": "host_id",
+             "zone_ok_tbl": "gid", "vol_tbl": "gid", "ipod": "gid",
+             "image_tbl": "img_id", "noexec_tbl": "tol_id"}
+
+
+def _pod_layout(num_scalars: int, gpad: int, has_ports: bool,
+                has_disk: bool, has_spread: bool, has_vol_zone: bool,
+                mp: Optional[MpConst], pol: Optional[PolConst]):
+    """The packed chunk's columns: {FastPlan attribute: (offset, width)}
+    and the total width W, for one kernel variant. req_scalar takes the
+    kernel's LANES columns, its unused ones zero."""
+    fields = [(name, 1) for name in POD_SCALARS + POD_IDS]
+    if gpad or has_vol_zone or mp is not None:
+        fields.append(("gid", 1))
+    if pol is not None and pol.has_image:
+        fields.append(("img_id", 1))
+    if pol is not None and pol.has_sa:
+        fields.append(("sa_sig", 1))
+    if num_scalars:
+        fields.append(("req_scalar", LANES))
+    for name, on in (("port_row", has_ports), ("disk_row", has_disk),
+                     ("ss_row", has_spread),
+                     ("saa_row", pol is not None and pol.has_saa)):
+        if gpad and on:
+            fields.append((name, gpad))
+    if pol is not None and pol.has_sa:
+        fields += [("sa_pin_row", pol.la8), ("sa_match_row", pol.fd8)]
+    layout, width = {}, 0
+    for name, w in fields:
+        layout[name] = (width, w)
+        width += w
+    return layout, width
+
+
+def _node_layout(num_scalars: int, gpad: int, has_spread: bool,
+                 has_vol_zone: bool, ip: Optional[IpConst],
+                 mp: Optional[MpConst], pol: Optional[PolConst]):
+    """The FastPlan attributes of the node side a variant uploads."""
+    names = list(STATICS + SIG_TABLES)
+    if num_scalars:
+        names.append("alloc_scalar")
+    if has_vol_zone:
+        names.append("zone_ok_tbl")
+    if mp is not None:
+        names.append("vol_tbl")
+    if gpad and has_spread:
+        names.append("zone_onehot")
+    if gpad and ip is not None:
+        names += ["topo_rows", "ipod"]
+    if pol is not None:
+        for name, on in (("label_tbl", pol.has_label),
+                         ("label_prio_row", pol.has_prio),
+                         ("image_tbl", pol.has_image),
+                         ("noexec_tbl", pol.has_noexec),
+                         ("saa_dom_tbl", pol.has_saa),
+                         ("sa_val_tbl", pol.has_sa)):
+            if on:
+                names.append(name)
+    return names
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _chunk_operands(pods, tables, cols, by):
+    """One chunk's per-pod kernel operands: the columns of its packed
+    [k, W] pods at cols' (offset, width) pairs, and each of tables gathered
+    at the pods' ids in the column at the matching offset of by."""
+    return ([pods[:, at:at + w] for at, w in cols],
+            [t[pods[:, at]] for t, at in zip(tables, by)])
+
+
 @lru_cache(maxsize=16)
 def _build_call(npad: int, k: int, most_requested: bool, num_bits: int,
                 num_scalars: int, srows: int, interpret: bool,
@@ -2279,65 +2367,97 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
                        plan.has_disk, plan.has_spread, plan.has_vol_zone,
                        ipc, mpc, polc, plan.policy)
 
-    statics = [jnp.asarray(a) for a in (
-        plan.alloc_cpu, plan.alloc_mem, plan.alloc_gpu, plan.alloc_eph,
-        plan.allowed, plan.cond_bits, plan.mem_pressure, plan.disk_pressure)]
-    tables = [jnp.asarray(a) for a in (
-        plan.selector_ok, plan.taint_ok, plan.intolerable,
-        plan.aff_count, plan.avoid_score, plan.host_ok)]
+    names = _node_layout(plan.num_scalars, gpad, plan.has_spread,
+                         plan.has_vol_zone, ipc, mpc, polc)
     if carry_in is None:
         carry_in = init_carry(plan)
-    carry = [jnp.asarray(a) for a in carry_in.rows]
-    misc = jnp.asarray(carry_in.misc)
-    if plan.num_scalars:
-        ascal = jnp.asarray(plan.alloc_scalar)
-        scal_carry = jnp.asarray(carry_in.scal)
-    if gpad:
-        pres_carry = jnp.asarray(carry_in.pres)
-        zone_oh = (jnp.asarray(plan.zone_onehot)
-                   if plan.has_spread else None)
-    pd_carry = None
-    if ipc is not None:
-        topo_dev = jnp.asarray(plan.topo_rows)
-        ip_tbl_dev = jnp.asarray(plan.ipod)
-        if ipc.kd:
-            pd_carry = jnp.asarray(carry_in.pd)
-    if mpc is not None:
-        vol_tbl_dev = jnp.asarray(plan.vol_tbl)
-        uv_carry = jnp.asarray(carry_in.uv)
-    zone_tbl = (jnp.asarray(plan.zone_ok_tbl)
-                if plan.has_vol_zone else None)
-    if polc is not None:
-        if polc.has_label:
-            ltbl_dev = jnp.asarray(plan.label_tbl)
-        if polc.has_prio:
-            lprio_dev = jnp.asarray(plan.label_prio_row)
-        if polc.has_image:
-            img_tbl_dev = jnp.asarray(plan.image_tbl)
-        if polc.has_noexec:
-            nx_tbl_dev = jnp.asarray(plan.noexec_tbl)
-        if polc.has_saa:
-            saadom_dev = jnp.asarray(plan.saa_dom_tbl)
-        if polc.has_sa:
-            saval_dev = jnp.asarray(plan.sa_val_tbl)
+    # the carry in the kernel's output order: rows, misc, then the parts
+    # the variant has (its outputs past the three per-pod ones)
+    carry = list(carry_in.rows) + [carry_in.misc] + [
+        a for a, on in ((carry_in.scal, plan.num_scalars),
+                        (carry_in.pres, gpad),
+                        (carry_in.pd, ipc is not None and ipc.kd),
+                        (carry_in.uv, mpc is not None)) if on]
+    # one batched upload of the node side; a carry part chained from an
+    # earlier call's carry_out is on the device already and stays there
+    parts = [getattr(plan, n) for n in names] + carry
+    host = [i for i, a in enumerate(parts) if not isinstance(a, jax.Array)]
+    transfers = 0
+    if host:
+        for i, a in zip(host, jax.device_put(tuple(parts[i]
+                                                   for i in host))):
+            parts[i] = a
+        transfers += 1
+    node = dict(zip(names, parts))
+    carry = parts[len(names):]
 
     if usp:
         usp.set("nodes", npad)
-        usp.set("bytes", sum(int(a.nbytes) for a in statics + tables
-                             + carry + [misc]))
+        usp.set("bytes", sum(int(parts[i].nbytes) for i in host))
+        usp.set("transfers", transfers)
         usp.end()
     lsp = flight.span("kernel_launch", "device")
+    # the span's pods, packed once: ghost rows past the span are infeasible
+    # everywhere (GHOST_REQ), with no carry or rr effect
+    cols, width = _pod_layout(plan.num_scalars, gpad, plan.has_ports,
+                              plan.has_disk, plan.has_spread,
+                              plan.has_vol_zone, mpc, polc)
+    num_chunks = -(-span // k) if span > 0 else 0
+    packed = np.zeros((num_chunks * k, width), np.int32)
+    packed[span:, cols["req_cpu"][0]] = GHOST_REQ
+    if span > 0:
+        for name, (at, w) in cols.items():
+            a = np.asarray(getattr(plan, name)[start:stop]).reshape(span, -1)
+            packed[:span, at:at + a.shape[1]] = a
+    gathered = [n for n in names if n in GATHER_BY]
+    tables = tuple(node[n] for n in gathered)
+    by = tuple(cols[GATHER_BY[n]][0] for n in gathered)
+    col_spans = tuple(cols.values())
 
-    def col(a, fill):
-        out = np.full(k, fill, dtype=np.int32)
-        out[:a.shape[0]] = a
-        return out.reshape(k, 1)
+    def kernel_args(c, r, carry):
+        # the kernel's operands in its order, from the chunk's columns c and
+        # gathered rows r (by FastPlan attribute), the node side and carry
+        extra = iter(carry[8:])
+        args = ([c[n] for n in POD_SCALARS] + [r[n] for n in SIG_TABLES]
+                + [node[n] for n in STATICS] + carry[:8])
+        if plan.num_scalars:
+            args += [c["req_scalar"], node["alloc_scalar"], next(extra)]
+        pres = next(extra) if gpad else None
+        pd = next(extra) if ipc is not None and ipc.kd else None
+        uv = next(extra) if mpc is not None else None
+        if plan.has_vol_zone:
+            args.append(r["zone_ok_tbl"])
+        if mpc is not None:
+            args += [r["vol_tbl"], uv]
+        if gpad:
+            args.append(c["gid"])
+            if plan.has_spread:
+                args.append(node["zone_onehot"])
+            args.append(pres)
+            args += [c[n] for n, on in (("port_row", plan.has_ports),
+                                        ("disk_row", plan.has_disk),
+                                        ("ss_row", plan.has_spread)) if on]
+            if ipc is not None:
+                args += [node["topo_rows"], r["ipod"]]
+                if ipc.kd:
+                    args.append(pd)
+        if polc is not None:
+            if polc.has_label:
+                args.append(node["label_tbl"])
+            if polc.has_prio:
+                args.append(node["label_prio_row"])
+            if polc.has_image:
+                args.append(r["image_tbl"])
+            if polc.has_noexec:
+                args.append(r["noexec_tbl"])
+            if polc.has_saa:
+                args += [c["saa_row"], node["saa_dom_tbl"]]
+            if polc.has_sa:
+                args += [c["sa_sig"], c["sa_pin_row"], c["sa_match_row"],
+                         node["sa_val_tbl"]]
+        return args
 
-    def grow(a, w=None):
-        # per-pod [*, W] group rows for one chunk; ghost rows all-zero
-        out = np.zeros((k, w or gpad), dtype=np.int32)
-        out[:a.shape[0]] = a
-        return out
+    dispatches = 0
 
     # Dispatch chunks ahead of fetching their per-pod outputs: the carry
     # chains device-to-device (out[:7] feed the next call unmaterialized),
@@ -2362,100 +2482,20 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
                         np.asarray(ocnt)[:n_real, :num_bits],
                         np.asarray(oadv)[:n_real, 0] != 0))
 
-    num_chunks = -(-span // k) if span > 0 else 0
     for ci in range(num_chunks):
         sl = slice(start + ci * k, min(start + (ci + 1) * k, stop))
-        # ghost padding: infeasible everywhere, no carry/rr effect
-        scalars = [
-            col(plan.req_cpu[sl], GHOST_REQ), col(plan.req_mem[sl], 0),
-            col(plan.req_gpu[sl], 0), col(plan.req_eph[sl], 0),
-            col(plan.nz_cpu[sl], 0), col(plan.nz_mem[sl], 0),
-            col(plan.zero_request[sl], 0), col(plan.best_effort[sl], 0)]
-        ids = [col(plan.sel_id[sl], 0), col(plan.tol_id[sl], 0),
-               col(plan.aff_id[sl], 0), col(plan.avoid_id[sl], 0),
-               col(plan.host_id[sl], 0)]
-        # pregather the signature rows for this chunk (XLA gather, [k, Npad])
-        sel_rows = tables[0][ids[0][:, 0]]
-        tol_rows = tables[1][ids[1][:, 0]]
-        intol_rows = tables[2][ids[1][:, 0]]
-        aff_rows = tables[3][ids[2][:, 0]]
-        av_rows = tables[4][ids[3][:, 0]]
-        host_rows = tables[5][ids[4][:, 0]]
-        args = ([jnp.asarray(a) for a in scalars]
-                + [sel_rows, tol_rows, intol_rows, aff_rows, av_rows,
-                   host_rows]
-                + statics + carry + [misc])
-        if plan.num_scalars:
-            rs = np.zeros((k, LANES), dtype=np.int32)
-            rs[:sl.stop - sl.start, :plan.num_scalars] = plan.req_scalar[sl]
-            args += [jnp.asarray(rs), ascal, scal_carry]
-        if gpad or plan.has_vol_zone or mpc is not None:
-            gids = col(plan.gid[sl], 0)
-        if plan.has_vol_zone:
-            args.append(zone_tbl[gids[:, 0]])
-        if mpc is not None:
-            args.append(vol_tbl_dev[gids[:, 0]])
-            args.append(uv_carry)
-        if gpad:
-            args.append(jnp.asarray(gids))
-            if plan.has_spread:
-                args.append(zone_oh)
-            args.append(pres_carry)
-            if plan.has_ports:
-                args.append(jnp.asarray(grow(plan.port_row[sl])))
-            if plan.has_disk:
-                args.append(jnp.asarray(grow(plan.disk_row[sl])))
-            if plan.has_spread:
-                args.append(jnp.asarray(grow(plan.ss_row[sl])))
-            if ipc is not None:
-                args.append(topo_dev)
-                # per-pod interpod rows: device gather from the per-group
-                # table (pad rows gather row 0 of a zero-padded table;
-                # ghost pods are infeasible everywhere regardless)
-                args.append(ip_tbl_dev[gids[:, 0]])
-                if ipc.kd:
-                    args.append(pd_carry)
-        if polc is not None:
-            # residue-class policy operands (ghost pods gather row 0 /
-            # all-zero rows; they are infeasible everywhere regardless)
-            if polc.has_label:
-                args.append(ltbl_dev)
-            if polc.has_prio:
-                args.append(lprio_dev)
-            if polc.has_image:
-                iid = col(plan.img_id[sl], 0)
-                args.append(img_tbl_dev[iid[:, 0]])
-            if polc.has_noexec:
-                args.append(nx_tbl_dev[ids[1][:, 0]])
-            if polc.has_saa:
-                args.append(jnp.asarray(grow(plan.saa_row[sl])))
-                args.append(saadom_dev)
-            if polc.has_sa:
-                args.append(jnp.asarray(col(plan.sa_sig[sl], 0)))
-                args.append(jnp.asarray(grow(plan.sa_pin_row[sl],
-                                             polc.la8)))
-                args.append(jnp.asarray(grow(plan.sa_match_row[sl],
-                                             polc.fd8)))
-                args.append(saval_dev)
+        pods = jax.device_put(packed[ci * k:(ci + 1) * k])
+        dispatches += 1
         # Mosaic has no 64-bit types, and under the backend's process-wide
         # x64 every Python scalar in the kernel body would trace as a weak
         # int64; the operands and results are int32 either way
         with jax.enable_x64(False):
-            out = call(*args)
-        carry = list(out[:7])
-        misc = out[7]
-        oat = 11
-        if plan.num_scalars:
-            scal_carry = out[oat]
-            oat += 1
-        if gpad:
-            pres_carry = out[oat]
-            oat += 1
-        if pd_carry is not None:
-            pd_carry = out[oat]
-            oat += 1
-        if mpc is not None:
-            uv_carry = out[oat]
+            c, r = _chunk_operands(pods, tables, col_spans, by)
+            dispatches += 1
+            out = call(*kernel_args(dict(zip(cols, c)),
+                                    dict(zip(gathered, r)), carry))
+            dispatches += 1
+        carry = list(out[:8]) + list(out[11:])
         pending.append((out[8], out[9], out[10], sl.stop - sl.start))
         if sync_every and len(pending) > sync_every:
             drain_one()
@@ -2466,6 +2506,7 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
     if lsp:
         lsp.set("pods", span)
         lsp.set("chunks", num_chunks)
+        lsp.set("dispatches", dispatches)
         lsp.end()
     with flight.span("device_wait", "device"):
         while pending:
@@ -2479,10 +2520,11 @@ def fast_scan(plan: FastPlan, chunk: int = 0,
                     np.concatenate([r[2] for r in results]))
     if not return_carry:
         return out3
+    extra = iter(carry[8:])
     carry_out = FastCarry(
-        rows=list(carry), misc=misc,
-        scal=scal_carry if plan.num_scalars else None,
-        pres=pres_carry if gpad else None,
-        pd=pd_carry,
-        uv=uv_carry if mpc is not None else None)
+        rows=list(carry[:7]), misc=carry[7],
+        scal=next(extra) if plan.num_scalars else None,
+        pres=next(extra) if gpad else None,
+        pd=next(extra) if ipc is not None and ipc.kd else None,
+        uv=next(extra) if mpc is not None else None)
     return out3 + (carry_out,)
